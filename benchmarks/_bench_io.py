@@ -19,6 +19,20 @@ import os
 _RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
 
 
+def execution_stamp() -> dict:
+    """``interpret``: whether the Pallas kernels ran in the interpreter
+    (on any platform but a TPU); ``device``: where the numbers were
+    measured, as JAX reports it."""
+    import jax
+
+    from repro.backends.pallas import interpret_mode
+
+    dev = jax.devices()[0]
+    return {"interpret": interpret_mode(),
+            "device": {"platform": dev.platform, "kind": dev.device_kind,
+                       "count": len(jax.devices())}}
+
+
 def default_out(filename: str) -> str:
     """Default (non-CI) output path: ``results/<filename>``."""
     return os.path.join(_RESULTS_DIR, filename)

@@ -43,7 +43,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(__file__))
 
-from _bench_io import default_out, write_bench_json
+from _bench_io import default_out, execution_stamp, write_bench_json
 
 SCHEMA = "repro-bench/fused-v4"
 DEFAULT_OUT = default_out("BENCH_fused.json")
@@ -265,7 +265,7 @@ def main(argv=None) -> int:
         "schema": SCHEMA,
         "smoke": args.smoke,
         "reps": reps,
-        "interpret": True,
+        **execution_stamp(),
         "compile_cache": {
             "hits": hits,
             "misses": misses,
@@ -308,4 +308,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -1,5 +1,5 @@
 """Kernel micro-benchmarks through the session API: a ``pallas``
-session (interpret) vs the ``oracle`` reference on identical inputs,
+session vs the ``oracle`` reference on identical inputs,
 plus the analytic TPU-side traffic model for each kernel.  Swapping the
 one-string backend name re-prices every row on a different executor."""
 

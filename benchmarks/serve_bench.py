@@ -38,7 +38,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(__file__))
 
-from _bench_io import default_out, write_bench_json
+from _bench_io import default_out, execution_stamp, write_bench_json
 
 SCHEMA = "repro-bench/serve-v2"
 DEFAULT_OUT = default_out("BENCH_serve.json")
@@ -152,6 +152,7 @@ def main(argv=None) -> int:
         "schema": SCHEMA,
         "smoke": args.smoke,
         "backend": args.backend,
+        **execution_stamp(),
         "rounds": rounds,
         "tick_window_s": tick_window,
         "workload": {
@@ -194,4 +195,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -7,7 +7,7 @@ schedules by program content:
 
   * ``oracle``  pure bitwise reference (ground truth),
   * ``sim``     behavioural DRAM model with the calibrated error surfaces,
-  * ``pallas``  bulk TPU kernels (interpret mode on CPU).
+  * ``pallas``  bulk TPU kernels (interpreted on a CPU, compiled on a TPU).
 
 Runs in ~30s on CPU:
   1. simultaneous many-row activation success (calibrated model),

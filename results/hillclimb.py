@@ -8,6 +8,7 @@ the roofline terms + top collectives (the §Perf hypothesis loop tool).
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU lowering tool: never the chip
 
 import argparse
 import dataclasses

@@ -62,6 +62,9 @@ def main():
     done = {(r["arch"], r["shape"]) for r in results if r.get("status") in ("ok", "skipped")}
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # Dry-run cells are CPU lowering tools; the preflight above may hold
+    # the chip, and a child must never compete with it.
+    env["JAX_PLATFORMS"] = "cpu"
     for arch in ARCHS:
         for shape in SHAPES:
             if (arch, shape) in done:

@@ -124,9 +124,10 @@ print(f"bench gate OK: add32 fused {add['fused']['dispatches']} vs "
       f"{cc['misses']} misses")
 
 # Megakernel gate: whole-schedule execution must collapse the deep
-# workloads (add32: ~34 levels, mul8: ~36) to at most 2 launches, never
-# launch more than the level-fused path, and cost no more wall time on
-# the smoke sizes; lowered tables must be cache-reused across reps.
+# workloads (add32: ~34 levels, mul8: ~36) to at most 2 launches and
+# never launch more than the level-fused path; lowered tables must be
+# cache-reused across reps.  No wall time is gated: on the CPU it times
+# the Pallas interpreter, which no deployment runs.
 for wl in ("add32", "mul8"):
     r = rows[(wl, "pallas")]
     mega, fused = r["megakernel"], r["fused"]
@@ -136,14 +137,10 @@ for wl in ("add32", "mul8"):
     assert mega["parity"], (wl, mega)
     assert mega["vmem"] is not None and mega["vmem"]["block_c"] % 128 == 0
 add_mega = rows[("add32", "pallas")]["megakernel"]
-add_fused = rows[("add32", "pallas")]["fused"]
-assert add_mega["wall_s"] <= add_fused["wall_s"], (add_mega, add_fused)
 lc = doc["lowering_cache"]
 assert lc["hits"] >= 1, lc
-print(f"megakernel gate OK: add32 {add_mega['dispatches']} dispatch "
-      f"({add_mega['wall_s']*1e3:.1f} ms vs fused "
-      f"{add_fused['wall_s']*1e3:.1f} ms); lowering cache {lc['hits']} "
-      f"hits / {lc['misses']} misses")
+print(f"megakernel gate OK: add32 {add_mega['dispatches']} dispatch; "
+      f"lowering cache {lc['hits']} hits / {lc['misses']} misses")
 PY
 rm -rf "$BENCH_CI_ROOT"
 
@@ -180,11 +177,9 @@ for o in loads:
         (o, bat["energy_nj"], seq["energy_nj"])
     assert all(p["tick_window_s"] == doc["tick_window_s"]
                for p in (seq, bat)), o
-# Throughput gate at the smoke load point (largest load; widest margin).
+# No throughput is gated: on the CPU it times the Pallas interpreter.
 o = loads[-1]
 seq, bat = points[(o, "sequential")], points[(o, "batched")]
-assert bat["throughput_rps"] >= seq["throughput_rps"], \
-    (bat["throughput_rps"], seq["throughput_rps"])
 # The batched service must be hitting the shared schedule cache.
 assert bat["cache"]["hit_rate"] > 0, bat["cache"]
 print(f"serve gate OK: load {o} batched {bat['throughput_rps']:.0f} req/s"

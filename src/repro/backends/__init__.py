@@ -9,7 +9,7 @@ One :class:`~repro.pud.isa.Program`, three interchangeable executors:
 >>> bad_bits = be.mismatch(out, want)
 
 Every backend takes the same :class:`ExecutionContext` (calibration
-point, timings, temperature/voltage, interpret/tiling flags), so a
+point, timings, temperature/voltage, tiling flags), so a
 backend is a one-string config choice everywhere.  New executors
 (multi-device sharded sim, compiled-TPU) register with
 :func:`register_backend` and inherit every consumer for free.

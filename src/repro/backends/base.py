@@ -93,7 +93,7 @@ class Capabilities:
             ``Subarray``/``PUDDevice`` APA/PRE/ACT command model rather
             than closed-form boolean semantics.
         accelerated: True when bulk ops dispatch Pallas TPU kernels
-            (interpret mode on CPU, compiled on real TPUs).
+            (compiled on a TPU, the Pallas interpreter elsewhere).
         max_majx: widest MAJ arity this backend can execute.  For the
             calibrated ``sim`` backend this is the manufacturer limit
             (fn 11: 9 for Mfr H, 7 for Mfr M); digital backends are

@@ -4,7 +4,7 @@ The paper's central result is that the *same* APA command sequence yields
 MAJX, Multi-RowCopy, or plain RowClone depending only on the operating
 regime — timings (t1, t2), temperature, wordline voltage, data pattern.
 ``ExecutionContext`` captures exactly that regime (plus framework-side
-execution knobs: interpret mode, tile geometry, RNG seed) so that the
+execution knobs: tile geometry, RNG seed) so that the
 regime is declared once and threaded to whichever backend executes,
 instead of today's per-call keyword soup.
 """
@@ -67,7 +67,6 @@ class ExecutionContext:
       content, see :meth:`repro.session.cache.CompileCache.
       certificate_for`); set False to opt out on hot paths that already
       certified their programs elsewhere,
-    * ``interpret`` — Pallas interpret mode (CPU) vs compiled TPU,
     * ``block_r`` / ``block_c`` — VPU tile geometry for bulk kernels,
     * ``vmem_budget_bytes`` — on-chip working-set ceiling the megakernel
       executor's column planner blocks against
@@ -88,7 +87,6 @@ class ExecutionContext:
     n_act: int = 32
 
     certify: bool = True
-    interpret: bool = True
     block_r: int = 8
     block_c: int = 512
     vmem_budget_bytes: int = 8 * 2**20

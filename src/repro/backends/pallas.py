@@ -3,8 +3,10 @@
 Dispatches the Pallas kernels of :mod:`repro.kernels` (bit-sliced CSA
 MAJX, fan-out Multi-RowCopy, fused XOR+popcount mismatch, fused
 bit-serial adder) through the shared VPU tiling helper
-(:mod:`repro.kernels.tiling`).  ``ctx.interpret=True`` is the validated
-CPU path; on real TPUs construct the context with ``interpret=False``.
+(:mod:`repro.kernels.tiling`).  How the kernels run is not a knob:
+:func:`interpret_mode` compiles them on a TPU and runs the Pallas
+interpreter everywhere else, and the backend fixes that mode once, at
+construction.
 Batch dispatch is vmapped over the kernel wrappers — one fused launch
 per batch, not a python loop.
 
@@ -35,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.backends.base import Backend, Capabilities
+from repro.backends.context import ExecutionContext
 from repro.core import calibration as cal
 from repro.core.costmodel import COST
 from repro.kernels.bitserial.ops import bitserial_add
@@ -44,8 +47,21 @@ from repro.kernels.rowcopy.ops import fanout
 from repro.pud.isa import Program
 
 
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter on this process.
+
+    On a TPU the kernels run compiled, never interpreted.  Elsewhere the
+    interpreter is the only way a Pallas TPU kernel runs at all.
+    """
+    return jax.default_backend() != "tpu"
+
+
 class PallasBackend(Backend):
     name = "pallas"
+
+    def __init__(self, ctx: Optional[ExecutionContext] = None):
+        super().__init__(ctx)
+        self.interpret = interpret_mode()
 
     def capabilities(self) -> Capabilities:
         return Capabilities(
@@ -75,7 +91,7 @@ class PallasBackend(Backend):
              n_act: Optional[int] = None) -> jax.Array:
         out_words = planes.size // planes.shape[0]
         self._launch((planes.size + out_words) * 4)
-        return majx_kernel(planes, interpret=self.ctx.interpret,
+        return majx_kernel(planes, interpret=self.interpret,
                            block_r=self.ctx.block_r,
                            block_c=self.ctx.block_c)
 
@@ -83,23 +99,23 @@ class PallasBackend(Backend):
         """(B, X, R, C) -> (B, R, C) in one vmapped kernel dispatch."""
         planes = jnp.asarray(planes, jnp.uint32)
         self._launch((planes.size + planes.size // planes.shape[1]) * 4)
-        fn = functools.partial(majx_kernel, interpret=self.ctx.interpret,
+        fn = functools.partial(majx_kernel, interpret=self.interpret,
                                block_r=self.ctx.block_r,
                                block_c=self.ctx.block_c)
         return jax.vmap(fn)(planes)
 
     def rowcopy(self, src: jax.Array, n_dst: int) -> jax.Array:
         self._launch(src.size * (1 + n_dst) * 4)
-        return fanout(src, n_dst, interpret=self.ctx.interpret,
+        return fanout(src, n_dst, interpret=self.interpret,
                       block_r=self.ctx.block_r, block_c=self.ctx.block_c)
 
     def mismatch(self, a: jax.Array, b: jax.Array) -> jax.Array:
         self._launch((jnp.asarray(a).size + jnp.asarray(b).size) * 4)
-        return mismatch_count(a, b, interpret=self.ctx.interpret)
+        return mismatch_count(a, b, interpret=self.interpret)
 
     def add_planes(self, a: jax.Array, b: jax.Array) -> jax.Array:
         self._launch(3 * jnp.asarray(a).size * 4)
-        return bitserial_add(a, b, interpret=self.ctx.interpret)
+        return bitserial_add(a, b, interpret=self.interpret)
 
     # ------------------------------------------------- fused program path
     def run_fused(self, program: Program, state: jax.Array, *,
@@ -159,7 +175,7 @@ class PallasBackend(Backend):
                          block_r=self.ctx.block_r)
         self._launch(2 * rows * words * 4)  # image in + image out
         return run_lowering(lowering, state, block_c=plan.block_c,
-                            interpret=self.ctx.interpret)
+                            interpret=self.interpret)
 
     def _exec_group(self, group, entry: jax.Array,
                     state: jax.Array) -> jax.Array:

@@ -71,8 +71,11 @@ def restore(tree_like, directory: str, step: Optional[int] = None,
     if use_kernel:
         flat = [jax.tree.leaves(t) for t in trees]
         treedef = jax.tree.structure(trees[0])
+        from repro.backends.pallas import interpret_mode
         from repro.kernels.majx.ops import vote as kvote
-        voted = [kvote([f[i] for f in flat]) for i in range(len(flat[0]))]
+        interpret = interpret_mode()
+        voted = [kvote([f[i] for f in flat], interpret=interpret)
+                 for i in range(len(flat[0]))]
         out = jax.tree.unflatten(treedef, voted)
     else:
         out = tmr.vote_pytree(trees)

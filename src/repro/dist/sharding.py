@@ -95,12 +95,11 @@ def use_rules(rules: AxisRules):
             _STATE.rules = prev
 
 
-def _current_mesh() -> Optional[Mesh]:
-    """The mesh entered via ``with mesh:``, or None outside any."""
-    from jax.interpreters import pxla
-
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh is None or mesh.empty:
+def _current_mesh() -> Optional[jax.sharding.AbstractMesh]:
+    """The (abstract) mesh entered via ``with jax.set_mesh(mesh):``, or
+    None outside any; readable inside ``jax.jit`` tracing too."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return None
     return mesh
 

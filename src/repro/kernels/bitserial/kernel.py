@@ -41,7 +41,7 @@ def bitserial_add_pallas(
     *,
     block_r: int = 8,
     block_c: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """a, b: (NBITS, R, C) uint32 planes -> (NBITS, R, C) sum planes."""
     nbits, r, c = a.shape

@@ -12,7 +12,7 @@ from repro.kernels.bitserial.ref import bitserial_add_ref
 
 
 def bitserial_add(a_planes: jax.Array, b_planes: jax.Array, *,
-                  interpret: bool = True, block_r: int = 8,
+                  interpret: bool, block_r: int = 8,
                   block_c: int = 256) -> jax.Array:
     """(NBITS, R, C) or (NBITS, C) packed planes -> sum planes."""
     a = jnp.asarray(a_planes, jnp.uint32)
@@ -28,7 +28,7 @@ def bitserial_add(a_planes: jax.Array, b_planes: jax.Array, *,
     return out[:, 0, :] if squeeze else out
 
 
-def add_u32(a: jax.Array, b: jax.Array, *, interpret: bool = True) -> jax.Array:
+def add_u32(a: jax.Array, b: jax.Array, *, interpret: bool) -> jax.Array:
     """uint32 element vectors -> uint32 sums, via the bit-plane kernel."""
     a = jnp.asarray(a, jnp.uint32).reshape(-1)
     b = jnp.asarray(b, jnp.uint32).reshape(-1)

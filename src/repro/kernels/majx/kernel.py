@@ -76,7 +76,7 @@ def majx_pallas(
     *,
     block_r: int = 8,
     block_c: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """planes: (N, R, C) uint32, N odd -> (R, C) uint32 majority."""
     n, r, c = planes.shape

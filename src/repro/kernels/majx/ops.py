@@ -11,12 +11,13 @@ from repro.kernels.majx.kernel import majx_pallas
 from repro.kernels.majx.ref import majx_ref
 
 
-def majx(planes: jax.Array, *, interpret: bool = True,
+def majx(planes: jax.Array, *, interpret: bool,
          block_r: int = 8, block_c: int = 512) -> jax.Array:
     """Bulk MAJX over (N, R, C) packed uint32 planes -> (R, C).
 
     Pads to VPU-aligned tiles, dispatches the Pallas kernel, crops.
-    ``interpret=True`` is the validated CPU path; on real TPUs pass False.
+    ``interpret`` selects the Pallas interpreter (the only way the kernel
+    runs on a CPU) or the compiled TPU kernel.
     """
     planes = jnp.asarray(planes, jnp.uint32)
     if planes.ndim == 2:
@@ -31,7 +32,7 @@ def majx(planes: jax.Array, *, interpret: bool = True,
     return out[0] if squeeze else out
 
 
-def vote(replicas, *, interpret: bool = True):
+def vote(replicas, *, interpret: bool):
     """TMR/XMR vote over replicas of an arbitrary fixed-width array.
 
     Bitcasts each replica to packed words, majority-votes them through the

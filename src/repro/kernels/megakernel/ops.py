@@ -4,6 +4,13 @@
 front of the program rows, see :mod:`repro.compile.megakernel`), pads
 it to the VPU tile, launches :func:`repro.kernels.megakernel.kernel.
 schedule_pallas` exactly once, and crops the program rows back out.
+
+The level tables ride in SMEM as scalar-prefetch operands.  A v5e
+core has 1 MiB of SMEM: the chip's compiler accepts tables of
+1,036,288 bytes and refuses 1,047,552 (each table is padded to 4 KiB,
+and the kernel's own scalars need a few KiB more).
+:data:`SMEM_TABLE_LIMIT_BYTES` keeps 64 KiB of that as margin; a larger
+lowering raises ``ValueError`` on every platform.
 """
 
 from __future__ import annotations
@@ -15,13 +22,16 @@ from repro.compile.megakernel import MegaLowering, N_CONST_ROWS, ONE_ROW
 from repro.kernels.megakernel.kernel import schedule_pallas
 from repro.kernels.tiling import VPU_LANES, VPU_SUBLANES, clamp_block_c
 
+#: Most level-table bytes one megakernel launch may stage in SMEM.
+SMEM_TABLE_LIMIT_BYTES = 1024 * 1024 - 64 * 1024
+
 
 def run_lowering(
     lowering: MegaLowering,
     state: jax.Array,
     *,
     block_c: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Execute lowered level tables on a (rows, words) uint32 image.
 
@@ -38,6 +48,12 @@ def run_lowering(
         raise ValueError(
             f"lowering addresses {lowering.n_rows} rows but state has "
             f"only {rows}")
+    if lowering.table_bytes > SMEM_TABLE_LIMIT_BYTES:
+        raise ValueError(
+            f"megakernel level tables need {lowering.table_bytes} bytes of "
+            f"SMEM ({lowering.n_levels} levels x {lowering.w_max} slots x "
+            f"{lowering.x_max} operands); the limit is "
+            f"SMEM_TABLE_LIMIT_BYTES={SMEM_TABLE_LIMIT_BYTES}")
 
     block_c = clamp_block_c(block_c)
     rows_aug = -(-(rows + N_CONST_ROWS) // VPU_SUBLANES) * VPU_SUBLANES
@@ -49,10 +65,12 @@ def run_lowering(
     aug = aug.at[N_CONST_ROWS:N_CONST_ROWS + rows, :words].set(state)
 
     out = schedule_pallas(
-        jnp.asarray(lowering.src),
-        jnp.asarray(lowering.dst),
-        jnp.asarray(lowering.inv),
+        jnp.asarray(lowering.src.reshape(-1)),
+        jnp.asarray(lowering.dst.reshape(-1)),
+        jnp.asarray(lowering.inv.reshape(-1)),
         aug,
+        n_levels=int(lowering.n_levels),
+        w=int(lowering.w_max),
         x=int(lowering.x_max),
         block_c=block_c,
         interpret=interpret,

@@ -3,9 +3,9 @@
 The characterization harness (§3-§6) compares millions of read-back cells
 against expected data per trial; the hot loop is "count differing bits".
 The kernel fuses XOR, SWAR popcount, and a grid-carried scalar reduction:
-each (BR, BC) block contributes its partial sum into a single accumulator
-block that every grid step maps to, so HBM sees the operands exactly once
-and one int32 comes back.
+each (BR, BC) block contributes its partial sum into a single SMEM
+accumulator that every grid step maps to, so HBM sees the operands
+exactly once and one int32 comes back.
 """
 
 from __future__ import annotations
@@ -15,6 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def mismatch_kernel(g_ref, w_ref, o_ref):
@@ -41,8 +42,10 @@ def mismatch_pallas(
     *,
     block_r: int = 8,
     block_c: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
+    """Differing bits of two (R, C) uint32 images, R and C padded to the
+    (block_r, block_c) tile with equal words (see ``ops.mismatch_count``)."""
     r, c = got.shape
     grid = (pl.cdiv(r, block_r), pl.cdiv(c, block_c))
     spec = pl.BlockSpec((block_r, block_c), lambda i, j: (i, j))
@@ -50,7 +53,7 @@ def mismatch_pallas(
         mismatch_kernel,
         grid=grid,
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         interpret=interpret,
     )(got, want)[0, 0]

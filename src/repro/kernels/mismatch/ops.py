@@ -11,18 +11,23 @@ from repro.kernels.mismatch.ref import mismatch_count_ref
 
 
 def mismatch_count(got: jax.Array, want: jax.Array, *,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """Number of differing bits between packed arrays of any shape."""
     g = jnp.asarray(got, jnp.uint32).reshape(-1)
     w = jnp.asarray(want, jnp.uint32).reshape(-1)
     width = 512
-    g2 = tiling.words_to_rows(g, width)
-    w2 = tiling.words_to_rows(w, width)
-    return mismatch_pallas(g2, w2, interpret=interpret)
+    # Zero padding on both sides XORs to zero: the tail adds no bits, and
+    # the kernel never reads past the arrays into a partial block.
+    g2, _ = tiling.pad_to_tile(tiling.words_to_rows(g, width),
+                               tiling.VPU_SUBLANES, width)
+    w2, _ = tiling.pad_to_tile(tiling.words_to_rows(w, width),
+                               tiling.VPU_SUBLANES, width)
+    return mismatch_pallas(g2, w2, block_r=tiling.VPU_SUBLANES,
+                           block_c=width, interpret=interpret)
 
 
 def success_rate(got, want, n_bits: int | None = None, *,
-                 interpret: bool = True) -> float:
+                 interpret: bool) -> float:
     g = jnp.asarray(got, jnp.uint32)
     total = int(n_bits) if n_bits else g.size * 32
     bad = int(mismatch_count(got, want, interpret=interpret))

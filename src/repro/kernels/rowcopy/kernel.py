@@ -30,7 +30,7 @@ def fanout_pallas(
     fanout: int,
     block_r: int = 8,
     block_c: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """src: (R, C) -> (fanout, R, C) broadcast."""
     r, c = src.shape

@@ -10,7 +10,7 @@ from repro.kernels.rowcopy.kernel import fanout_pallas
 from repro.kernels.rowcopy.ref import fanout_ref
 
 
-def fanout(src: jax.Array, fanout_n: int, *, interpret: bool = True,
+def fanout(src: jax.Array, fanout_n: int, *, interpret: bool,
            block_r: int = 8, block_c: int = 512) -> jax.Array:
     """Broadcast (R, C) -> (fanout_n, R, C), Multi-RowCopy style."""
     src = jnp.asarray(src)

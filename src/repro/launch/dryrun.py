@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax-importing import: jax locks the device count at
 # first backend initialization.  The dry-run is the ONLY entry point that
 # fakes 512 devices; tests and benches see the real (1-CPU) topology.
+# It is a CPU lowering tool: it never opens an accelerator, so it cannot
+# compete for a chip with the process that holds it.
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell.
 
@@ -37,14 +40,6 @@ from repro.models import model as M
 from repro.train.step import make_train_step
 
 
-def _cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() returns a 1-elem list on older jax."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def _train_fn(cfg, microbatches: int = 1):
     tc = TrainConfig(microbatches=microbatches, compression="none")
     return make_train_step(cfg, tc)
@@ -77,7 +72,7 @@ def lower_cell(cfg, shape, mesh, *, donate: bool = True,
 
     rules_ctx = (shd.use_rules(shd.SERVE_RULES)
                  if serve_rules else contextlib.nullcontext())
-    with mesh, rules_ctx:
+    with jax.set_mesh(mesh), rules_ctx:
         if shape.kind == "train":
             state_abs, state_shard, _ = sp.state_specs(cfg, mesh)
             batch_abs, batch_shard = sp.batch_specs(cfg, shape, mesh, True)
@@ -123,20 +118,20 @@ def _cost_points(cfg, shape, mesh):
             for s_small in (64, 128):
                 sh = dataclasses.replace(shape, seq_len=s_small)
                 _, comp = lower_cell(cfg, sh, mesh, donate=False)
-                ca = _cost_analysis(comp)
+                ca = comp.cost_analysis()
                 pts[s_small] = rl.CostPoint(ca.get("flops", 0.0),
                                             ca.get("bytes accessed", 0.0))
             if shape.kind == "decode":
                 # decode for ssm is python-unrolled: exact, no composition
                 _, comp = lower_cell(cfg, shape, mesh, donate=False)
-                ca = _cost_analysis(comp)
+                ca = comp.cost_analysis()
                 return rl.CostPoint(ca.get("flops", 0.0),
                                     ca.get("bytes accessed", 0.0))
             return rl.compose_seq(shape.seq_len, pts)
         for d in depths:
             cfg_d = dataclasses.replace(cfg, n_layers=d, remat="none")
             _, comp = lower_cell(cfg_d, shape, mesh, donate=False)
-            ca = _cost_analysis(comp)
+            ca = comp.cost_analysis()
             points[d] = rl.CostPoint(ca.get("flops", 0.0),
                                      ca.get("bytes accessed", 0.0))
         return rl.compose(cfg, points)
@@ -165,7 +160,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
     hlo = compiled.as_text()
     trips = [max(cfg.n_layers, 1)] if mb == 1 else [mb, max(cfg.n_layers, 1)]
     coll = rl.collective_bytes(hlo, loop_trips=trips)
-    ca = _cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     deploy_cost = rl.CostPoint(ca.get("flops", 0.0),
                                ca.get("bytes accessed", 0.0))
 

@@ -16,11 +16,8 @@ import jax
 
 
 def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    # axis_types landed after jax 0.4.37; Auto is the default there anyway.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

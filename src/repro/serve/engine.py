@@ -36,9 +36,26 @@ import numpy as np
 from repro.backends import ExecutionContext
 from repro.configs.base import ModelConfig
 from repro.core import bitplanes as bp
+from repro.core import calibration as cal
 from repro.models import model as M
 from repro.serve.queue import HealRequest, IntegrityRequest, ServeError
 from repro.serve.service import PudService, ServiceConfig
+
+
+#: Widest replica vote an engine-owned service is sized for: MAJ9, the
+#: widest majority the paper's chips perform (Table 1).
+MAX_HEAL_REPLICAS = max(cal.MAJX_MAX_X.values())
+
+
+def _packed_rows(tree) -> int:
+    """Rows of the tile :meth:`Engine._pack_pytree` lays ``tree`` out in,
+    from leaf shapes alone."""
+    from repro.kernels import tiling
+
+    total = sum(-(-leaf.size * leaf.dtype.itemsize // 4)
+                for leaf in jax.tree.leaves(tree))
+    width = max(min(tiling.MAX_BLOCK_C, total), 1)
+    return -(-total // width)
 
 
 class IntegrityContextError(ServeError):
@@ -77,10 +94,14 @@ class Engine:
         # Integrity work runs through a PudService; pass a shared
         # ``pud_service`` to pool votes with other engines/tenants, or
         # let the engine own a single-session service.  The service
-        # defaults to an ideal context (see module docstring).
+        # defaults to an ideal context (see module docstring).  An owned
+        # service's row budget fits a heal of the whole parameter tile
+        # by up to MAX_HEAL_REPLICAS replicas, whatever the model size.
         self.service = pud_service or PudService(ServiceConfig(
             backend=pud_backend,
-            ctx=pud_ctx or ExecutionContext(ideal=True), pool_size=1))
+            ctx=pud_ctx or ExecutionContext(ideal=True), pool_size=1,
+            tenant_rows=max(ServiceConfig.tenant_rows,
+                            (MAX_HEAL_REPLICAS + 1) * _packed_rows(params))))
         self.strict_integrity = strict_integrity
         self.tenant = tenant
         #: Compat: the first pooled session still answers the whole

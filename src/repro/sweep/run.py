@@ -2,7 +2,7 @@
 
 .. code-block:: bash
 
-    # <=16-point executable smoke grid on sim + pallas (interpret):
+    # <=16-point executable smoke grid on sim + pallas:
     python -m repro.sweep.run --smoke
 
     # one paper figure's grid (see --list-figures):
@@ -160,4 +160,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

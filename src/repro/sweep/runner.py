@@ -13,8 +13,9 @@ Execution model:
   (same-shaped chunks share one schedule); when a device mesh is supplied
   the stacked ``(B, X, R, C)`` batch instead goes through the vmapped
   ``majx_batch`` path placed with
-  :func:`repro.dist.sharding.sharding_for` over the mesh's data axis,
-  so the B grid points of the chunk spread across local devices;
+  :func:`repro.dist.sharding.sharding_for` over the mesh's data axis
+  and run under ``jax.shard_map``, so each device votes its own share of
+  the chunk's B grid points;
 * other backends execute point-by-point through the same bulk API;
 * the ``analytic`` pseudo-backend evaluates the calibrated
   :class:`~repro.core.errormodel.ErrorModel` surface — exact at every
@@ -88,6 +89,28 @@ def _success(got, want) -> tuple[float, int]:
     return 1.0 - bad / n_bits, n_bits
 
 
+def sharded_majx_batch(session, mesh, shape: tuple[int, ...]):
+    """``(vote, sharding)``: ``session.majx_batch`` over a ``(B, X, R, C)``
+    batch placed with ``sharding`` across ``mesh``.
+
+    The B axis shards by the ``"batch"`` rule of
+    :mod:`repro.dist.sharding` (replicated where it does not divide).
+    XLA cannot partition a Pallas kernel, so ``vote`` runs the batch
+    under ``jax.shard_map``: each device votes its own shard of grid
+    points, and no collective moves the batch.
+    """
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from repro.dist.sharding import sharding_for
+
+    sharding = sharding_for(shape, ("batch", None, None, None), mesh)
+    vote = jax.shard_map(session.majx_batch, mesh=mesh,
+                         in_specs=sharding.spec,
+                         out_specs=P(sharding.spec[0]), check_vma=False)
+    return vote, sharding
+
+
 def _context(spec: SweepSpec, p: GridPoint) -> ExecutionContext:
     timings = {"majx": dict(majx_t1=p.t1, majx_t2=p.t2),
                "mrc": dict(mrc_t1=p.t1, mrc_t2=p.t2),
@@ -95,8 +118,7 @@ def _context(spec: SweepSpec, p: GridPoint) -> ExecutionContext:
     return ExecutionContext(
         mfr=p.mfr, timings=Timings(**timings), temp_c=p.temp_c,
         vpp_v=p.vpp_v, pattern=p.pattern if p.op == "majx" else "random",
-        ideal=spec.ideal, n_act=p.n_act, interpret=spec.interpret,
-        seed=p.seed)
+        ideal=spec.ideal, n_act=p.n_act, seed=p.seed)
 
 
 def _expected(p: GridPoint) -> float:
@@ -205,7 +227,7 @@ class _Executor:
         the first is a schedule-cache hit.  Under a device mesh the
         stacked batch instead goes through the sharded ``majx_batch``
         path, which places the B grid points across local devices
-        (still one vmapped dispatch).
+        (one vmapped dispatch per device).
         """
         import jax
 
@@ -216,10 +238,9 @@ class _Executor:
                     _rng(self.spec, p)) for p in pts])  # (B, X, R, C)
         sess = self.session(pts[0])
         if self.mesh is not None:
-            from repro.dist.sharding import sharding_for
-            placed = jax.device_put(batch, sharding_for(
-                batch.shape, ("batch", None, None, None), self.mesh))
-            got = np.asarray(sess.majx_batch(placed))    # (B, R, C)
+            vote, sharding = sharded_majx_batch(sess, self.mesh,
+                                                batch.shape)
+            got = np.asarray(vote(jax.device_put(batch, sharding)))
         else:
             prog, out_base = planner.fused_majx_program(pts, rows)
             state = np.concatenate([

@@ -117,7 +117,6 @@ class SweepSpec:
     rows: int = 2
     words: int = 16
     ideal: bool = False
-    interpret: bool = True
     #: grid points per resumable execution chunk (the planner's unit).
     chunk: int = 8
 

@@ -23,6 +23,18 @@ def _all(ctx=IDEAL):
 # ---------------------------------------------------------------- registry
 
 
+def test_pallas_interprets_on_cpu_only():
+    """One rule decides how Pallas kernels run: the interpreter off a
+    TPU (the only way they run on a CPU), compiled on one."""
+    import jax
+
+    from repro.backends.pallas import interpret_mode
+
+    on_tpu = jax.default_backend() == "tpu"
+    assert interpret_mode() is not on_tpu
+    assert get_backend("pallas", IDEAL).interpret is not on_tpu
+
+
 def test_registry_lists_all_three():
     assert set(BACKENDS) <= set(available_backends())
 

@@ -16,7 +16,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, jax
 from repro.configs.registry import get_config, SHAPES
 from repro.launch.mesh import make_mesh
-from repro.launch.dryrun import _cost_analysis, lower_cell
+from repro.launch.dryrun import lower_cell
 from repro.launch import roofline as rl
 
 cfg = get_config("xlstm-125m", smoke=True)
@@ -26,7 +26,7 @@ mesh = make_mesh((4, 2), ("data", "model"))
 lowered, compiled = lower_cell(cfg, shape, mesh)
 mem = compiled.memory_analysis()
 coll = rl.collective_bytes(compiled.as_text(), loop_multiplier=cfg.n_layers)
-ca = _cost_analysis(compiled)
+ca = compiled.cost_analysis()
 print(json.dumps({
     "temp_gb": mem.temp_size_in_bytes / 2**30,
     "flops": ca.get("flops", 0.0),

@@ -36,7 +36,7 @@ def test_elastic_restart_from_checkpoint(tmp_path):
 
 def test_serve_rules_swap_batch_mapping():
     mesh = make_test_mesh(model=1)
-    with mesh:
+    with jax.set_mesh(mesh):
         assert axis_extent("batch", DEFAULT_RULES) >= 1
         with use_rules(SERVE_RULES):
             assert axis_extent("batch") == 1       # replicated

@@ -22,7 +22,8 @@ from repro.kernels.rowcopy.ref import fanout_ref
 def test_majx_kernel_shapes(n, shape):
     rng = np.random.default_rng((n, *shape))
     x = jnp.asarray(rand_u32(rng, n, *shape))
-    assert (np.asarray(majx(x)) == np.asarray(majx_ref(x))).all()
+    assert (np.asarray(majx(x, interpret=True))
+            == np.asarray(majx_ref(x))).all()
 
 
 @sweep(6)
@@ -33,7 +34,7 @@ def test_majx_kernel_random_blocks(rng):
     x = jnp.asarray(rand_u32(rng, n, r, c))
     br = int(rng.choice([8, 16]))
     bc = int(rng.choice([128, 256, 512]))
-    got = majx(x, block_r=br, block_c=bc)
+    got = majx(x, block_r=br, block_c=bc, interpret=True)
     assert (np.asarray(got) == np.asarray(majx_ref(x))).all()
 
 
@@ -46,7 +47,7 @@ def test_bitserial_add_widths(nbits):
         nbits, -1)
     pb = bp.pack_uint_elements(jnp.asarray(b.reshape(-1)), nbits).reshape(
         nbits, -1)
-    got = bitserial_add(pa, pb)
+    got = bitserial_add(pa, pb, interpret=True)
     want = bitserial_add_ref(pa, pb)
     assert (np.asarray(got) == np.asarray(want)).all()
 
@@ -55,7 +56,7 @@ def test_bitserial_add_widths(nbits):
 def test_add_u32_matches_numpy(rng):
     k = int(rng.integers(1, 700))
     a, b = rand_u32(rng, k), rand_u32(rng, k)
-    got = np.asarray(add_u32(a, b))
+    got = np.asarray(add_u32(a, b, interpret=True))
     assert (got == (a + b)).all()
 
 
@@ -63,7 +64,7 @@ def test_add_u32_matches_numpy(rng):
 def test_fanout_kernel(fanout_n):
     rng = np.random.default_rng(fanout_n)
     src = jnp.asarray(rand_u32(rng, 9, 300))
-    got = fanout(src, fanout_n)
+    got = fanout(src, fanout_n, interpret=True)
     want = fanout_ref(src, fanout_n)
     assert got.shape == (fanout_n, 9, 300)
     assert (np.asarray(got) == np.asarray(want)).all()
@@ -73,10 +74,11 @@ def test_fanout_kernel(fanout_n):
 def test_mismatch_kernel(rng):
     n = int(rng.integers(1, 3000))
     g, w = rand_u32(rng, n), rand_u32(rng, n)
-    got = int(mismatch_count(jnp.asarray(g), jnp.asarray(w)))
+    got = int(mismatch_count(jnp.asarray(g), jnp.asarray(w),
+                             interpret=True))
     want = int(mismatch_count_ref(jnp.asarray(g), jnp.asarray(w)))
     assert got == want
-    assert success_rate(g, g) == 1.0
+    assert success_rate(g, g, interpret=True) == 1.0
 
 
 def test_vote_kernel_heals_corruption():
@@ -87,7 +89,7 @@ def test_vote_kernel_heals_corruption():
     key = jax.random.PRNGKey(2)
     x = jax.random.normal(key, (513,), jnp.float32)
     reps = [corrupt(x, jax.random.fold_in(key, i), 1e-3) for i in range(3)]
-    healed = vote(reps)
+    healed = vote(reps, interpret=True)
     assert (np.asarray(healed) == np.asarray(x)).all()
 
 
@@ -97,5 +99,5 @@ def test_vote_kernel_bf16():
     key = jax.random.PRNGKey(1)
     x = jax.random.normal(key, (257,), jnp.float32).astype(jnp.bfloat16)
     reps = [corrupt(x, jax.random.fold_in(key, i), 5e-4) for i in range(5)]
-    healed = vote(reps)
+    healed = vote(reps, interpret=True)
     assert (np.asarray(healed) == np.asarray(x)).all()

@@ -206,7 +206,8 @@ def test_lowering_is_state_height_independent():
     low = lower_schedule(build_schedule(prog))
     for rows in (4, 9, 40):
         state = rand_u32(np.random.default_rng(rows), rows, WORDS)
-        got = np.asarray(run_lowering(low, jnp.asarray(state)))
+        got = np.asarray(run_lowering(low, jnp.asarray(state),
+                                      interpret=True))
         want = _oracle_want(prog, jnp.asarray(state))
         assert (got == want).all(), rows
 
@@ -216,7 +217,24 @@ def test_run_lowering_rejects_short_state():
     prog.emit("MAJ", x=3, n_act=4, srcs=(0, 1, 2), dsts=(9,))
     low = lower_schedule(build_schedule(prog))
     with pytest.raises(ValueError, match="addresses 10 rows"):
-        run_lowering(low, jnp.zeros((4, 4), jnp.uint32))
+        run_lowering(low, jnp.zeros((4, 4), jnp.uint32),
+                     interpret=True)
+
+
+def test_run_lowering_rejects_tables_beyond_smem():
+    """Tables that would not fit the chip's SMEM fail loudly, on every
+    platform, instead of running some other executor."""
+    from repro.compile.megakernel import MegaLowering
+    from repro.kernels.megakernel.ops import SMEM_TABLE_LIMIT_BYTES
+
+    levels = SMEM_TABLE_LIMIT_BYTES // (4 * 3 * 8) + 1
+    low = MegaLowering(src=np.zeros((levels, 8, 3), np.int32),
+                       dst=np.full((levels, 8), 2, np.int32),
+                       inv=np.zeros((levels, 8), np.uint32),
+                       n_rows=1, level_meta=())
+    assert low.table_bytes > SMEM_TABLE_LIMIT_BYTES
+    with pytest.raises(ValueError, match="SMEM_TABLE_LIMIT_BYTES"):
+        run_lowering(low, jnp.zeros((4, 128), jnp.uint32), interpret=True)
 
 
 # ----------------------------------------- numpy ref vs the Pallas kernel
@@ -233,7 +251,8 @@ def test_numpy_ref_executor_matches_pallas_kernel(rng):
     ref = schedule_exec_ref(low, state)
     assert (ref == want).all()
     if low.n_levels:
-        krn = np.asarray(run_lowering(low, jnp.asarray(state)))
+        krn = np.asarray(run_lowering(low, jnp.asarray(state),
+                                      interpret=True))
         assert (krn == ref).all()
 
 

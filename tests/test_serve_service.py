@@ -342,6 +342,27 @@ def test_engine_heal_and_verify_through_service(make_tiny_pud_engine):
     assert eng.service.snapshot().tenants["engine"]["completed"] == 2
 
 
+def test_engine_heals_params_beyond_default_arena():
+    """An engine-owned service sizes its row budget from the params: a
+    17 MiB tree packs into 1,088 rows of 4,096 words, and a 3-replica
+    heal needs 4 x 1,088 rows, past the default 4,096-row budget."""
+    from repro.configs.registry import get_config
+    from repro.serve import ServiceConfig
+    from repro.serve.engine import Engine
+
+    rng = np.random.default_rng(17)
+    clean = {"w": rng.integers(0, 2**32, 17 * 2**20 // 4, dtype=np.uint32)}
+    bad = {"w": clean["w"].copy()}
+    bad["w"][::100_003] ^= np.uint32(1 << 7)
+    flipped = int(np.bitwise_count(bad["w"] ^ clean["w"]).sum())
+    eng = Engine(clean, get_config("xlstm-125m", smoke=True),
+                 pud_backend="oracle")
+    assert 4 * 1088 > ServiceConfig.tenant_rows
+    assert eng.heal_params([bad, clean, dict(clean)]) == flipped
+    assert (np.asarray(eng.params["w"]) == clean["w"]).all()
+    assert eng.verify_params(clean) == 1.0
+
+
 def test_engine_warns_on_non_ideal_context(make_tiny_pud_engine):
     from repro.serve.engine import IntegrityContextWarning
 

@@ -95,6 +95,23 @@ def test_tmr_store_heals_corrupted_replica(tmp_path):
         assert (np.asarray(a, np.float32) == np.asarray(b, np.float32)).all()
 
 
+def test_tmr_store_kernel_vote_heals_corrupted_replica(tmp_path):
+    """The MAJX-kernel restore path picks its own kernel mode."""
+    cfg, tc, loader = _small()
+    state, _ = init_train_state(jax.random.PRNGKey(0), cfg)
+    tmr_store.save(state, str(tmp_path), 3, replicas=3)
+    shard = os.path.join(str(tmp_path), "replica_2", "step_00000003",
+                         "shard_p0.npz")
+    blob = bytearray(open(shard, "rb").read())
+    blob[len(blob) // 2] ^= 0xFF
+    open(shard, "wb").write(bytes(blob))
+    restored, step, _ = tmr_store.restore(state, str(tmp_path),
+                                          use_kernel=True)
+    assert step == 3
+    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(restored)):
+        assert (np.asarray(a, np.float32) == np.asarray(b, np.float32)).all()
+
+
 def test_trainer_restarts_after_failure(tmp_path):
     cfg, tc, loader = _small()
     t = Trainer(cfg, tc, loader,
