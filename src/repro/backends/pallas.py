@@ -19,6 +19,10 @@ Multi-RowCopy dispatch, while NOT/COPY levels are pure gather/scatter.
 lowers to static level tables (:mod:`repro.compile.megakernel`) that
 ONE ``pallas_call`` scans end-to-end, VMEM-resident, column-blocked
 against ``Capabilities.vmem_budget_bytes`` when the image is too wide.
+:meth:`run_fused` opens the host span ``pud/backend.run_fused``
+(:mod:`repro.obs`) around the level executor in both modes: the image
+upload and, per level, the gather, kernel and scatter glue (fused), or
+the one megakernel launch with its padding and crop.
 ``self.dispatch_count`` tracks real kernel launches, which is the
 structural metric ``benchmarks/bench.py`` and the CI perf gate assert
 on; each launch also accrues :data:`repro.core.costmodel.COST`-priced
@@ -36,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.backends.base import Backend, Capabilities
 from repro.backends.context import ExecutionContext
 from repro.core import calibration as cal
@@ -134,19 +139,20 @@ class PallasBackend(Backend):
         """
         from repro.compile.schedule import build_schedule
 
-        if mode == "megakernel":
-            return self.run_megakernel(program, state, sched=sched,
-                                       lowering=lowering)
-        if mode != "fused":
-            raise ValueError(f"unknown run_fused mode {mode!r}")
-        if sched is None:
-            sched = build_schedule(program)
-        state = jnp.asarray(state, jnp.uint32)
-        for level in sched.levels:
-            entry = state
-            for group in level:
-                state = self._exec_group(group, entry, state)
-        return state
+        with obs.span("backend.run_fused"):
+            if mode == "megakernel":
+                return self.run_megakernel(program, state, sched=sched,
+                                           lowering=lowering)
+            if mode != "fused":
+                raise ValueError(f"unknown run_fused mode {mode!r}")
+            if sched is None:
+                sched = build_schedule(program)
+            state = jnp.asarray(state, jnp.uint32)
+            for level in sched.levels:
+                entry = state
+                for group in level:
+                    state = self._exec_group(group, entry, state)
+            return state
 
     def run_megakernel(self, program: Program, state: jax.Array, *,
                        sched=None, lowering=None) -> jax.Array:

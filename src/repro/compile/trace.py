@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import bitplanes as bp
 from repro.pud.isa import Program
 
@@ -44,7 +45,8 @@ class Tracer:
     # ------------------------------------------------------------- rows
     @staticmethod
     def _key(plane) -> bytes:
-        return np.asarray(plane, np.uint32).tobytes()
+        with obs.span("compile.sync"):
+            return np.asarray(plane, np.uint32).tobytes()
 
     @property
     def n_rows(self) -> int:
@@ -57,7 +59,8 @@ class Tracer:
         row = self._table.get(key)
         if row is None:
             row = len(self._init)
-            self._init.append(np.asarray(plane, np.uint32).copy())
+            with obs.span("compile.sync"):
+                self._init.append(np.asarray(plane, np.uint32).copy())
             self._table[key] = row
         return row
 
@@ -151,29 +154,30 @@ def compile_elementwise(op: str, a, b, tier: int = 3, n_act: int = 4
     program executes through :meth:`Backend.run_fused` in level-batched
     kernel dispatches instead of one launch per gate.
     """
-    a = jnp.asarray(a, jnp.uint32).reshape(-1)
-    b = jnp.asarray(b, jnp.uint32).reshape(-1)
-    k = int(a.shape[0])
-    A = bp.pack_uint_elements(a)
-    B = bp.pack_uint_elements(b)
+    with obs.span("compile"):
+        a = jnp.asarray(a, jnp.uint32).reshape(-1)
+        b = jnp.asarray(b, jnp.uint32).reshape(-1)
+        k = int(a.shape[0])
+        A = bp.pack_uint_elements(a)
+        B = bp.pack_uint_elements(b)
 
-    def build(bs):
-        if op == "and":
-            return [bs.and_(A[i], B[i]) for i in range(A.shape[0])]
-        if op == "or":
-            return [bs.or_(A[i], B[i]) for i in range(A.shape[0])]
-        if op == "xor":
-            return [bs.xor(A[i], B[i]) for i in range(A.shape[0])]
-        if op == "add":
-            return list(bs.add(A, B)[0])
-        if op == "sub":
-            return list(bs.sub(A, B)[0])
-        if op == "mul":
-            return list(bs.mul(A, B))
-        if op == "div":
-            return list(bs.div(A, B)[0])
-        raise ValueError(f"unknown op {op!r}")
+        def build(bs):
+            if op == "and":
+                return [bs.and_(A[i], B[i]) for i in range(A.shape[0])]
+            if op == "or":
+                return [bs.or_(A[i], B[i]) for i in range(A.shape[0])]
+            if op == "xor":
+                return [bs.xor(A[i], B[i]) for i in range(A.shape[0])]
+            if op == "add":
+                return list(bs.add(A, B)[0])
+            if op == "sub":
+                return list(bs.sub(A, B)[0])
+            if op == "mul":
+                return list(bs.mul(A, B))
+            if op == "div":
+                return list(bs.div(A, B)[0])
+            raise ValueError(f"unknown op {op!r}")
 
-    cp = trace_planes(build, tier=tier, n_act=n_act)
+        cp = trace_planes(build, tier=tier, n_act=n_act)
     cp.n_lanes = k
     return cp

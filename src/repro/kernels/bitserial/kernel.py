@@ -24,6 +24,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+#: The ``pallas_call``'s name.  Its device op shows in a profiler
+#: trace as ``bitserial_add.<n>``, whatever module runs it.
+KERNEL_NAME = "bitserial_add"
+
+
 def bitserial_add_kernel(a_ref, b_ref, o_ref, *, nbits: int):
     carry = jnp.zeros_like(a_ref[0])
     for i in range(nbits):
@@ -54,4 +59,5 @@ def bitserial_add_pallas(
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((nbits, r, c), jnp.uint32),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(a, b)

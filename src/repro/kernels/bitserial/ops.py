@@ -1,4 +1,8 @@
-"""jit'd wrapper: bit-serial add on packed planes or uint element vectors."""
+"""Wrapper: bit-serial add on packed planes or uint element vectors.
+
+The wrappers are not jitted: packing, pad and crop run as eager ops
+around the jitted ``bitserial_add_pallas`` at every call.
+"""
 
 from __future__ import annotations
 

@@ -23,6 +23,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+#: The ``pallas_call``'s name.  Its device op shows in a profiler
+#: trace as ``majx_csa.<n>``, whatever module runs it.
+KERNEL_NAME = "majx_csa"
+
+
 def _csa_accumulate(planes):
     """Bit-sliced counter: returns digit planes [c0, c1, ...] (LSB first)."""
     max_digits = len(planes).bit_length()
@@ -90,4 +95,5 @@ def majx_pallas(
         out_specs=pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c), jnp.uint32),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(planes)

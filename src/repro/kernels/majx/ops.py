@@ -1,4 +1,8 @@
-"""jit'd public wrapper for the bulk MAJX kernel (+ TMR vote entry point)."""
+"""Public wrapper for the bulk MAJX kernel (+ TMR vote entry point).
+
+The wrapper is not jitted: its pad and crop run as eager ops around the
+jitted ``majx_pallas`` at every call.
+"""
 
 from __future__ import annotations
 

@@ -42,6 +42,11 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.majx.kernel import _csa_accumulate, _ge_threshold
 
 
+#: The ``pallas_call``'s name.  Its device op shows in a profiler
+#: trace as ``megakernel_levels.<n>``, whatever module runs it.
+KERNEL_NAME = "megakernel_levels"
+
+
 def schedule_kernel(src_ref, dst_ref, inv_ref, x_ref, o_ref, vote_ref, *,
                     n_levels: int, w: int, x: int):
     """Execute all levels against one (rows_aug, block_c) column slab.
@@ -113,4 +118,5 @@ def schedule_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows_aug, c), jnp.uint32),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(src, dst, inv, state)

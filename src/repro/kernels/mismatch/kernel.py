@@ -18,6 +18,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+#: The ``pallas_call``'s name.  Its device op shows in a profiler
+#: trace as ``mismatch_popcount.<n>``, whatever module runs it.
+KERNEL_NAME = "mismatch_popcount"
+
+
 def mismatch_kernel(g_ref, w_ref, o_ref):
     i = pl.program_id(0)
     j = pl.program_id(1)
@@ -56,4 +61,5 @@ def mismatch_pallas(
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(got, want)[0, 0]

@@ -1,4 +1,8 @@
-"""jit'd wrapper for the mismatch/success-rate kernel."""
+"""Wrapper for the mismatch/success-rate kernel.
+
+The wrapper is not jitted: its reshape and pad run as eager ops around
+the jitted ``mismatch_pallas`` at every call.
+"""
 
 from __future__ import annotations
 

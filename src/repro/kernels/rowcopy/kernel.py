@@ -18,6 +18,11 @@ import jax
 from jax.experimental import pallas as pl
 
 
+#: The ``pallas_call``'s name.  Its device op shows in a profiler
+#: trace as ``rowcopy_fanout.<n>``, whatever module runs it.
+KERNEL_NAME = "rowcopy_fanout"
+
+
 def fanout_kernel(src_ref, o_ref):
     o_ref[...] = src_ref[...][None]
 
@@ -46,4 +51,5 @@ def fanout_pallas(
         out_specs=pl.BlockSpec((1, block_r, block_c), lambda k, i, j: (k, i, j)),
         out_shape=jax.ShapeDtypeStruct((fanout, r, c), src.dtype),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(src)

@@ -1,4 +1,8 @@
-"""jit'd wrapper for the Multi-RowCopy fan-out kernel."""
+"""Wrapper for the Multi-RowCopy fan-out kernel.
+
+The wrapper is not jitted: its pad and crop run as eager ops around the
+jitted ``fanout_pallas`` at every call.
+"""
 
 from __future__ import annotations
 

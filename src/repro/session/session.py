@@ -31,6 +31,7 @@ from typing import Optional, Sequence, Union
 import jax
 import numpy as np
 
+from repro import obs
 from repro.backends import Backend, ExecutionContext, resolve_backend
 from repro.compile.schedule import Schedule
 from repro.pud.isa import Program
@@ -115,21 +116,22 @@ class DramSession:
         :class:`~repro.analyze.cert.CertificationError` if the compiled
         schedule or level tables ever diverge from program dataflow.
         """
-        key = program_key(program)
-        self._validate(program, state, key)
-        sched = self.cache.schedule_for(program, key=key)
-        lowering = None
-        if mode == "megakernel" and self.capabilities().megakernel:
-            lowering = self.cache.lowering_for(program, key=key,
-                                               sched=sched)
-        if self.ctx.certify:
-            # Static race/liveness/equivalence certification of the
-            # exact artifacts about to execute; content-cached, so a
-            # repeated program is a dictionary hit, not a re-analysis.
-            self.cache.certificate_for(program, key=key, sched=sched,
-                                       lowering=lowering)
-        return self.backend.run_fused(program, state, sched=sched,
-                                      mode=mode, lowering=lowering)
+        with obs.span("session.run_fused"):
+            key = program_key(program)
+            self._validate(program, state, key)
+            sched = self.cache.schedule_for(program, key=key)
+            lowering = None
+            if mode == "megakernel" and self.capabilities().megakernel:
+                lowering = self.cache.lowering_for(program, key=key,
+                                                   sched=sched)
+            if self.ctx.certify:
+                # Static race/liveness/equivalence certification of the
+                # exact artifacts about to execute; content-cached, so a
+                # repeated program is a dictionary hit, not a re-analysis.
+                self.cache.certificate_for(program, key=key, sched=sched,
+                                           lowering=lowering)
+            return self.backend.run_fused(program, state, sched=sched,
+                                          mode=mode, lowering=lowering)
 
     # --------------------------------------------- §8.1 compiled arithmetic
     def elementwise(self, op: str, a, b, tier: Optional[int] = None,
@@ -140,9 +142,10 @@ class DramSession:
         :meth:`run_fused` — i.e. through the compile cache."""
         from repro.pud.arith import run_elementwise
 
-        return run_elementwise(
-            op, a, b, tier=tier or self.ctx.tier,
-            n_act=n_act or self.ctx.n_act, executor=self)
+        with obs.span("elementwise"):
+            return run_elementwise(
+                op, a, b, tier=tier or self.ctx.tier,
+                n_act=n_act or self.ctx.n_act, executor=self)
 
     # ------------------------------------------------------ bulk delegation
     def capabilities(self):
