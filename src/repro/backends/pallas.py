@@ -15,14 +15,23 @@ with the :mod:`repro.compile` schedule — every dependency level of the
 program becomes at most one MAJX dispatch (mixed arities padded with
 constant 0/1 plane pairs, an exact identity) plus at most one
 Multi-RowCopy dispatch, while NOT/COPY levels are pure gather/scatter.
+The level walk is written once, as a pure function of the image over
+the group indices the backend builds once per schedule, and runs two
+ways.  The first time the backend sees a schedule it runs eagerly, op by
+op, so a program that runs once pays no XLA compile.  The second time,
+the walk is jitted with its indices baked in as constants, and from
+then on each call is one dispatch of that compiled program.  The
+backend keeps the walks of its last ``WALK_CACHE_SIZE`` schedules.
 ``run_fused(mode="megakernel")`` goes further: the whole schedule
 lowers to static level tables (:mod:`repro.compile.megakernel`) that
 ONE ``pallas_call`` scans end-to-end, VMEM-resident, column-blocked
 against ``Capabilities.vmem_budget_bytes`` when the image is too wide.
 :meth:`run_fused` opens the host span ``pud/backend.run_fused``
 (:mod:`repro.obs`) around the level executor in both modes: the image
-upload and, per level, the gather, kernel and scatter glue (fused), or
-the one megakernel launch with its padding and crop.
+upload and the level walk (fused), or the one megakernel launch with
+its padding and crop; inside it, ``pud/backend.levels_build`` wraps
+building and first running a jitted walk, and ``pud/backend.levels_jit``
+each later dispatch of one.
 ``self.dispatch_count`` tracks real kernel launches, which is the
 structural metric ``benchmarks/bench.py`` and the CI perf gate assert
 on; each launch also accrues :data:`repro.core.costmodel.COST`-priced
@@ -33,7 +42,11 @@ joules too.
 
 from __future__ import annotations
 
+import collections
+import copy
+import dataclasses
 import functools
+import threading
 from typing import Optional
 
 import jax
@@ -67,6 +80,9 @@ class PallasBackend(Backend):
     def __init__(self, ctx: Optional[ExecutionContext] = None):
         super().__init__(ctx)
         self.interpret = interpret_mode()
+        #: Schedule -> :class:`_LevelWalk`, least recently used first.
+        self._walks: collections.OrderedDict = collections.OrderedDict()
+        self._walks_lock = threading.Lock()
 
     def capabilities(self) -> Capabilities:
         return Capabilities(
@@ -134,6 +150,13 @@ class PallasBackend(Backend):
         rows.  Prebuilt ``sched`` / ``lowering`` artifacts (the session
         compile cache) skip the scheduling and lowering passes entirely.
 
+        Fused mode runs the schedule's level walk (:meth:`_walk`): eager
+        the first time the backend sees the schedule, then as one jitted
+        function, built on the second sighting (span
+        ``pud/backend.levels_build``) and dispatched from then on (span
+        ``pud/backend.levels_jit``).  Either way ``dispatch_count`` and
+        ``energy_nj_total`` advance by one launch per MAJ/MRC group.
+
         ``mode="megakernel"`` routes to :meth:`run_megakernel` — the
         whole schedule in one dispatch.
         """
@@ -148,11 +171,73 @@ class PallasBackend(Backend):
             if sched is None:
                 sched = build_schedule(program)
             state = jnp.asarray(state, jnp.uint32)
-            for level in sched.levels:
-                entry = state
-                for group in level:
-                    state = self._exec_group(group, entry, state)
-            return state
+            walk, first = self._level_walk(sched)
+            if walk.max_row >= state.shape[0]:
+                raise ValueError(
+                    f"program addresses row {walk.max_row} of a "
+                    f"{state.shape[0]}-row image")
+            if first:
+                return self._walk(walk, state)
+            if walk.jitted is None:
+                with obs.span("backend.levels_build"):
+                    # Traced on a copy of the backend: the launches it
+                    # counts while tracing are dropped, and each call's
+                    # are replayed on this one below.
+                    walk.jitted = jax.jit(
+                        functools.partial(copy.copy(self)._walk, walk))
+                    out = walk.jitted(state)
+            else:
+                with obs.span("backend.levels_jit"):
+                    out = walk.jitted(state)
+            width = state.shape[1]
+            for words in walk.launch_words:
+                self._launch(words * width * 4)
+            return out
+
+    def _level_walk(self, sched) -> tuple["_LevelWalk", bool]:
+        """The schedule's :class:`_LevelWalk` from the LRU, built and
+        admitted on its first sighting (then ``True``)."""
+        with self._walks_lock:
+            walk = self._walks.get(sched)
+            if walk is not None:
+                self._walks.move_to_end(sched)
+                return walk, False
+            walk = self._walks[sched] = _LevelWalk(sched)
+            while len(self._walks) > WALK_CACHE_SIZE:
+                self._walks.popitem(last=False)
+            return walk, True
+
+    def _walk(self, walk: "_LevelWalk", state: jax.Array) -> jax.Array:
+        """Every level of the schedule, a pure function of ``state``.
+
+        Runs eagerly or under ``jax.jit`` alike: the group indices are
+        NumPy constants of ``walk``.  The all-0 and all-1 rows that pad
+        narrow MAJ ops are appended below the image once, as rows -2 and
+        -1, and cropped at the end; no ``dst`` addresses them.  Each
+        group gathers from the level-entry image and scatters into the
+        running one.
+        """
+        rows, width = state.shape
+        image = jnp.concatenate([
+            state,
+            jnp.zeros((1, width), jnp.uint32),
+            jnp.full((1, width), 0xFFFFFFFF, jnp.uint32)])
+        for level in walk.levels:
+            entry = image
+            for g in level:
+                if g.kind == "MAJ":
+                    # (B, X) gather -> (X, B, W): one MAJX launch.
+                    batch = jnp.swapaxes(entry[g.srcs], 0, 1)
+                    vals = self.majx(batch)[g.sel]
+                elif g.kind == "MRC":
+                    copies = self.rowcopy(entry[g.srcs], g.param)
+                    vals = copies[g.sel_copy, g.sel]
+                elif g.kind == "NOT":
+                    vals = self._not(entry[g.srcs])
+                else:
+                    vals = self._copy(entry[g.srcs])
+                image = image.at[g.dsts].set(vals)
+        return image[:rows]
 
     def run_megakernel(self, program: Program, state: jax.Array, *,
                        sched=None, lowering=None) -> jax.Array:
@@ -183,78 +268,82 @@ class PallasBackend(Backend):
         return run_lowering(lowering, state, block_c=plan.block_c,
                             interpret=self.interpret)
 
-    def _exec_group(self, group, entry: jax.Array,
-                    state: jax.Array) -> jax.Array:
-        if group.kind == "MAJ":
-            return self._fused_maj(group, entry, state)
-        if group.kind == "MRC":
-            return self._fused_mrc(group, entry, state)
-        # NOT / COPY: one gather (+ complement) + scatter, no kernel.
-        srcs = np.array([op.srcs[0] for op in group.ops
-                         for _ in op.dsts])
-        dsts = np.array([d for op in group.ops for d in op.dsts])
-        vals = entry[srcs]
-        if group.kind == "NOT":
-            vals = self._not(vals)
-        else:
-            vals = self._copy(vals)
-        return state.at[dsts].set(vals)
 
-    def _fused_maj(self, group, entry: jax.Array,
-                   state: jax.Array) -> jax.Array:
-        """All MAJ ops of a level in ONE kernel dispatch.
+#: Level walks a backend keeps (schedules seen, most recent last); the
+#: same bound as the session's ``CompileCache``.
+WALK_CACHE_SIZE = 128
 
-        Narrower ops are padded to the level's widest arity X with
-        constant (all-0, all-1) plane *pairs* — each pair adds one to
-        the popcount and one to the majority threshold, so
-        ``MAJ_k(x..) == MAJ_X(x.., 0*m, 1*m)`` exactly.  The batch is
-        laid out (X, B, W): every op is one row-image of the tile, so a
-        single non-vmapped MAJX launch covers the whole level with
-        minimal VPU padding.
-        """
+#: Rows of the constant planes the walk appends below the image.
+ZERO_ROW, ONE_ROW = -2, -1
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class _GroupPlan:
+    """One schedule group's gather and scatter indices.
+
+    MAJ: ``srcs`` is the (B, X) source matrix, narrower ops padded to
+    the group's arity X with constant (all-0, all-1) plane *pairs*, each
+    pair adding one to the popcount and one to the majority threshold,
+    so ``MAJ_k(x..) == MAJ_X(x.., 0*m, 1*m)`` exactly; ``sel`` is the op
+    each of ``dsts`` takes.  MRC: ``srcs`` is the (B,) source rows, one
+    fan-out to the widest destination count serves them all, and each
+    op scatters the prefix of copies its own ``dsts`` ask for (copies
+    are identical, so a prefix is exact): ``sel_copy`` / ``sel`` pick
+    (copy, op) per dst.  NOT / COPY: ``srcs`` is one source row per dst.
+    ``launch_words`` is the kernel's operand + result words per image
+    word (0 without a kernel).
+    """
+
+    kind: str
+    param: int
+    srcs: np.ndarray
+    dsts: np.ndarray
+    sel: Optional[np.ndarray] = None
+    sel_copy: Optional[np.ndarray] = None
+    launch_words: int = 0
+
+
+def _plan_group(group) -> _GroupPlan:
+    ops = group.ops
+    dsts = np.array([d for op in ops for d in op.dsts])
+    sel = np.array([i for i, op in enumerate(ops) for _ in op.dsts])
+    if group.kind == "MAJ":
         x_max = group.param
-        width = entry.shape[-1]
-        # Augment the image with one all-0 and one all-1 row, then build
-        # the whole (B, X) source-index matrix on the host: padding slots
-        # point at the constant rows, and a single fancy-index gather
-        # assembles the batch (no per-op jnp traffic).
-        zero_row, one_row = entry.shape[0], entry.shape[0] + 1
-        aug = jnp.concatenate([
-            entry,
-            jnp.zeros((1, width), jnp.uint32),
-            jnp.full((1, width), 0xFFFFFFFF, jnp.uint32)])
-        idx = np.empty((len(group.ops), x_max), np.int32)
-        for i, op in enumerate(group.ops):
+        srcs = np.empty((len(ops), x_max), np.int32)
+        for i, op in enumerate(ops):
             k = len(op.srcs)
             if (x_max - k) % 2:
                 raise ValueError(
                     f"cannot pad MAJ{k} to MAJ{x_max}: parity differs")
             pad = (x_max - k) // 2
-            idx[i, :k] = op.srcs
-            idx[i, k:k + pad] = zero_row
-            idx[i, k + pad:] = one_row
-        batch = jnp.swapaxes(aug[idx], 0, 1)          # (X, B, W)
-        out = self.majx(batch)                        # (B, W), 1 dispatch
-        dsts = np.array([d for op in group.ops for d in op.dsts])
-        sel = np.array([i for i, op in enumerate(group.ops)
-                        for _ in op.dsts])
-        return state.at[dsts].set(out[sel])
+            srcs[i, :k] = op.srcs
+            srcs[i, k:k + pad] = ZERO_ROW
+            srcs[i, k + pad:] = ONE_ROW
+        return _GroupPlan("MAJ", x_max, srcs, dsts, sel=sel,
+                          launch_words=len(ops) * (x_max + 1))
+    if group.kind == "MRC":
+        sel_copy = np.array([j for op in ops for j in range(len(op.dsts))])
+        return _GroupPlan("MRC", group.param,
+                          np.array([op.srcs[0] for op in ops]), dsts,
+                          sel=sel, sel_copy=sel_copy,
+                          launch_words=len(ops) * (1 + group.param))
+    # NOT / COPY: one gather (+ complement) + scatter, no kernel.
+    return _GroupPlan(group.kind, group.param,
+                      np.array([op.srcs[0] for op in ops
+                                for _ in op.dsts]), dsts)
 
-    def _fused_mrc(self, group, entry: jax.Array,
-                   state: jax.Array) -> jax.Array:
-        """All Multi-RowCopy ops of a level in ONE fan-out dispatch.
 
-        Sources stack into a (B, W) block treated as one (R=B, C=W)
-        image; a single fan-out to the widest destination count yields
-        (n, B, W), and each op scatters the prefix of copies its own
-        ``dsts`` ask for (copies are identical, so a prefix is exact).
-        """
-        n_max = group.param
-        srcs = np.array([op.srcs[0] for op in group.ops])
-        copies = self.rowcopy(entry[srcs], n_max)     # (n_max, B, W)
-        dsts = np.array([d for op in group.ops for d in op.dsts])
-        sel_copy = np.array([j for op in group.ops
-                             for j in range(len(op.dsts))])
-        sel_op = np.array([i for i, op in enumerate(group.ops)
-                           for _ in op.dsts])
-        return state.at[dsts].set(copies[sel_copy, sel_op])
+class _LevelWalk:
+    """A schedule's group plans, built once, and its jitted walk, built
+    on the schedule's second sighting."""
+
+    def __init__(self, sched):
+        self.levels = tuple(tuple(_plan_group(g) for g in level)
+                            for level in sched.levels)
+        groups = [g for level in self.levels for g in level]
+        #: Per MAJ/MRC group in walk order: what one launch moves.
+        self.launch_words = tuple(g.launch_words for g in groups
+                                  if g.launch_words)
+        self.max_row = max((int(a.max()) for g in groups
+                            for a in (g.srcs, g.dsts)), default=-1)
+        self.jitted = None

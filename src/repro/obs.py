@@ -11,8 +11,9 @@ device: a span's duration is host time, and device time comes from the
 device's own lines of the same trace.
 
 The spans, with their names stable.  Each opens once per call at its
-site, except ``compile.sync``, which opens once per host read; they nest
-on the calling thread.  The metric that reads each is a reader of the
+site, except ``compile.sync``, which opens once per host read, and the
+two ``backend.levels_*`` spans, of which a fused call opens at most one;
+they nest on the calling thread.  The metric that reads each is a reader of the
 benchmark (``benchmarks/chip/metrics``).
 
 ``pud/elementwise``
@@ -35,9 +36,19 @@ benchmark (``benchmarks/chip/metrics``).
     backend's span).
 ``pud/backend.run_fused``
     Opens in ``PallasBackend.run_fused``, fused mode and the megakernel
-    route alike: the level executor, from the image upload through each
-    level's gather, kernel and scatter.  Read by ``level_exec_ms.arith``
-    (its time).
+    route alike: the level executor, the image upload and the level walk
+    (eager, op by op, or one dispatch of the jitted walk).  Read by
+    ``level_exec_ms.arith`` (its time).
+``pud/backend.levels_build``
+    Opens inside ``pud/backend.run_fused`` on the second sighting of a
+    schedule in fused mode: building its jitted level walk (trace and
+    XLA compile) and the first run of it.  Read by no metric: the
+    benchmark's cells pay it in set-up.
+``pud/backend.levels_jit``
+    Opens inside ``pud/backend.run_fused`` around each later dispatch of
+    a jitted level walk.  Read by ``level_jit_per_call.arith`` (its
+    count).  A fused call that opens neither ran the walk eagerly: the
+    schedule's first sighting.
 
 The kernels' device time is read from the name each ``pallas_call``
 gives its device op (``KERNEL_NAME`` in each kernel module of

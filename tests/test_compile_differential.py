@@ -14,6 +14,7 @@ import os
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from _proptest import rand_u32, sweep
 from repro.backends import ExecutionContext, get_backend
@@ -219,6 +220,133 @@ def test_fused_elementwise_matches_per_gate_recording():
     assert prog_p.histogram() == prog_o.histogram()
     assert all(op.dsts for op in prog_p.ops)      # addressed
     assert not any(op.dsts for op in prog_o.ops)  # cost-only
+
+
+# ------------------------------------------------- the jitted level walk
+
+
+def _aliasing_program() -> Program:
+    prog = Program()
+    prog.emit("MAJ", x=3, n_act=4, srcs=(0, 1, 2), dsts=(0,))
+    prog.emit("MAJ", x=3, n_act=4, srcs=(0, 1, 2), dsts=(1,))
+    prog.emit("NOT", srcs=(1,), dsts=(1,))
+    prog.emit("MRC", n_act=4, srcs=(1,), dsts=(2, 0, 3))
+    return prog
+
+
+def _mixed_arity_program() -> Program:
+    prog = Program()
+    prog.emit("MAJ", x=3, n_act=4, srcs=(0, 1, 2), dsts=(8, 10))
+    prog.emit("MAJ", x=7, n_act=8, srcs=(0, 1, 2, 3, 4, 5, 6), dsts=(9,))
+    prog.emit("MAJ", x=5, n_act=8, srcs=(8, 9, 10, 3, 4), dsts=(11,))
+    return prog
+
+
+def _mrc_prefix_program() -> Program:
+    """Two MRC ops of different fan-outs in one level: each scatters a
+    prefix of the widest fan-out's copies."""
+    prog = Program()
+    prog.emit("MRC", n_act=8, srcs=(0,), dsts=(4, 5, 6, 7, 8))
+    prog.emit("MRC", n_act=4, srcs=(1,), dsts=(9, 10))
+    prog.emit("MAJ", x=3, n_act=4, srcs=(4, 9, 2), dsts=(11,))
+    return prog
+
+
+def _not_copy_program() -> Program:
+    """NOT / COPY levels only: no kernel launches at all."""
+    prog = Program()
+    prog.emit("NOT", srcs=(0,), dsts=(1, 2))
+    prog.emit("COPY", srcs=(3,), dsts=(4,))
+    prog.emit("NOT", srcs=(2,), dsts=(0,))
+    prog.emit("COPY", srcs=(1,), dsts=(3, 5))
+    return prog
+
+
+def _adder_program() -> Program:
+    rng = np.random.default_rng(8)
+    return compile_elementwise("add", rand_u32(rng, 32), rand_u32(rng, 32),
+                               tier=5, n_act=32).program
+
+
+WALK_CORPUS = {
+    "aliasing": _aliasing_program,
+    "mixed_arity": _mixed_arity_program,
+    "mrc_prefix": _mrc_prefix_program,
+    "not_copy": _not_copy_program,
+    "random": lambda: rand_program(np.random.default_rng(9), n_ops=16),
+    "adder32": _adder_program,
+}
+
+
+@pytest.mark.parametrize("name", sorted(WALK_CORPUS))
+def test_jitted_walk_matches_eager_and_per_op(name, monkeypatch):
+    """First sighting eager, second builds the jitted walk, third and
+    later reuse it; every call bit-identical to the per-op interpreter,
+    with the eager walk's launches and energy, at two image shapes."""
+    from repro import obs
+
+    prog = WALK_CORPUS[name]()
+    sched = build_schedule(prog)
+    rng = np.random.default_rng(10)
+    opened = []
+    span = obs.span
+    monkeypatch.setattr(obs, "span",
+                        lambda n: opened.append(n) or span(n))
+
+    pal = get_backend("pallas", IDEAL)
+    jitted = []
+    for words in (WORDS, 2 * WORDS):
+        state = jnp.asarray(rand_u32(rng, prog.n_rows() + 1, words))
+        want = np.asarray(get_backend("pallas", IDEAL).run(prog, state))
+        eager = get_backend("pallas", IDEAL)  # a first sighting
+        assert (np.asarray(eager.run_fused(prog, state, sched=sched))
+                == want).all()
+        for _ in range(3):
+            pal.reset_dispatches()
+            opened.clear()
+            got = np.asarray(pal.run_fused(prog, state, sched=sched))
+            assert (got == want).all()
+            assert pal.dispatch_count == eager.dispatch_count \
+                == sched.n_dispatches()
+            assert pal.energy_nj_total == eager.energy_nj_total
+            walk = pal._walks[sched]
+            jitted.append((walk.jitted, [n for n in opened
+                                         if n.startswith("backend.levels")]))
+    assert jitted[0] == (None, [])
+    build, hit = ["backend.levels_build"], ["backend.levels_jit"]
+    fn = jitted[1][0]
+    assert fn is not None
+    # The new shape under the same schedule reuses the jitted walk.
+    assert jitted[1:] == [(fn, build)] + [(fn, hit)] * 4
+
+
+def test_walk_lru_holds_its_bound():
+    from repro.backends import pallas
+
+    pal = get_backend("pallas", IDEAL)
+    state = jnp.asarray(rand_u32(np.random.default_rng(11), 4, WORDS))
+    progs = []
+    for i in range(pallas.WALK_CACHE_SIZE + 2):
+        prog = Program()
+        prog.emit("NOT", srcs=(i % 3,), dsts=(3,), tag=f"p{i}")
+        progs.append(prog)
+        out = np.asarray(pal.run_fused(prog, state))
+        assert (out[3] == ~np.asarray(state)[i % 3]).all()
+    assert len(pal._walks) == pallas.WALK_CACHE_SIZE
+    # The two oldest went: the first is a first sighting again.
+    assert build_schedule(progs[0]) not in pal._walks
+    assert build_schedule(progs[2]) in pal._walks
+    pal.run_fused(progs[0], state)
+    assert pal._walks[build_schedule(progs[0])].jitted is None
+    assert len(pal._walks) == pallas.WALK_CACHE_SIZE
+
+
+def test_walk_refuses_rows_beyond_the_image():
+    prog = Program()
+    prog.emit("MAJ", x=3, n_act=4, srcs=(0, 1, 2), dsts=(4,))
+    state = jnp.asarray(rand_u32(np.random.default_rng(12), 4, WORDS))
+    with pytest.raises(ValueError, match="row 4 of a 4-row image"):
+        get_backend("pallas", IDEAL).run_fused(prog, state)
 
 
 # --------------------------------------------------------- helper hygiene

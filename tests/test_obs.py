@@ -112,3 +112,28 @@ def test_a_span_without_a_profiler_keeps_nothing(tmp_path):
     jax.profiler.start_trace(str(tmp_path))
     jax.profiler.stop_trace()
     assert _pud_events(str(tmp_path)) == []
+
+
+def test_level_walk_spans_open_inside_the_backend(tmp_path):
+    """The same program three times: the first call walks eagerly and
+    opens no ``backend.levels_*`` span, the second builds the jitted
+    walk, the third dispatches it; each inside ``backend.run_fused``."""
+    rng = np.random.default_rng(1)
+    a, b = (rng.integers(0, 2**32, 64, dtype=np.uint32) for _ in range(2))
+    session = DramSession("pallas")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            out, _ = session.elementwise("add", a, b)
+            jax.block_until_ready(out)
+    finally:
+        jax.profiler.stop_trace()
+    np.testing.assert_array_equal(np.asarray(out), a + b)
+    events = sorted(_pud_events(str(tmp_path)), key=lambda e: e[1])
+    backend = [e for e in events if e[0] == "backend.run_fused"]
+    levels = [e for e in events if e[0].startswith("backend.levels")]
+    assert [e[0] for e in levels] == ["backend.levels_build",
+                                      "backend.levels_jit"]
+    for (_, start, end, _), (_, p_start, p_end, _) in zip(levels,
+                                                          backend[1:]):
+        assert p_start <= start and end <= p_end
