@@ -390,7 +390,8 @@ def engine_phase(sm: Smoke) -> str:
     from repro.kernels.majx.ops import majx
     from repro.models import model as M
     from repro.pud.tmr import corrupt
-    from repro.serve.engine import Engine, Request, _packed_rows
+    from repro.serve import scrub
+    from repro.serve.engine import Engine, Request
 
     s = sm.sizes
     cfg = get_config("xlstm-125m", smoke=s.smoke_model)
@@ -406,10 +407,10 @@ def engine_phase(sm: Smoke) -> str:
 
     engine = Engine(params, cfg, max_seq=s.prompt_len + s.new_tokens,
                     seed=sm.seed)
-    rows = _packed_rows(params)
+    rows = scrub.layout_of(params).tile_rows
     sm.assert_kernel("engine heal vote", functools.partial(
         majx, interpret=engine.pud.backend.interpret),
-        _u32((3, rows, min(4096, n_bytes // 4))))
+        _u32((3, rows, scrub.ROW_WORDS)))
     clean = jax.tree.map(jnp.copy, params)
     fixed = engine.heal_params([bad, params, clean])
     for a, b in zip(jax.tree.leaves(engine.params), leaves):

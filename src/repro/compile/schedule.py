@@ -73,6 +73,18 @@ class Schedule:
 
     levels: tuple[tuple[FusedGroup, ...], ...]
 
+    def __hash__(self) -> int:
+        # Immutable, so the hash over every op is taken once and kept:
+        # the backend looks its level walk up by schedule on every run.
+        h = self.__dict__.get("_hash")
+        if h is None:
+            h = hash(self.levels)
+            object.__setattr__(self, "_hash", h)
+        return h
+
+    def __getstate__(self) -> dict:
+        return {"levels": self.levels}   # a string hash is per process
+
     @property
     def n_levels(self) -> int:
         return len(self.levels)

@@ -1,10 +1,15 @@
 """Wrapper for the mismatch/success-rate kernel.
 
-The wrapper is not jitted: its reshape and pad run as eager ops around
-the jitted ``mismatch_pallas`` at every call.
+The wrapper is jitted with the kernel: the flattening, the row reshape
+and the padding fuse into the one dispatch, and a packed tile whose
+word count is a whole number of ``tiling.MAX_BLOCK_C``-word rows keeps
+its rows (the reshape folds away, no relayout copy), compared in blocks
+of whole rows.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,12 +19,13 @@ from repro.kernels.mismatch.kernel import mismatch_pallas
 from repro.kernels.mismatch.ref import mismatch_count_ref
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def mismatch_count(got: jax.Array, want: jax.Array, *,
                    interpret: bool) -> jax.Array:
     """Number of differing bits between packed arrays of any shape."""
     g = jnp.asarray(got, jnp.uint32).reshape(-1)
     w = jnp.asarray(want, jnp.uint32).reshape(-1)
-    width = 512
+    width = tiling.MAX_BLOCK_C if g.size % tiling.MAX_BLOCK_C == 0 else 512
     # Zero padding on both sides XORs to zero: the tail adds no bits, and
     # the kernel never reads past the arrays into a partial block.
     g2, _ = tiling.pad_to_tile(tiling.words_to_rows(g, width),

@@ -11,8 +11,9 @@ device: a span's duration is host time, and device time comes from the
 device's own lines of the same trace.
 
 The spans, with their names stable.  Each opens once per call at its
-site, except ``compile.sync``, which opens once per host read, and the
-two ``backend.levels_*`` spans, of which a fused call opens at most one;
+site, except ``compile.sync``, which opens once per host read, the
+two ``backend.levels_*`` spans, of which a fused call opens at most one,
+and the ``scrub.*`` spans, which open once per tile;
 they nest on the calling thread.  The metric that reads each is a reader of the
 benchmark (``benchmarks/chip/metrics``).
 
@@ -49,6 +50,20 @@ benchmark (``benchmarks/chip/metrics``).
     a jitted level walk.  Read by ``level_jit_per_call.arith`` (its
     count).  A fused call that opens neither ran the walk eagerly: the
     schedule's first sighting.
+
+``pud/service.scrub``
+    Opens in ``PudService.scrub``: one scrub of a resident replica set,
+    the queue, admission and batcher included.  Read by
+    ``scrub_host_ms.scrub`` (its time less the two below), and every
+    ``*.scrub`` reader reads nothing from a trace without it.
+``pud/scrub.tile``
+    Opens in ``serve.scrub.scrub`` around each tile: its image, the
+    fused run (``pud/session.run_fused`` and ``pud/backend.run_fused``
+    open inside, read by ``level_exec_ms.scrub``), the mismatch passes
+    and the write-back.  Read by ``tiles_per_call.scrub`` (its count).
+``pud/scrub.verify``
+    Opens inside ``pud/scrub.tile`` around the tile's mismatch passes,
+    one per replica.  Read by ``verify_ms.scrub`` (its time).
 
 The kernels' device time is read from the name each ``pallas_call``
 gives its device op (``KERNEL_NAME`` in each kernel module of

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import hashlib
 import json
 
 from repro.core.costmodel import COST
@@ -43,6 +44,20 @@ class Program:
 
     def extend(self, other: "Program") -> None:
         self.ops.extend(other.ops)
+
+    def freeze(self) -> "FrozenProgram":
+        """The program as it stands, in a form that can no longer change."""
+        return FrozenProgram(self.ops)
+
+    def content_key(self) -> str:
+        """SHA-256 over every op's semantic fields (kind, arity,
+        activation count, row addresses), the provenance ``tag`` left
+        out: programs that differ only in tags share one key."""
+        h = hashlib.sha256()
+        for op in self.ops:
+            h.update(
+                f"{op.kind}|{op.x}|{op.n_act}|{op.srcs}|{op.dsts}\n".encode())
+        return h.hexdigest()
 
     def n_rows(self) -> int:
         """Rows an executing backend must hold (max address + 1)."""
@@ -95,3 +110,36 @@ class Program:
         """Energy from the Fig.-5 power model over the schedule (W x ns =
         nJ; delegates to :data:`repro.core.costmodel.COST`)."""
         return COST.program_energy_nj(self, errors, **env)
+
+
+class FrozenProgram(Program):
+    """A :class:`Program` that can no longer change.
+
+    ``ops`` is a tuple, and ``emit``, ``extend`` and assignment raise, so
+    what is derived from the content may be kept on the instance: the
+    content key is hashed once, not on every run.  A program that runs
+    many times over fresh state (the scrub's tile vote) is frozen once
+    and reused.
+    """
+
+    def __init__(self, ops=()):
+        object.__setattr__(self, "ops", tuple(ops))
+
+    def __setattr__(self, name, value):
+        raise dataclasses.FrozenInstanceError(
+            f"cannot assign to field {name!r} of a FrozenProgram")
+
+    def emit(self, *args, **kwargs) -> None:
+        raise TypeError("a FrozenProgram takes no more ops")
+
+    def extend(self, other: "Program") -> None:
+        raise TypeError("a FrozenProgram takes no more ops")
+
+    def freeze(self) -> "FrozenProgram":
+        return self
+
+    def content_key(self) -> str:
+        key = self.__dict__.get("_content_key")
+        if key is None:
+            key = self.__dict__["_content_key"] = super().content_key()
+        return key

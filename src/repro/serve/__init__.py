@@ -11,6 +11,8 @@ substrate efficiently.  This package is that service subsystem:
   backpressure, load-shedding;
 * :mod:`repro.serve.batcher` — continuous batching: same-shape requests
   coalesce into ONE fused Program per tick;
+* :mod:`repro.serve.scrub` — replica sets resident on the device and
+  their tile-by-tile MAJ scrub;
 * :mod:`repro.serve.slo` — request traces + rolling p50/p99/throughput/
   occupancy/cache-hit SLO snapshots;
 * :mod:`repro.serve.service` — :class:`PudService`, the engine tying
@@ -29,7 +31,9 @@ from repro.serve.batcher import Batcher, BatchOutcome, BatchPlan
 from repro.serve.queue import (EraseRequest, EraseResult, HealRequest,
                                HealResult, IntegrityRequest,
                                IntegrityResult, Priority, PudRequest,
-                               RequestQueue, ServeError)
+                               RequestQueue, ScrubRequest, ScrubResult,
+                               ServeError)
+from repro.serve.scrub import ReplicaSet
 from repro.serve.service import PudService, ServiceConfig
 from repro.serve.slo import RequestTrace, SloMonitor, SloSnapshot, Span
 
@@ -38,7 +42,7 @@ __all__ = [
     "BatchOutcome", "BatchPlan", "Batcher", "DeadlineExceededError",
     "EraseRequest", "EraseResult", "HealRequest", "HealResult",
     "IntegrityRequest", "IntegrityResult", "Priority", "PudRequest",
-    "PudService", "QueueFullError", "RequestQueue", "RequestTrace",
-    "ServeError", "ServiceConfig", "SloMonitor", "SloSnapshot", "Span",
-    "TenantArena",
+    "PudService", "QueueFullError", "ReplicaSet", "RequestQueue",
+    "RequestTrace", "ScrubRequest", "ScrubResult", "ServeError",
+    "ServiceConfig", "SloMonitor", "SloSnapshot", "Span", "TenantArena",
 ]

@@ -15,6 +15,9 @@ coalesce_key` merge into ONE addressed Program built through the typed
   dispatch.
 * **erase** — one WR'd pattern row fans out in Multi-RowCopy waves over
   every request's rows; again a single level, one fused MRC dispatch.
+* **scrub** — a resident replica set votes tile by tile, one frozen
+  tile Program for every tile (:mod:`repro.serve.scrub`); scrubs never
+  coalesce.
 * **verify** — ``mismatch`` is a scalar reduction per request (no
   per-request split of a fused result), so integrity checks share the
   tick and session but execute one bulk op each.
@@ -35,7 +38,7 @@ import numpy as np
 from repro.core import calibration as cal
 from repro.serve.queue import (EraseRequest, EraseResult, HealRequest,
                                HealResult, IntegrityRequest, IntegrityResult,
-                               PudRequest)
+                               PudRequest, ScrubResult)
 
 
 @dataclasses.dataclass
@@ -97,6 +100,8 @@ class Batcher:
             return self._execute_erase(plan, session)
         if plan.kind == "verify":
             return self._execute_verify(plan, session)
+        if plan.kind == "scrub":
+            return self._execute_scrub(plan, session)
         raise ValueError(f"unknown batch kind {plan.kind!r}")
 
     def _execute_heal(self, plan: BatchPlan, session) -> BatchOutcome:
@@ -155,6 +160,17 @@ class Batcher:
             off += req.rows
         return BatchOutcome(plan, results, n_ops=len(prog.ops),
                             n_levels=session.schedule_for(prog).n_levels)
+
+    def _execute_scrub(self, plan: BatchPlan, session) -> BatchOutcome:
+        from repro.serve import scrub
+
+        [req] = plan.requests
+        rs = req.replicas
+        corrected = scrub.scrub(session, rs)
+        result = ScrubResult(corrected=corrected, tiles=rs.layout.tiles,
+                             words=rs.layout.words)
+        return BatchOutcome(plan, [result], n_ops=rs.layout.tile_rows,
+                            n_levels=1)
 
     def _execute_verify(self, plan: BatchPlan, session) -> BatchOutcome:
         results = []

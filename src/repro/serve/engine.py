@@ -7,13 +7,14 @@ repro.models.model; the engine is pure scheduling.
 
 PUD hooks: the engine's integrity work (replica vote-healing and
 bit-level verification) runs through a :class:`~repro.serve.service.
-PudService` — the engine is a thin *client* submitting typed
-:class:`~repro.serve.queue.HealRequest`/:class:`~repro.serve.queue.
-IntegrityRequest` work, so engine votes share the service's session
-pool, schedule cache, continuous batching, and SLO accounting with
-every other tenant.  The offload planner's verdict (where the vote
-*would* run on PUD-capable memory; advisory on TPU-only deployments)
-rides back on each heal result.
+PudService` — the engine is a thin *client*: it installs its replicas
+on the device, scrubs them through the service
+(:meth:`~repro.serve.service.PudService.scrub`) and submits typed
+:class:`~repro.serve.queue.IntegrityRequest` work, so engine votes share
+the service's session pool, schedule cache, queue and SLO accounting
+with every other tenant.  The offload planner's verdict (where the vote
+*would* run on PUD-capable memory; advisory on TPU-only deployments) is
+kept for each heal.
 
 Integrity votes must be error-free, so healing on a non-ideal
 :class:`~repro.backends.context.ExecutionContext` (a stochastic backend
@@ -35,27 +36,16 @@ import numpy as np
 
 from repro.backends import ExecutionContext
 from repro.configs.base import ModelConfig
-from repro.core import bitplanes as bp
 from repro.core import calibration as cal
 from repro.models import model as M
-from repro.serve.queue import HealRequest, IntegrityRequest, ServeError
+from repro.serve import scrub
+from repro.serve.queue import IntegrityRequest, ServeError
 from repro.serve.service import PudService, ServiceConfig
 
 
 #: Widest replica vote an engine-owned service is sized for: MAJ9, the
 #: widest majority the paper's chips perform (Table 1).
 MAX_HEAL_REPLICAS = max(cal.MAJX_MAX_X.values())
-
-
-def _packed_rows(tree) -> int:
-    """Rows of the tile :meth:`Engine._pack_pytree` lays ``tree`` out in,
-    from leaf shapes alone."""
-    from repro.kernels import tiling
-
-    total = sum(-(-leaf.size * leaf.dtype.itemsize // 4)
-                for leaf in jax.tree.leaves(tree))
-    width = max(min(tiling.MAX_BLOCK_C, total), 1)
-    return -(-total // width)
 
 
 class IntegrityContextError(ServeError):
@@ -95,13 +85,16 @@ class Engine:
         # ``pud_service`` to pool votes with other engines/tenants, or
         # let the engine own a single-session service.  The service
         # defaults to an ideal context (see module docstring).  An owned
-        # service's row budget fits a heal of the whole parameter tile
-        # by up to MAX_HEAL_REPLICAS replicas, whatever the model size.
+        # service's row budget fits a scrub tile of up to
+        # MAX_HEAL_REPLICAS replicas and a verify of the whole packed
+        # params, whatever the model size.
+        layout = scrub.layout_of(params)
         self.service = pud_service or PudService(ServiceConfig(
             backend=pud_backend,
             ctx=pud_ctx or ExecutionContext(ideal=True), pool_size=1,
             tenant_rows=max(ServiceConfig.tenant_rows,
-                            (MAX_HEAL_REPLICAS + 1) * _packed_rows(params))))
+                            (MAX_HEAL_REPLICAS + 1) * layout.tile_rows,
+                            2 * layout.rows)))
         self.strict_integrity = strict_integrity
         self.tenant = tenant
         #: Compat: the first pooled session still answers the whole
@@ -130,69 +123,43 @@ class Engine:
             raise IntegrityContextError(msg)
         warnings.warn(msg, IntegrityContextWarning, stacklevel=3)
 
-    def _pack_pytree(self, tree):
-        """Pytree -> ((rows, width) tile, metas, total_words, width)."""
-        from repro.kernels import tiling
-
-        metas = []  # (n_words, shape, dtype) per leaf, for re-splitting
-        for leaf in jax.tree.leaves(tree):
-            w, shape, dtype = bp.bitcast_to_planes(leaf)
-            metas.append((int(w.size), shape, dtype))
-        words = jnp.concatenate([bp.bitcast_to_planes(leaf)[0].reshape(-1)
-                                 for leaf in jax.tree.leaves(tree)])
-        total = int(words.size)
-        width = min(tiling.MAX_BLOCK_C, total)
-        return np.asarray(tiling.words_to_rows(words, width)), metas, \
-            total, width
-
     def heal_params(self, replicas: Sequence) -> int:
         """Majority-vote parameter replicas through the PUD service.
 
         ``replicas``: >= 3 (odd) pytrees with the engine's param
         structure.  Installs the healed params and returns the number
-        of corrected bits.
+        of bits corrected in ``replicas[0]``.
 
-        The engine is a thin client: every replica's packed words
-        become one tile of a single typed
-        :class:`~repro.serve.queue.HealRequest`, and the service's
-        batcher lowers it (coalesced with any concurrent tenants'
-        same-shape votes) to ONE single-level fused Program — one
-        batched MAJX dispatch on the ``pallas`` backend, schedule
-        -cached across repeat votes.  The offload planner's verdict for
-        the fused program is appended to ``self.pud_decisions``
+        The engine is a thin client: the replicas are packed onto the
+        device as a :class:`~repro.serve.scrub.ReplicaSet`, scrubbed
+        tile by tile through the service (one schedule-cached tile
+        Program for every tile, one batched MAJX dispatch each on the
+        ``pallas`` backend), and unpacked once.  The offload planner's
+        verdict for the tile vote is appended to ``self.pud_decisions``
         (advisory: where the vote would run on PUD-capable memory).
         """
         self._check_integrity_ctx()
-        tiles, metas, total, _ = self._pack_pytree(replicas[0])
-        rep_tiles = [tiles] + [self._pack_pytree(r)[0]
-                               for r in replicas[1:]]
-        [result] = self.service.serve([HealRequest(
-            replicas=np.stack(rep_tiles), tenant=self.tenant)])
-        voted = result.healed.reshape(-1)[:total]
-
-        healed_leaves, off = [], 0
-        treedef = jax.tree.structure(replicas[0])
-        for n_words, shape, dtype in metas:
-            healed_leaves.append(bp.bitcast_from_planes(
-                jnp.asarray(voted[off:off + n_words]), shape, dtype))
-            off += n_words
-        self.params = jax.tree.unflatten(treedef, healed_leaves)
-        self.pud_decisions.append(result.decision)
-        return result.fixed_bits
+        rs = self.service.install_replica_trees(replicas,
+                                                tenant=self.tenant)
+        result = self.service.scrub(rs)
+        self.params = self.service.live(rs)
+        self.pud_decisions.append(scrub.plan(self.pud, rs))
+        return result.corrected[0]
 
     def verify_params(self, reference) -> float:
         """Bit-level success rate of live params vs a reference pytree.
 
         One typed :class:`~repro.serve.queue.IntegrityRequest` through
-        the service (the tiles' zero padding matches on both sides, so
-        the packed comparison equals the per-leaf one; the rate is
-        normalized by the real parameter bits, not the padding).
+        the service over both trees packed on the device (the layout's
+        zero padding matches on both sides, so the packed comparison
+        equals the per-leaf one; the rate is normalized by the real
+        parameter bits, not the padding).
         """
-        live, _, total, _ = self._pack_pytree(self.params)
-        ref, _, _, _ = self._pack_pytree(reference)
+        layout = scrub.layout_of(self.params)
         [result] = self.service.serve([IntegrityRequest(
-            live=live, reference=ref, tenant=self.tenant)])
-        return 1.0 - result.mismatch_bits / max(total * 32, 1)
+            live=scrub.pack(self.params, layout),
+            reference=scrub.pack(reference, layout), tenant=self.tenant)])
+        return 1.0 - result.mismatch_bits / max(layout.words * 32, 1)
 
     # ------------------------------------------------------------ serving
     def _sample(self, logits) -> np.ndarray:

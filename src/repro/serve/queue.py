@@ -1,10 +1,11 @@
 """Typed PUD service requests and the priority request queue.
 
 The serve layer's unit of work is a *request*: a tenant asking for one
-of the paper's three production capabilities — an integrity check
-(bit-level mismatch of a live tile vs a reference), a MAJX heal
-(majority vote across replica tiles, §5), or a Multi-RowCopy bulk erase
-(§8.2).  Requests are plain dataclasses over packed uint32 bit-plane
+of the paper's production capabilities — an integrity check (bit-level
+mismatch of a live tile vs a reference), a MAJX heal (majority vote
+across replica tiles, §5), a scrub of resident replicas (the same vote,
+tile by tile, written back), or a Multi-RowCopy bulk erase (§8.2).
+Requests are plain dataclasses over packed uint32 bit-plane
 tiles (the layout of :mod:`repro.core.bitplanes`), carry priority /
 deadline / tenant metadata, and expose the two properties the service
 machinery keys on:
@@ -30,6 +31,8 @@ import heapq
 import itertools
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -45,10 +48,12 @@ class Priority(enum.IntEnum):
     LOW = 2
 
 
-def _as_tile(arr, what: str, ndim: int) -> np.ndarray:
+def _as_tile(arr, what: str, ndim: int):
+    """A packed uint32 tile; a device array stays on the device."""
     if arr is None:
         raise ServeError(f"{what} is required")
-    out = np.asarray(arr, np.uint32)
+    out = jnp.asarray(arr, jnp.uint32) if isinstance(arr, jax.Array) \
+        else np.asarray(arr, np.uint32)
     if out.ndim != ndim:
         raise ServeError(
             f"{what} must be a rank-{ndim} packed uint32 tile, got "
@@ -183,6 +188,30 @@ class EraseRequest(PudRequest):
         return self.rows  # the shared pattern row is charged to no tenant
 
 
+@dataclasses.dataclass
+class ScrubRequest(PudRequest):
+    """Majority-vote scrub of a resident replica set, tile by tile.
+
+    ``replicas`` is a :class:`~repro.serve.scrub.ReplicaSet` installed by
+    :meth:`~repro.serve.service.PudService.install_replicas`; the votes
+    are written back into it.  Scrubs never coalesce: each is its own
+    plan.  Admission charges one tile image, the rows a tile's program
+    addresses.  Result: :class:`ScrubResult`.
+    """
+
+    replicas: object = None                    # required; validated below
+
+    def __post_init__(self):
+        if self.replicas is None:
+            raise ServeError("ScrubRequest.replicas is required")
+
+    def coalesce_key(self) -> tuple:
+        return ("scrub", id(self))
+
+    def rows_needed(self) -> int:
+        return (self.replicas.x + 1) * self.replicas.layout.tile_rows
+
+
 # ---------------------------------------------------------------- results
 
 
@@ -201,6 +230,13 @@ class HealResult:
     healed: np.ndarray          # (rows, words) voted tile
     fixed_bits: int             # bits corrected vs replica 0
     decision: object = None     # OffloadDecision for the fused program
+
+
+@dataclasses.dataclass(frozen=True)
+class ScrubResult:
+    corrected: tuple[int, ...]  # bits each replica had wrong, now voted
+    tiles: int                  # tiles voted
+    words: int                  # leaf words voted per replica
 
 
 @dataclasses.dataclass(frozen=True)

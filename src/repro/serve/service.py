@@ -29,6 +29,11 @@ Two client styles share one engine:
 ...     res = await svc.submit(HealRequest(replicas=tiles))
 ...     await svc.stop()
 
+Replicas of a params pytree can live on the device
+(:meth:`PudService.install_replicas`) and be scrubbed there tile by tile
+(:meth:`PudService.scrub`, :mod:`repro.serve.scrub`); the scrub is a
+request like any other.
+
 The serve engine's ``heal_params`` / ``verify_params``
 (:mod:`repro.serve.engine`) are thin sync clients of this service, so
 the whole integrity workload runs through one engine.
@@ -42,11 +47,17 @@ import itertools
 import time
 from typing import Callable, Optional, Union
 
+import jax.numpy as jnp
+
+from repro import obs
 from repro.backends import Backend, ExecutionContext
 from repro.serve.admission import (AdmissionController, AdmissionError,
                                    DeadlineExceededError)
 from repro.serve.batcher import Batcher
-from repro.serve.queue import PudRequest, RequestQueue
+from repro.serve import scrub as scrub_mod
+from repro.serve.queue import (PudRequest, RequestQueue, ScrubRequest,
+                               ScrubResult, ServeError)
+from repro.serve.scrub import ReplicaSet
 from repro.serve.slo import RequestTrace, SloMonitor, SloSnapshot
 from repro.session import CompileCache, DramSession
 
@@ -72,6 +83,11 @@ class ServiceConfig:
                                   # (honored by serve() and the async loop)
     shed_late: bool = True        # drop past-deadline work at tick time
     latency_window: int = 512     # rolling SLO window (completions)
+
+
+def _check_replica_count(x: int) -> None:
+    if x % 2 == 0 or x < 3:
+        raise ServeError(f"a replica set needs an odd count >= 3, got {x}")
 
 
 @dataclasses.dataclass
@@ -225,6 +241,37 @@ class PudService:
         while self.backlog:
             self.tick()
         return [slots[i] for i in range(len(requests))]
+
+    # --------------------------------------------------- resident replicas
+    def install_replicas(self, tree, x: int = 3, *,
+                         tenant: str = "default") -> ReplicaSet:
+        """Pack ``tree`` onto the device once and hold ``x`` replicas of
+        it (``x`` odd >= 3); the handle is what :meth:`scrub` and
+        :meth:`live` take."""
+        _check_replica_count(x)
+        rs = scrub_mod.install([tree], tenant)
+        rs.replicas += tuple(jnp.copy(rs.replicas[0])
+                             for _ in range(x - 1))
+        return rs
+
+    def install_replica_trees(self, trees, *,
+                              tenant: str = "default") -> ReplicaSet:
+        """Hold each of ``trees`` (one structure) as a replica."""
+        _check_replica_count(len(trees))
+        return scrub_mod.install(trees, tenant)
+
+    def scrub(self, replicas: ReplicaSet) -> ScrubResult:
+        """Vote ``replicas`` tile by tile through the queue, admission
+        and batcher, and write the votes back into every replica."""
+        with obs.span("service.scrub"):
+            [result] = self.serve([ScrubRequest(replicas=replicas,
+                                                tenant=replicas.tenant)])
+        return result
+
+    def live(self, replicas: ReplicaSet):
+        """The params pytree replica 0 holds (after a scrub, every
+        replica holds it), unpacked on the device."""
+        return scrub_mod.unpack(replicas.replicas[0], replicas.layout)
 
     # ------------------------------------------------------------ async API
     async def start(self) -> None:

@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import hashlib
 import threading
 from typing import Optional, TYPE_CHECKING
 
@@ -47,12 +46,9 @@ if TYPE_CHECKING:  # pragma: no cover - import cycle guard for hints only
 
 
 def program_key(program: Program) -> str:
-    """Content hash of a Program's semantic fields (tags excluded)."""
-    h = hashlib.sha256()
-    for op in program.ops:
-        h.update(
-            f"{op.kind}|{op.x}|{op.n_act}|{op.srcs}|{op.dsts}\n".encode())
-    return h.hexdigest()
+    """Content hash of a Program's semantic fields (tags excluded); a
+    :class:`~repro.pud.isa.FrozenProgram` hashes once and keeps it."""
+    return program.content_key()
 
 
 @dataclasses.dataclass

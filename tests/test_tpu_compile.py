@@ -97,3 +97,33 @@ def test_kernel_compiles_for_v5e(name, one_chip):
             for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_scrub_tile_compiles_for_v5e(one_chip):
+    """The scrub's tile vote at its real tile (1,024 rows of 4,096
+    words): the jitted level walk of the tile program holds a kernel and
+    keeps its working set well under 1 GB, and the write-back updates
+    the replicas in place."""
+    import copy
+
+    from repro.backends.pallas import PallasBackend, _LevelWalk
+    from repro.serve import scrub
+
+    backend = PallasBackend()
+    backend.interpret = False
+    tile = scrub.TILE_ROWS
+    walk = _LevelWalk(build_schedule(scrub.tile_program(3, tile)))
+    image = jax.ShapeDtypeStruct((4 * tile, scrub.ROW_WORDS), jnp.uint32,
+                                 sharding=one_chip)
+    compiled = jax.jit(functools.partial(copy.copy(backend)._walk, walk)
+                       ).lower(image).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes + \
+        mem.output_size_in_bytes < 2**28
+    replicas = tuple(jax.ShapeDtypeStruct((4 * tile, scrub.ROW_WORDS),
+                                          jnp.uint32, sharding=one_chip)
+                     for _ in range(3))
+    commit = scrub._commit.lower(replicas, image, 0).compile()
+    assert commit.memory_analysis().alias_size_in_bytes == \
+        3 * 4 * tile * scrub.ROW_WORDS * 4
