@@ -76,10 +76,9 @@ def _multiplier(nbits: int, lanes: int):
     rng = np.random.default_rng(11)
     bits_a = rng.integers(0, 2, (nbits, lanes)).astype(bool)
     bits_b = rng.integers(0, 2, (nbits, lanes)).astype(bool)
-    A = bp.pack(bits_a)
-    B = bp.pack(bits_b)
-    cp = trace_planes(lambda bs: list(bs.mul(A, B)), tier=5, n_act=32)
-    return cp.program, cp.state
+    tr = trace_planes(lambda bs, A, B: list(bs.mul(A, B)), nbits, tier=5,
+                      n_act=32)
+    return tr.program, tr.image(bp.pack(bits_a), bp.pack(bits_b))
 
 
 def _erase(waves: int, fanout: int, words: int):

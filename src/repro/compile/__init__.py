@@ -13,7 +13,7 @@ Three layers:
   images wider than the on-chip budget;
 * :mod:`repro.compile.trace` — lower §8.1 ``BitSerial`` gate streams to
   addressed, fusable Programs (SSA row allocation over a subarray
-  image).
+  image), traced once per shape by plane origin, never by value.
 
 Consumers: the ``pallas`` backend executes schedules, ``pud.arith``
 routes batch-native executors through :func:`compile_elementwise`, the
@@ -29,11 +29,11 @@ from repro.compile.megakernel import (MegaLowering, VmemPlan,
                                       lower_schedule, plan_vmem)
 from repro.compile.schedule import (FusedGroup, Schedule, build_schedule,
                                     dependency_levels)
-from repro.compile.trace import (CompiledProgram, Tracer,
+from repro.compile.trace import (CompiledProgram, Trace, Tracer,
                                  compile_elementwise, trace_planes)
 
 __all__ = [
-    "CompiledProgram", "FusedGroup", "MegaLowering", "Schedule", "Tracer",
-    "VmemPlan", "build_schedule", "compile_elementwise",
+    "CompiledProgram", "FusedGroup", "MegaLowering", "Schedule", "Trace",
+    "Tracer", "VmemPlan", "build_schedule", "compile_elementwise",
     "dependency_levels", "lower_schedule", "plan_vmem", "trace_planes",
 ]

@@ -11,7 +11,8 @@ device: a span's duration is host time, and device time comes from the
 device's own lines of the same trace.
 
 The spans, with their names stable.  Each opens once per call at its
-site, except ``compile.sync``, which opens once per host read, the
+site, except ``compile.trace`` and ``compile.sync``, which open once per
+miss of the compile cache, the
 two ``backend.levels_*`` spans, of which a fused call opens at most one,
 and the ``scrub.*`` spans, which open once per tile;
 they nest on the calling thread.  The metric that reads each is a reader of the
@@ -21,15 +22,19 @@ benchmark (``benchmarks/chip/metrics``).
     Opens in ``DramSession.elementwise``: the whole call as the program
     sees it.  Every reader below reads nothing from a trace without it.
 ``pud/compile``
-    Opens in ``compile.trace.compile_elementwise``: packing both
-    operands, ``trace_planes``, ``initial_state``.  Read by
-    ``trace_ms.arith`` (its time).
+    Opens in ``compile.trace.compile_elementwise``: the lookup of the
+    (op, lanes, tier, n_act) in the compile cache and the dispatch of
+    the jitted image build.  Read by ``trace_ms.arith`` (its time).
+``pud/compile.trace``
+    Opens inside ``pud/compile`` on a miss of the compile cache: tracing
+    the op once (``trace_planes``); the image build compiles on its
+    first dispatch, after it.  Read by ``compile_traces_per_call.arith``
+    (its count).
 ``pud/compile.sync``
-    Opens in ``compile.trace.Tracer`` around each host read of a
-    plane's value (``_key``, and the copy in ``row_of``).  Read by
-    ``host_syncs_per_call.arith`` (its count).  The count is of reads
-    the tracing mechanism makes, not of bytes moved: a read that JAX
-    serves from an array's cached host copy counts as one read.
+    Opens inside ``pud/compile.trace`` around its one host read, of the
+    origin IDs of every gate's operands (``Tracer.allocate``).  Read by
+    ``host_syncs_per_call.arith`` (its count).  No operand value is
+    read: a call that hits the cache opens neither span.
 ``pud/session.run_fused``
     Opens in ``DramSession.run_fused``: validation, ``program_key``, the
     compile cache's schedule, lowering and certificate, then the

@@ -278,10 +278,11 @@ def run_elementwise(op: str, a, b, tier: int = 3, n_act: int = 4,
     level-batched kernel dispatches via ``executor.run_fused`` — the
     values still come from that executor's kernels, and the returned
     Program additionally carries row addresses (same op histogram as the
-    per-gate recording).  When the executor is a session, that
-    ``run_fused`` resolves through its content-hashed compile cache, so
-    re-running a traced program (same op/tier/width) skips
-    re-scheduling.
+    per-gate recording).  The program is traced once per (op, lanes,
+    tier, n_act), from no operand value; when the executor is a
+    session, its ``run_fused`` resolves the program's schedule through
+    the content-hashed compile cache, so a repeated shape skips tracing
+    and re-scheduling alike.
     """
     caps = getattr(executor, "capabilities", None)
     if caps is not None and executor.capabilities().native_batch:

@@ -39,13 +39,9 @@ WORDS = 4  # state width of every fixture (uint32 words per row)
 def _adder(nbits: int):
     """Traced tier-5 ripple-carry adder over nbits-plane operands."""
     from repro.compile import trace_planes
-    from repro.core import bitplanes as bp
 
-    rng = np.random.default_rng(nbits)
-    A = bp.pack(rng.integers(0, 2, (nbits, WORDS * 32)).astype(bool))
-    B = bp.pack(rng.integers(0, 2, (nbits, WORDS * 32)).astype(bool))
-    cp = trace_planes(lambda bs: list(bs.add(A, B)[0]), tier=5, n_act=32)
-    return cp.program
+    return trace_planes(lambda bs, A, B: list(bs.add(A, B)[0]), nbits,
+                        tier=5, n_act=32).program
 
 
 def _maj_tree(x: int):

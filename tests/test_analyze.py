@@ -342,18 +342,13 @@ def test_differential_programs_certify():
 
 def test_traced_adder_certifies_with_dead_gate():
     from repro.compile import trace_planes
-    from repro.core import bitplanes as bp
 
-    rng = np.random.default_rng(3)
-    A = bp.pack(rng.integers(0, 2, (4, 64)).astype(bool))
-    B = bp.pack(rng.integers(0, 2, (4, 64)).astype(bool))
-
-    def f(bs):
+    def f(bs, A, B):
         s, carry = bs.add(A, B)
         bs.not_(carry)          # dead gate: complement nothing reads
         return list(s)
 
-    prog = trace_planes(f, tier=5, n_act=32).program
+    prog = trace_planes(f, 4, tier=5, n_act=32).program
     sched = build_schedule(prog)
     cert = certify(prog, sched=sched, lowering=lower_schedule(sched))
     assert cert.summary[0] == ("race", 0, 0)

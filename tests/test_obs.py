@@ -1,10 +1,12 @@
 """The program's host spans (``repro.obs``), read back from a profile.
 
-One ``DramSession("pallas").elementwise("add", ...)`` at 64 lanes runs
-under ``jax.profiler``; the ``.xplane.pb`` it writes is read with
-``jax.profiler.ProfileData``.
+``DramSession("pallas").elementwise("add", ...)`` at 64 lanes runs under
+``jax.profiler`` twice, each call in its own profile: the first under a
+fresh compile cache (a miss, which traces), the second on other operands
+(a hit).  Each ``.xplane.pb`` is read with ``jax.profiler.ProfileData``.
 """
 
+import collections
 import glob
 import os
 
@@ -13,15 +15,19 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.compile import compile_elementwise
+from repro.compile import compile_elementwise, trace
 from repro.session import DramSession
 
 ONCE = ("elementwise", "compile", "session.run_fused", "backend.run_fused")
 
+#: What a miss opens besides: the trace, and its one read of the
+#: operands' origin IDs.
+MISS = ("compile.trace", "compile.sync")
+
 #: Each span and the span it opens inside.
 PARENT = {"compile": "elementwise", "session.run_fused": "elementwise",
           "backend.run_fused": "session.run_fused",
-          "compile.sync": "compile"}
+          "compile.trace": "compile", "compile.sync": "compile.trace"}
 
 
 def _pud_events(log_dir):
@@ -43,46 +49,60 @@ def _pud_events(log_dir):
 
 @pytest.fixture(scope="module")
 def traced_add(tmp_path_factory):
-    log_dir = str(tmp_path_factory.mktemp("profile"))
+    """(miss events, hit events, program) of two profiled calls."""
     rng = np.random.default_rng(0)
-    a, b = (rng.integers(0, 2**32, 64, dtype=np.uint32) for _ in range(2))
-    session = DramSession("pallas")
-    jax.profiler.start_trace(log_dir)
-    try:
-        out, program = session.elementwise("add", a, b)
-        jax.block_until_ready(out)
-    finally:
-        jax.profiler.stop_trace()
-    np.testing.assert_array_equal(np.asarray(out), a + b)
-    return _pud_events(log_dir), program
+    events, program = [], None
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(trace, "_compiled", collections.OrderedDict())
+        for _ in ("miss", "hit"):
+            log_dir = str(tmp_path_factory.mktemp("profile"))
+            a, b = (rng.integers(0, 2**32, 64, dtype=np.uint32)
+                    for _ in range(2))
+            # A session of its own: each call walks its schedule eagerly.
+            session = DramSession("pallas")
+            jax.profiler.start_trace(log_dir)
+            try:
+                out, program = session.elementwise("add", a, b)
+                jax.block_until_ready(out)
+            finally:
+                jax.profiler.stop_trace()
+            np.testing.assert_array_equal(np.asarray(out), a + b)
+            events.append(_pud_events(log_dir))
+    return events[0], events[1], program
 
 
 def test_each_layer_span_opens_once_per_call(traced_add):
-    events, _ = traced_add
-    names = [e[0] for e in events]
-    for name in ONCE:
-        assert names.count(name) == 1, name
-    assert set(names) == set(ONCE) | {"compile.sync"}
+    miss, hit, _ = traced_add
+    for events, extra in ((miss, MISS), (hit, ())):
+        names = [e[0] for e in events]
+        for name in ONCE + extra:
+            assert names.count(name) == 1, name
+        assert set(names) == set(ONCE + extra)
 
 
 def test_spans_nest_on_the_calling_thread(traced_add):
-    events, _ = traced_add
+    events, _, _ = traced_add
     assert len({e[3] for e in events}) == 1
-    once = {e[0]: e for e in events if e[0] in ONCE}
+    first = {e[0]: e for e in events}
     for name, start, end, _ in events:
         if name == "elementwise":
             continue
-        _, p_start, p_end, _ = once[PARENT[name]]
+        _, p_start, p_end, _ = first[PARENT[name]]
         assert p_start <= start and end <= p_end, (name, PARENT[name])
     # The compile finishes before the fused run starts.
-    assert once["compile"][2] <= once["session.run_fused"][1]
+    assert first["compile"][2] <= first["session.run_fused"][1]
 
 
 def test_every_traced_gate_reads_its_planes_back(traced_add):
-    events, program = traced_add
-    syncs = sum(1 for e in events if e[0] == "compile.sync")
+    """A miss traces the program and reads every gate's operand planes
+    back in one read, of their origin IDs; a hit opens ``pud/compile``
+    and neither ``compile.trace`` nor ``compile.sync``."""
+    miss, hit, program = traced_add
     assert len(program.ops) == 96
-    assert syncs >= len(program.ops)
+    assert [e[0] for e in miss].count("compile.sync") == 1
+    names = [e[0] for e in hit]
+    assert "compile" in names
+    assert "compile.trace" not in names and "compile.sync" not in names
 
 
 def test_the_megakernel_route_opens_the_backend_span(tmp_path):

@@ -127,3 +127,26 @@ def test_scrub_tile_compiles_for_v5e(one_chip):
     commit = scrub._commit.lower(replicas, image, 0).compile()
     assert commit.memory_analysis().alias_size_in_bytes == \
         3 * 4 * tile * scrub.ROW_WORDS * 4
+
+
+def test_elementwise_image_and_unpack_compile_for_v5e(one_chip):
+    """The add32 arith cell's per-call device work around the walk, at
+    its 2^20 lanes: packing both operands into the image, and unpacking
+    the result rows, each one jitted program that holds no temporary
+    beyond its operands and its result."""
+    from repro.compile import trace
+
+    lanes = 2**20
+    compiled = trace._compile("add", lanes, 5, 32)
+    rows, words = len(compiled.trace.sources), lanes // 32
+    operand = jax.ShapeDtypeStruct((lanes,), jnp.uint32, sharding=one_chip)
+    image = compiled.image.lower(operand, operand).compile()
+    # Rows pad to the chip's 8 sublanes.
+    assert image.memory_analysis().output_size_in_bytes == \
+        -(-rows // 8) * 8 * words * 4
+    assert image.memory_analysis().temp_size_in_bytes < 2**24
+    state = jax.ShapeDtypeStruct((rows, words), jnp.uint32,
+                                 sharding=one_chip)
+    unpack = compiled.unpack.lower(state).compile()
+    assert unpack.memory_analysis().output_size_in_bytes == lanes * 4
+    assert unpack.memory_analysis().temp_size_in_bytes < 2**24
