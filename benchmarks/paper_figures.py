@@ -280,8 +280,8 @@ def fig18_energy_modes():
     """Kill-Llama-style energy-savings view over the execution paths.
 
     Runs the same §8.1 programs (32-bit adder, 8-bit multiplier)
-    through the ``pallas`` session's three executors — per-op, fused,
-    megakernel — and reports the CostModel-priced TPU-side energy each
+    through the ``pallas`` session's two executors — per-op and fused —
+    and reports the CostModel-priced TPU-side energy each
     accrues (launch round-trips at board power + HBM traffic), next to
     the DRAM-side energy of executing the identical program in-situ
     under the Fig. 5 power model.  The headline ratios mirror the
@@ -305,9 +305,7 @@ def fig18_energy_modes():
         energies = {}
         for mode, run in (
                 ("per_op", lambda: session.run(prog, state)),
-                ("fused", lambda: session.run_fused(prog, state)),
-                ("megakernel", lambda: session.run_fused(
-                    prog, state, mode="megakernel"))):
+                ("fused", lambda: session.run_fused(prog, state))):
             with session.count_dispatches() as scope:
                 run()
             energies[mode] = scope.energy_nj
@@ -319,8 +317,6 @@ def fig18_energy_modes():
         rows.append((f"fig18_{wl}_savings", 0.0,
                      f"fused_vs_per_op="
                      f"{energies['per_op']/energies['fused']:.2f};"
-                     f"mega_vs_per_op="
-                     f"{energies['per_op']/energies['megakernel']:.2f};"
                      f"pud_vs_per_op={energies['per_op']/pud_nj:.2f}"))
     rows.append(("fig18_dispatch_energy_nj", 0.0,
                  f"per_launch={COST.dispatch_energy_nj(1):.1f}"))

@@ -7,8 +7,8 @@ process, at 65,536-bit DRAM rows (2,048 ``uint32`` words):
 * ``session`` — ``DramSession("pallas")`` against ``DramSession("oracle")``
   on the same seeded data: MAJ3/5/7/9 over 1,024 rows, Multi-RowCopy
   1->31 of 512 rows, ``mismatch`` over 64 MiB, and the §8.1 add32
-  program on 2^20 lanes (``run_fused`` in ``fused`` and ``megakernel``
-  mode, and ``elementwise("add")``);
+  program on 2^20 lanes (``run_fused`` twice: the eager level walk, then
+  the jitted one, and ``elementwise("add")``);
 * ``service`` — ``PudService`` on ``pallas`` with 4 tenants: one heal of
   3 x 1,024 rows each with one replica corrupted at a 1e-5 bit-error
   rate, one integrity check each, and one erase of 1,024 rows at
@@ -166,10 +166,8 @@ def session_phase(sm: Smoke) -> str:
     import numpy as np
 
     from repro.backends import ExecutionContext
-    from repro.compile import compile_elementwise, lower_schedule, plan_vmem
-    from repro.compile.schedule import build_schedule
+    from repro.compile import compile_elementwise
     from repro.kernels.majx.ops import majx
-    from repro.kernels.megakernel.ops import run_lowering
     from repro.kernels.mismatch.ops import mismatch_count
     from repro.kernels.rowcopy.ops import fanout
     from repro.session import DramSession
@@ -216,18 +214,12 @@ def session_phase(sm: Smoke) -> str:
     want = xa + xb
     cp = compile_elementwise("add", xa, xb, tier=ctx.tier, n_act=ctx.n_act)
     ref = ora.run_fused(cp.program, cp.state)
-    low = lower_schedule(build_schedule(cp.program))
     rows, words = cp.state.shape
-    plan = plan_vmem(low, rows, words, ctx.vmem_budget_bytes,
-                     block_r=ctx.block_r)
-    sm.assert_kernel("megakernel add32", functools.partial(
-        run_lowering, low, block_c=plan.block_c, interpret=be.interpret),
-        _u32(cp.state.shape))
-    for mode in ("fused", "megakernel"):
-        final = pal.run_fused(cp.program, cp.state, mode=mode)
-        check(jnp.array_equal(final, ref), f"add32 {mode} pallas == oracle")
+    for walk in ("eager", "jitted"):
+        final = pal.run_fused(cp.program, cp.state)
+        check(jnp.array_equal(final, ref), f"add32 {walk} pallas == oracle")
         check(np.array_equal(np.asarray(cp.outputs(final)), want),
-              f"add32 {mode} == numpy")
+              f"add32 {walk} == numpy")
     got, _ = pal.elementwise("add", xa, xb)
     ref_el, _ = ora.elementwise("add", xa, xb)
     check(np.array_equal(np.asarray(got), np.asarray(ref_el))
@@ -236,8 +228,9 @@ def session_phase(sm: Smoke) -> str:
     return (f"MAJ3/5/7/9 {s.maj_rows}x{s.words} | MRC 1->31 "
             f"{s.mrc_rows}x{s.words} | mismatch {s.mismatch_bytes} B "
             f"({bad} bits differ) | add32 {s.add_lanes} lanes, "
-            f"{len(cp.program.ops)} ops, {low.n_levels} levels, "
-            f"{rows}x{words} image, megakernel block_c={plan.block_c}")
+            f"{len(cp.program.ops)} ops, "
+            f"{pal.schedule_for(cp.program).n_levels} levels, "
+            f"{rows}x{words} image")
 
 
 def service_phase(sm: Smoke) -> str:

@@ -10,14 +10,8 @@ README.md), plus the *module docstrings* of ``examples/*.py`` and
 * repo file paths (``src/repro/sweep/spec.py``, ``scripts/ci.sh``, ...)
 
 and fails listing every reference that does not resolve to a real file
-under the repo.  It also cross-checks the ``repro-bench/*`` result
-schema ids three ways: every id mentioned in the docs or gated by
-``scripts/ci.sh`` must be one a benchmark script actually writes (a
-``SCHEMA = "repro-bench/..."`` assignment), and every written id must
-appear in both — so a schema bump that forgets ``docs/BENCH.md`` or
-the CI gate fails here instead of surprising a downstream consumer.
-Keeps the docs layer honest as modules move: CI runs this after the
-test suite (see ``scripts/ci.sh``).
+under the repo.  Keeps the docs layer honest as modules move: CI runs
+this after the test suite (see ``scripts/ci.sh``).
 """
 
 from __future__ import annotations
@@ -37,9 +31,6 @@ MODULE_RE = re.compile(r"\brepro(?:\.\w+)+")
 PATH_RE = re.compile(
     r"\b(?:src|docs|scripts|tests|benchmarks|results|examples)"
     r"/[\w./-]+\.(?:py|md|sh|json|toml)\b")
-SCHEMA_RE = re.compile(r"\brepro-bench/[a-z0-9-]+\b")
-SCHEMA_DEF_RE = re.compile(r"^SCHEMA\s*=\s*[\"'](repro-bench/[a-z0-9-]+)",
-                           re.M)
 
 
 def code_regions(text: str):
@@ -70,37 +61,6 @@ def module_docstring(path: str) -> str:
         except SyntaxError:
             return ""
     return ast.get_docstring(tree) or ""
-
-
-def check_schema_ids() -> tuple[list[str], int]:
-    """Cross-check repro-bench/* ids: docs and CI vs bench-script writers.
-
-    Three-way consistency: every id the docs (or the ``scripts/ci.sh``
-    bench gates) reference must be one a benchmark actually writes, and
-    every written id must appear in both — so a schema bump that
-    forgets ``docs/BENCH.md`` or the CI gate's ``assert doc["schema"]``
-    fails here instead of surprising a downstream consumer.
-    """
-    written: set[str] = set()
-    for path in sorted(glob.glob(os.path.join(REPO, "benchmarks", "*.py"))):
-        with open(path) as f:
-            written.update(SCHEMA_DEF_RE.findall(f.read()))
-    documented: set[str] = set()
-    for path in sorted(glob.glob(os.path.join(REPO, "docs", "*.md"))) + \
-            [os.path.join(REPO, "README.md")]:
-        with open(path) as f:
-            documented.update(SCHEMA_RE.findall(f.read()))
-    with open(os.path.join(REPO, "scripts", "ci.sh")) as f:
-        gated = set(SCHEMA_RE.findall(f.read()))
-    problems = [f"docs mention schema {s!r} that no benchmark writes"
-                for s in sorted(documented - written)]
-    problems += [f"benchmarks write schema {s!r} never documented in "
-                 f"docs/*.md" for s in sorted(written - documented)]
-    problems += [f"scripts/ci.sh gates on schema {s!r} that no benchmark "
-                 f"writes" for s in sorted(gated - written)]
-    problems += [f"benchmarks write schema {s!r} that scripts/ci.sh never "
-                 f"gates on" for s in sorted(written - gated)]
-    return problems, len(written | documented | gated)
 
 
 def main() -> int:
@@ -135,17 +95,14 @@ def main() -> int:
         # the same way doc-file references do.
         scan(os.path.relpath(path, REPO), module_docstring(path))
 
-    schema_problems, n_schemas = check_schema_ids()
-    if bad or schema_problems:
+    if bad:
         print("unresolved doc references:")
         for doc, ref in sorted(set(bad)):
             print(f"  {doc}: {ref}")
-        for msg in schema_problems:
-            print(f"  {msg}")
         return 1
     print(f"docs check OK ({n_refs} code references across "
           f"{len(docs)} doc files + {len(scripts)} script docstrings "
-          f"resolve; {n_schemas} bench schema id(s) consistent)")
+          f"resolve)")
     return 0
 
 

@@ -4,18 +4,18 @@ Subjects (combine freely; ``--all`` is every subject plus the negative
 mutation gate and the certificate-cache check):
 
 * ``--golden``  — every ``tests/golden/*.json`` fixture program,
-  certified against a freshly built schedule AND megakernel lowering;
-  when the fixture carries a frozen ``certificate`` section, the
-  recomputed digest must match it byte-for-byte.
+  certified against a freshly built schedule; when the fixture carries
+  a frozen ``certificate`` section, the recomputed digest must match it
+  byte-for-byte.
 * ``--serve``   — the heal and erase tick programs the serve batcher
   actually builds (captured from a real
   :class:`~repro.serve.batcher.Batcher` tick on the oracle backend).
 * ``--sweep``   — the fused MAJX chunk programs of the smoke sweep
   spec, as planned by :func:`repro.sweep.planner.plan`.
 * ``--mutate``  — the negative gate: every applicable seeded mutation
-  (:mod:`repro.analyze.mutate`) of every golden lowering must be
-  *rejected*; an accepted mutation is a hole in the analyzer and fails
-  the run.
+  (:mod:`repro.analyze.mutate`) of every golden schedule must be
+  *rejected*, and every mutation must apply to at least one fixture;
+  an accepted mutation is a hole in the analyzer and fails the run.
 * ``--cache-check`` — certify one golden program twice through a fresh
   :class:`~repro.session.cache.CompileCache` and assert the second
   lookup is a pure cache hit (zero re-analysis).
@@ -34,7 +34,6 @@ import sys
 
 from repro.analyze.cert import CertificationError, certify
 from repro.analyze.mutate import MUTATIONS
-from repro.compile.megakernel import lower_schedule
 from repro.compile.schedule import build_schedule
 from repro.pud.isa import Program
 
@@ -53,11 +52,9 @@ def _load_golden(path: str) -> tuple[str, Program, dict]:
 
 def _certify_one(name: str, prog: Program, *, verbose: bool,
                  frozen: dict | None = None) -> bool:
-    """Certify prog (schedule + lowering); print one line; True on OK."""
-    sched = build_schedule(prog)
-    low = lower_schedule(sched)
+    """Certify prog against its schedule; print one line; True on OK."""
     try:
-        cert = certify(prog, sched=sched, lowering=low, where=name)
+        cert = certify(prog, sched=build_schedule(prog), where=name)
     except CertificationError as e:
         print(f"FAIL {name}")
         print("  " + "\n  ".join(str(f) for f in e.report.errors[:10]))
@@ -106,9 +103,9 @@ def lint_serve(verbose: bool) -> bool:
     captured: list[tuple[str, Program]] = []
     inner = session.run_fused
 
-    def run_fused(prog, state, **kw):
+    def run_fused(prog, state):
         captured.append((prog.ops[0].tag or "tick", prog))
-        return inner(prog, state, **kw)
+        return inner(prog, state)
 
     session.run_fused = run_fused  # capture the real construction path
     rng = np.random.default_rng(7)
@@ -155,16 +152,14 @@ def mutation_gate(golden_dir: str, verbose: bool) -> bool:
     rejected: dict[str, int] = {m: 0 for m in MUTATIONS}
     for name, prog, _ in _golden_programs(golden_dir):
         sched = build_schedule(prog)
-        low = lower_schedule(sched)
         for mname, fn in MUTATIONS.items():
-            bad = fn(low)
+            bad = fn(sched)
             if bad is None:
-                continue  # no site on this fixture (e.g. no NOT ops)
+                continue  # no site on this fixture (e.g. no MAJ ops)
             applied[mname] += 1
             try:
-                certify(prog, sched=sched, lowering=bad,
-                        where=f"{name}+{mname}")
-                print(f"FAIL mutate/{name}+{mname}: corrupted lowering "
+                certify(prog, sched=bad, where=f"{name}+{mname}")
+                print(f"FAIL mutate/{name}+{mname}: corrupted schedule "
                       f"was certified — analyzer hole")
                 ok = False
             except CertificationError as e:
@@ -190,9 +185,8 @@ def cache_check(golden_dir: str) -> bool:
     name, prog, _ = _golden_programs(golden_dir)[0]
     cache = CompileCache()
     sched = cache.schedule_for(prog)
-    low = cache.lowering_for(prog, sched=sched)
-    first = cache.certificate_for(prog, sched=sched, lowering=low)
-    again = cache.certificate_for(prog, sched=sched, lowering=low)
+    first = cache.certificate_for(prog, sched=sched)
+    again = cache.certificate_for(prog, sched=sched)
     stats = cache.certificate_stats
     if stats.hits != 1 or stats.misses != 1 or first is not again:
         print(f"FAIL cache: expected 1 miss + 1 hit, got "

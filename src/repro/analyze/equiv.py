@@ -1,12 +1,12 @@
 """Dataflow-equivalence certification by symbolic execution.
 
 The differential suites sample random programs; this pass *proves* a
-specific compiled artifact.  All three execution forms of a program —
-the sequential op stream, the hazard-leveled
-:class:`~repro.compile.schedule.Schedule`, and the megakernel
-:class:`~repro.compile.megakernel.MegaLowering` slot tables — are
-symbolically executed over an abstract dataflow domain, and the final
-per-row values must be *structurally identical* terms.
+specific compiled artifact.  Both execution forms of a program — the
+sequential op stream and the hazard-leveled
+:class:`~repro.compile.schedule.Schedule`, executed as the level walk
+executes it — are symbolically executed over an abstract dataflow
+domain, and the final per-row values must be *structurally identical*
+terms.
 
 The domain is a hash-consed term algebra:
 
@@ -16,28 +16,23 @@ The domain is a hash-consed term algebra:
   constant folding,
 * ``Maj(v_1..v_k)`` — bit-position majority, canonicalized by operand
   *sort* (majority is symmetric; duplicates are preserved — input
-  replication is semantically meaningful), with two sound rewrites:
+  replication is semantically meaningful), with one sound rewrite,
+  **arity-padding cancellation**: matched (Const0, Const1) operand
+  pairs are removed — the exact ``MAJ_k == MAJ_{k+2m}(.., 0*m, 1*m)``
+  identity the walk relies on when it pads each MAJ op to its group's
+  arity (each pair adds one to the popcount and one to the threshold).
 
-  - **arity-padding cancellation**: matched (Const0, Const1) operand
-    pairs are removed — the exact
-    ``MAJ_k == MAJ_{k+2m}(.., 0*m, 1*m)`` identity the fused and
-    megakernel paths rely on (each pair adds one to the popcount and
-    one to the threshold);
-  - **identity collapse**: a 1-ary majority is its operand (how the
-    MRC/COPY/NOT arity-1 expansion slots certify), and an all-constant
-    majority folds to its constant.
+The rewrite is a true identity of the concrete semantics, so equal
+normal forms imply bit-equal execution on every backend; it is exactly
+the transformation the walk performs, so correct compiler output always
+normalizes back onto the source program's terms — any surviving
+structural difference is a genuine compilation bug (or an injected
+mutation: see :mod:`repro.analyze.mutate`).
 
-Every rewrite is a true identity of the concrete semantics, so equal
-normal forms imply bit-equal execution on every backend; the rewrites
-are exactly the transformations the compiler performs, so the correct
-compiler output always normalizes back onto the source program's terms
-— any surviving structural difference is a genuine compilation bug
-(or an injected mutation: see :mod:`repro.analyze.mutate`).
-
-Hazard semantics match the executors: schedule and table execution
-read the *level-entry* state and commit writes at level exit, while
-the sequential reference commits op by op.  A leveling bug therefore
-shows up as a term mismatch here even if the race pass missed it.
+Hazard semantics match the executors: schedule execution reads the
+*level-entry* state and commits writes at level exit, while the
+sequential reference commits op by op.  A leveling bug therefore shows
+up as a term mismatch here even if the race pass missed it.
 """
 
 from __future__ import annotations
@@ -45,8 +40,6 @@ from __future__ import annotations
 from typing import Optional
 
 from repro.analyze.report import ERROR, Finding
-from repro.compile.megakernel import (MegaLowering, N_CONST_ROWS, ONE_ROW,
-                                      ZERO_ROW)
 from repro.compile.schedule import Schedule
 from repro.pud.isa import Program
 
@@ -94,12 +87,6 @@ class SymbolicDomain:
             ops.remove(self.const1)
         if not ops:
             raise ValueError("majority over zero operands")
-        if len(ops) == 1:
-            return ops[0]            # MAJ_1(v) = v (identity slots)
-        consts = {self.const0, self.const1}
-        if all(o in consts for o in ops):
-            ones = sum(1 for o in ops if o == self.const1)
-            return self.const1 if 2 * ones > len(ops) else self.const0
         return self._intern(("maj", tuple(sorted(ops))))
 
     def render(self, v: int, depth: int = 3) -> str:
@@ -118,12 +105,14 @@ class SymbolicDomain:
         return f"maj({args}{more})"
 
 
-def _apply_op(dom: SymbolicDomain, op, read) -> Optional[int]:
-    """The value an op writes to every destination, reading via ``read``."""
+def _apply_op(dom: SymbolicDomain, op, read, pad: int = 0) -> Optional[int]:
+    """The value an op writes to every destination, reading via ``read``;
+    a MAJ votes ``pad`` constant (0, 1) pairs besides its sources."""
     if not op.dsts or op.kind in _SKIP_KINDS:
         return None
     if op.kind == "MAJ":
-        return dom.maj(tuple(read(s) for s in op.srcs))
+        return dom.maj(tuple(read(s) for s in op.srcs)
+                       + (dom.const0, dom.const1) * pad)
     if op.kind == "NOT":
         return dom.not_(read(op.srcs[0]))
     if op.kind in ("COPY", "MRC"):
@@ -147,13 +136,16 @@ def exec_program(dom: SymbolicDomain, program: Program,
 
 def exec_schedule(dom: SymbolicDomain, sched: Schedule,
                   n_rows: int) -> list[int]:
-    """Level-at-a-time execution: entry-state reads, exit commits."""
+    """Level-at-a-time execution as the walk runs it: entry-state reads,
+    exit commits, each MAJ padded to its group's arity (a group that
+    cannot be padded so is the race pass's ``SCHED_GROUP`` finding)."""
     state = [dom.input(r) for r in range(n_rows)]
     for lvl in sched.levels:
         entry = list(state)
         for g in lvl:
             for op in g.ops:
-                v = _apply_op(dom, op, lambda s: entry[s])
+                pad = (g.param - len(op.srcs)) // 2 if g.kind == "MAJ" else 0
+                v = _apply_op(dom, op, lambda s: entry[s], max(pad, 0))
                 if v is None:
                     continue
                 for d in op.dsts:
@@ -161,32 +153,11 @@ def exec_schedule(dom: SymbolicDomain, sched: Schedule,
     return state
 
 
-def exec_lowering(dom: SymbolicDomain, low: MegaLowering) -> list[int]:
-    """Slot-table execution over the augmented (const-prefixed) image.
-
-    Returns the augmented row values; program row ``r`` lives at index
-    ``r + N_CONST_ROWS``.  The trash row participates (inert slots
-    write it) but is excluded from comparison by the caller.
-    """
-    state = [dom.const0, dom.const1, dom.const0]   # zero / one / trash
-    state += [dom.input(r) for r in range(low.n_rows)]
-    for li in range(low.n_levels):
-        entry = list(state)
-        for w in range(low.w_max):
-            operands = tuple(entry[int(r)] for r in low.src[li, w])
-            v = dom.maj(operands)
-            if low.inv[li, w]:
-                v = dom.not_(v)
-            state[int(low.dst[li, w])] = v
-    return state
-
-
 def equivalence_findings(program: Program, sched: Optional[Schedule] = None,
-                         lowering: Optional[MegaLowering] = None, *,
-                         where: str = "program") -> list[Finding]:
-    """Prove schedule / lowering dataflow equal to the source program.
+                         *, where: str = "program") -> list[Finding]:
+    """Prove the schedule's dataflow equal to the source program's.
 
-    One shared :class:`SymbolicDomain` interns all three executions, so
+    One shared :class:`SymbolicDomain` interns both executions, so
     comparison is integer equality per row.  Findings carry rendered
     terms for the first few mismatching rows.
     """
@@ -204,26 +175,4 @@ def equivalence_findings(program: Program, sched: Optional[Schedule] = None,
                     f"{where}: schedule computes row {r} = "
                     f"{dom.render(got[r])}, program computes "
                     f"{dom.render(ref[r])}", where=f"row {r}"))
-
-    if lowering is not None:
-        if lowering.n_rows != n_rows:
-            out.append(Finding(
-                "equivalence", ERROR, "EQ_TABLE_SHAPE",
-                f"{where}: lowering covers {lowering.n_rows} program "
-                f"rows, program addresses {n_rows}"))
-            return out
-        aug = exec_lowering(dom, lowering)
-        if aug[ZERO_ROW] != dom.const0 or aug[ONE_ROW] != dom.const1:
-            out.append(Finding(
-                "equivalence", ERROR, "EQ_CONST_CLOBBERED",
-                f"{where}: a slot overwrote the constant 0/1 rows — "
-                f"every later padded vote is corrupted"))
-        for r in range(n_rows):
-            if aug[r + N_CONST_ROWS] != ref[r]:
-                out.append(Finding(
-                    "equivalence", ERROR, "EQ_TABLE_ROW",
-                    f"{where}: level tables compute row {r} = "
-                    f"{dom.render(aug[r + N_CONST_ROWS])}, program "
-                    f"computes {dom.render(ref[r])}", where=f"row {r}"))
-    # TRASH_ROW deliberately uncompared: it is the inert-slot sink.
     return out

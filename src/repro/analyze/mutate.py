@@ -1,31 +1,32 @@
-"""Seeded mutations of megakernel slot tables — the analyzer's negative gate.
+"""Seeded mutations of fused schedules — the analyzer's negative gate.
 
 A verifier that has only ever seen correct compiler output is
-untested.  This module applies small, *realistic* corruptions to a
-:class:`~repro.compile.megakernel.MegaLowering` — each one a bug class
-the lowering code could plausibly grow — and CI asserts that
-:func:`repro.analyze.cert.certify` rejects every applicable mutation on
-every golden fixture (``python -m repro.analyze --mutate``).
+untested.  This module applies small, *realistic* corruptions to the
+:class:`~repro.compile.schedule.Schedule` the level walk executes —
+each one a bug class the scheduler could plausibly grow — and CI
+asserts that :func:`repro.analyze.cert.certify` rejects every
+applicable mutation on the golden fixtures (``python -m repro.analyze
+--mutate``).
 
-Each mutation returns a new lowering (the input is never modified) or
-``None`` when the artifact has no site for it (e.g. ``drop_inv`` on a
-program without NOT ops).  Mutations prefer sites in the *latest*
-applicable level so the corruption survives to the final state and the
-equivalence pass cannot be masked by a later overwrite.
+Each mutation returns a new schedule (schedules are immutable, so the
+input is never modified) or ``None`` when the schedule has no site for
+it (e.g. ``shrink_param`` on a program without MAJ ops).  Mutations
+prefer sites in the *latest* applicable level.
 
 The six classes and the finding each must trigger:
 
-==================  ====================================================
-``swap_dst``        two slots' destinations exchanged → ``EQ_TABLE_ROW``
-``drop_inv``        a NOT slot's invert flag cleared → ``EQ_TABLE_ROW``
-``reorder_level``   two dependent levels swapped → stale entry reads
-``const_write``     a live slot retargeted at the constant-zero row →
-                    ``RACE_CONST_WRITE`` (and clobbered-const dataflow)
-``truncate_slot``   a live slot blanked to inert padding → its write
-                    vanishes → ``EQ_TABLE_ROW``
-``stale_pad``       one constant-one pad operand flipped to zero → the
-                    pad pairs no longer cancel → ``EQ_TABLE_ROW``
-==================  ====================================================
+================  ======================================================
+``swap_dst``      two differing ops of one level exchange destinations
+                  → ``SCHED_OP_SET`` / ``EQ_SCHEDULE_ROW``
+``hoist``         an op moved one level earlier, next to the op it
+                  reads from → ``RACE_RAW_LEVEL``
+``drop_op``       one op removed → ``SCHED_OP_SET``
+``retarget_src``  one source pointed at another row → ``SCHED_OP_SET``
+``waw``           one op made to write another same-level op's row
+                  with a different value → ``RACE_WAW_LEVEL``
+``shrink_param``  a MAJ group's arity below one of its ops' →
+                  ``SCHED_GROUP``
+================  ======================================================
 """
 
 from __future__ import annotations
@@ -33,133 +34,146 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Iterator, Optional
 
-import numpy as np
+from repro.compile.schedule import FusedGroup, Schedule
+from repro.pud.isa import PUDOp
 
-from repro.analyze.races import _is_inert_slot
-from repro.compile.megakernel import (MegaLowering, ONE_ROW, TRASH_ROW,
-                                      ZERO_ROW)
-
-
-def _copy(low: MegaLowering) -> MegaLowering:
-    return dataclasses.replace(low, src=low.src.copy(), dst=low.dst.copy(),
-                               inv=low.inv.copy())
+Levels = list[list[FusedGroup]]
 
 
-def _live_slots(low: MegaLowering, reverse: bool = True
-                ) -> Iterator[tuple[int, int]]:
-    """(level, slot) pairs of non-inert slots, latest level first."""
-    levels = range(low.n_levels - 1, -1, -1) if reverse \
-        else range(low.n_levels)
-    for li in levels:
-        for w in range(low.w_max):
-            if not _is_inert_slot(low.src[li, w], int(low.dst[li, w]),
-                                  int(low.inv[li, w])):
-                yield li, w
+def _levels(sched: Schedule) -> Levels:
+    return [list(lvl) for lvl in sched.levels]
 
 
-def _slot_sig(low: MegaLowering, li: int, w: int) -> tuple:
-    return (tuple(int(r) for r in low.src[li, w]), int(low.inv[li, w]))
+def _schedule(levels: Levels) -> Schedule:
+    return Schedule(tuple(tuple(g for g in lvl if g.ops) for lvl in levels
+                          if any(g.ops for g in lvl)))
 
 
-def swap_dst(low: MegaLowering) -> Optional[MegaLowering]:
-    """Exchange the destination rows of two differing slots of one level."""
-    by_level: dict[int, list[int]] = {}
-    for li, w in _live_slots(low):
-        by_level.setdefault(li, []).append(w)
-    for li in sorted(by_level, reverse=True):
-        slots = by_level[li]
-        for a in slots:
-            for b in slots:
-                if (low.dst[li, a] != low.dst[li, b]
-                        and _slot_sig(low, li, a) != _slot_sig(low, li, b)):
-                    m = _copy(low)
-                    m.dst[li, a], m.dst[li, b] = (low.dst[li, b],
-                                                  low.dst[li, a])
-                    return m
+def _sites(sched: Schedule) -> Iterator[tuple[int, int, int, PUDOp]]:
+    """(level, group, op index, op), latest level first."""
+    for li in range(sched.n_levels - 1, -1, -1):
+        for gi, g in enumerate(sched.levels[li]):
+            for oi, op in enumerate(g.ops):
+                yield li, gi, oi, op
+
+
+def _replace_op(levels: Levels, li: int, gi: int, oi: int,
+                op: Optional[PUDOp]) -> None:
+    """Put ``op`` at ``(li, gi, oi)``, or drop the op there when None."""
+    g = levels[li][gi]
+    ops = list(g.ops)
+    if op is None:
+        del ops[oi]
+    else:
+        ops[oi] = op
+    levels[li][gi] = dataclasses.replace(g, ops=tuple(ops))
+
+
+def _value(op: PUDOp) -> tuple:
+    return (op.kind, op.x, op.srcs)
+
+
+def swap_dst(sched: Schedule) -> Optional[Schedule]:
+    """Exchange the destinations of two differing ops of one level."""
+    for li, gi, oi, op in _sites(sched):
+        for gj, g in enumerate(sched.levels[li]):
+            for oj, other in enumerate(g.ops):
+                if (other.dsts != op.dsts
+                        and _value(other) != _value(op)):
+                    levels = _levels(sched)
+                    _replace_op(levels, li, gi, oi,
+                                dataclasses.replace(op, dsts=other.dsts))
+                    _replace_op(levels, li, gj, oj,
+                                dataclasses.replace(other, dsts=op.dsts))
+                    return _schedule(levels)
     return None
 
 
-def drop_inv(low: MegaLowering) -> Optional[MegaLowering]:
-    """Clear the invert flag of one NOT slot."""
-    for li, w in _live_slots(low):
-        if low.inv[li, w]:
-            m = _copy(low)
-            m.inv[li, w] = 0
-            return m
+def hoist(sched: Schedule) -> Optional[Schedule]:
+    """Move an op one level earlier, into the level that writes a row
+    it reads (a same-level RAW)."""
+    for li, gi, oi, op in _sites(sched):
+        if li == 0:
+            continue
+        below = {d for g in sched.levels[li - 1] for o in g.ops
+                 for d in o.dsts}
+        if not below & set(op.srcs):
+            continue
+        levels = _levels(sched)
+        _replace_op(levels, li, gi, oi, None)
+        g = sched.levels[li][gi]
+        for gj, h in enumerate(levels[li - 1]):
+            if h.kind == g.kind:
+                levels[li - 1][gj] = FusedGroup(
+                    g.kind, max(g.param, h.param), (*h.ops, op))
+                break
+        else:
+            levels[li - 1].append(FusedGroup(g.kind, g.param, (op,)))
+        return _schedule(levels)
     return None
 
 
-def reorder_level(low: MegaLowering) -> Optional[MegaLowering]:
-    """Swap two adjacent levels that carry a real dataflow dependency.
-
-    Only dependent pairs qualify — swapping independent levels is
-    legal, and a mutation the analyzer *should* accept is not a
-    negative test.
-    """
-    for li in range(low.n_levels - 2, -1, -1):
-        written = {int(low.dst[li, w]) for li_, w in _live_slots(low)
-                   if li_ == li} - {TRASH_ROW}
-        reads_next = {int(r) for li_, w in _live_slots(low) if li_ == li + 1
-                      for r in low.src[li_, w]}
-        if written & reads_next:
-            m = _copy(low)
-            for arr in (m.src, m.dst, m.inv):
-                arr[[li, li + 1]] = arr[[li + 1, li]]
-            meta = list(low.level_meta)
-            meta[li], meta[li + 1] = meta[li + 1], meta[li]
-            return dataclasses.replace(m, level_meta=tuple(meta))
+def drop_op(sched: Schedule) -> Optional[Schedule]:
+    """Remove one op: its write silently vanishes."""
+    for li, gi, oi, _ in _sites(sched):
+        levels = _levels(sched)
+        _replace_op(levels, li, gi, oi, None)
+        return _schedule(levels)
     return None
 
 
-def const_write(low: MegaLowering) -> Optional[MegaLowering]:
-    """Retarget one live slot at the constant-zero row."""
-    for li, w in _live_slots(low):
-        m = _copy(low)
-        m.dst[li, w] = ZERO_ROW
-        return m
+def retarget_src(sched: Schedule) -> Optional[Schedule]:
+    """Point an op's first source at another row the schedule touches."""
+    rows = sorted({r for _, _, _, op in _sites(sched)
+                   for r in (*op.srcs, *op.dsts)})
+    for li, gi, oi, op in _sites(sched):
+        other = next((r for r in rows if r != op.srcs[0]), None)
+        if other is None:
+            return None
+        levels = _levels(sched)
+        _replace_op(levels, li, gi, oi, dataclasses.replace(
+            op, srcs=(other, *op.srcs[1:])))
+        return _schedule(levels)
     return None
 
 
-def truncate_slot(low: MegaLowering) -> Optional[MegaLowering]:
-    """Blank one live slot to inert padding — its write silently vanishes."""
-    for li, w in _live_slots(low):
-        m = _copy(low)
-        m.src[li, w] = ZERO_ROW
-        m.dst[li, w] = TRASH_ROW
-        m.inv[li, w] = 0
-        return m
+def waw(sched: Schedule) -> Optional[Schedule]:
+    """Make an op also write the first destination of another op of its
+    level that computes a different value."""
+    for li, gi, oi, op in _sites(sched):
+        for g in sched.levels[li]:
+            for other in g.ops:
+                d = other.dsts[0]
+                if _value(other) != _value(op) and d not in op.dsts:
+                    levels = _levels(sched)
+                    _replace_op(levels, li, gi, oi, dataclasses.replace(
+                        op, dsts=(d, *op.dsts[1:])))
+                    return _schedule(levels)
     return None
 
 
-def stale_pad(low: MegaLowering) -> Optional[MegaLowering]:
-    """Flip one constant-one pad operand to constant-zero.
-
-    Breaks the ``MAJ_k == MAJ_{k+2m}`` padding identity: the popcount
-    threshold no longer matches the added constants, so the slot votes
-    a different function than its source op.  Real operand rows are
-    shifted past the constant prefix, so any ``ONE_ROW`` operand in a
-    live slot is padding by construction.
-    """
-    for li, w in _live_slots(low):
-        ones = np.flatnonzero(low.src[li, w] == ONE_ROW)
-        if ones.size:
-            m = _copy(low)
-            m.src[li, w, int(ones[-1])] = ZERO_ROW
-            return m
+def shrink_param(sched: Schedule) -> Optional[Schedule]:
+    """Give a MAJ group an arity two below its widest op's."""
+    for li in range(sched.n_levels - 1, -1, -1):
+        for gi, g in enumerate(sched.levels[li]):
+            if g.kind == "MAJ":
+                levels = _levels(sched)
+                levels[li][gi] = dataclasses.replace(g, param=g.param - 2)
+                return _schedule(levels)
     return None
 
 
 #: Name -> mutation, in the order CI reports them.
-MUTATIONS: dict[str, Callable[[MegaLowering], Optional[MegaLowering]]] = {
+MUTATIONS: dict[str, Callable[[Schedule], Optional[Schedule]]] = {
     "swap_dst": swap_dst,
-    "drop_inv": drop_inv,
-    "reorder_level": reorder_level,
-    "const_write": const_write,
-    "truncate_slot": truncate_slot,
-    "stale_pad": stale_pad,
+    "hoist": hoist,
+    "drop_op": drop_op,
+    "retarget_src": retarget_src,
+    "waw": waw,
+    "shrink_param": shrink_param,
 }
 
 
-def apply_mutation(low: MegaLowering, name: str) -> Optional[MegaLowering]:
-    """Apply one named mutation; None when the artifact has no site."""
-    return MUTATIONS[name](low)
+def apply_mutation(sched: Schedule, name: str) -> Optional[Schedule]:
+    """Apply one named mutation; None when the schedule has no site."""
+    return MUTATIONS[name](sched)

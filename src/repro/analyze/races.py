@@ -1,6 +1,6 @@
-"""Race detection over Programs, Schedule levels, and lowered slot tables.
+"""Race detection over Programs and their Schedule levels.
 
-The fused executors assume one hazard model — *reads sample the
+The level walk assumes one hazard model — *reads sample the
 level-entry state, writes commit at level exit* — and the scheduler's
 leveling is what makes that model agree with sequential program order.
 This pass re-derives the safety conditions from the artifacts
@@ -10,22 +10,21 @@ themselves instead of trusting the compiler:
   :func:`repro.session.validate.check_program` call runs: row addresses
   in range, no destination written twice inside one op, MAJ arity
   odd/complete, single-source kinds single-sourced.
-* **Schedule levels** (:func:`schedule_findings`) — no two ops of one
-  level writing the same row with different values (intra-level WAW;
-  identical redundant writes, e.g. one op's duplicated destination
-  list, are benign), and no op reading a row that an
-  earlier-in-program-order op of the *same* level writes (intra-level
-  RAW: the executor would feed it stale entry state).  WAR sharing —
-  a writer leveled with earlier readers of its destination — is legal
-  by the entry-state model and is not flagged.
-* **Slot tables** (:func:`lowering_findings`) — per level of a
-  :class:`~repro.compile.megakernel.MegaLowering`: no two live slots
-  writing one row (unless they compute the identical vote), no slot
-  writing the front constant rows, no live slot reading the trash row,
-  all indices inside the augmented image, pad parity intact.
+* **Schedule levels** (:func:`schedule_findings`) — every group
+  well-formed for the walk that executes it (each op of the group's
+  kind; a MAJ group's ``param`` at least each op's arity and of the
+  same parity, since the walk pads the difference with constant 0/1
+  pairs; an MRC group's ``param`` at least each op's fan-out), no two
+  ops of one level writing the same row with different values
+  (intra-level WAW; identical redundant writes, e.g. one op's
+  duplicated destination list, are benign), and no op reading a row
+  that an earlier-in-program-order op of the *same* level writes
+  (intra-level RAW: the executor would feed it stale entry state).
+  WAR sharing — a writer leveled with earlier readers of its
+  destination — is legal by the entry-state model and is not flagged.
 
 Everything here is pure content inspection — no backend, no state — so
-the checks run at compile/cache-insert time in O(ops) / O(slots).
+the checks run at compile/cache-insert time in O(ops).
 """
 
 from __future__ import annotations
@@ -33,11 +32,7 @@ from __future__ import annotations
 import collections
 from typing import Iterator, Optional
 
-import numpy as np
-
 from repro.analyze.report import ERROR, WARNING, Finding
-from repro.compile.megakernel import (MegaLowering, N_CONST_ROWS, ONE_ROW,
-                                      TRASH_ROW, ZERO_ROW)
 from repro.compile.schedule import Schedule, VALUE_KINDS
 from repro.pud.isa import Program, PUDOp
 
@@ -168,10 +163,34 @@ def iter_level_ops(sched: Schedule, program: Optional[Program] = None
         yield li, sorted(annotated, key=lambda t: t[0])
 
 
+def _group_findings(sched: Schedule, where: str) -> list[Finding]:
+    """Each group shaped as the level walk that executes it needs."""
+    out: list[Finding] = []
+    for li, lvl in enumerate(sched.levels):
+        for g in lvl:
+            for op in g.ops:
+                k, fan = len(op.srcs), len(op.dsts)
+                if op.kind != g.kind:
+                    bad = f"holds a {op.kind} op"
+                elif g.kind == "MAJ" and (g.param < k or (g.param - k) % 2):
+                    bad = (f"cannot pad a MAJ{k} op with constant 0/1 "
+                           f"pairs")
+                elif g.kind == "MRC" and g.param < fan:
+                    bad = f"copies too few rows for an MRC op to {fan} rows"
+                else:
+                    continue
+                out.append(Finding(
+                    "race", ERROR, "SCHED_GROUP",
+                    f"{where}: level {li} {g.kind} group of param "
+                    f"{g.param} {bad}", where=f"level {li}"))
+    return out
+
+
 def schedule_findings(sched: Schedule, program: Optional[Program] = None,
                       where: str = "schedule") -> list[Finding]:
-    """Intra-level WAW / RAW races plus op-set completeness vs source."""
-    out: list[Finding] = []
+    """Malformed groups, intra-level WAW / RAW races, and op-set
+    completeness vs source."""
+    out = _group_findings(sched, where)
     for li, ops in iter_level_ops(sched, program):
         written: dict[int, tuple] = {}       # row -> value signature
         writer: dict[int, int] = {}          # row -> program index
@@ -210,69 +229,4 @@ def schedule_findings(sched: Schedule, program: Optional[Program] = None,
                 f"source program (missing {len(list((want - got).elements()))}, "
                 f"extra {len(list((got - want).elements()))}; e.g. "
                 f"missing={missing!r} extra={extra!r})"))
-    return out
-
-
-# --------------------------------------------------- lowered slot tables
-
-
-def _is_inert_slot(src_row: np.ndarray, dst: int, inv: int) -> bool:
-    """The padding shape :func:`lower_schedule` emits for unused slots."""
-    return (dst == TRASH_ROW and inv == 0
-            and bool(((src_row == ZERO_ROW) | (src_row == ONE_ROW)).all()))
-
-
-def lowering_findings(low: MegaLowering,
-                      where: str = "lowering") -> list[Finding]:
-    """Structural safety of megakernel level tables (see module doc)."""
-    out: list[Finding] = []
-    n_aug = low.n_rows + N_CONST_ROWS
-    if low.x_max % 2 == 0:
-        out.append(Finding(
-            "race", ERROR, "TAB_X_PARITY",
-            f"{where}: padded vote arity x_max={low.x_max} is even — "
-            f"majority is undefined"))
-    for li in range(low.n_levels):
-        writers: dict[int, tuple] = {}   # row -> (operand tuple, inv)
-        for w in range(low.w_max):
-            src_row = low.src[li, w]
-            dst = int(low.dst[li, w])
-            inv = int(low.inv[li, w])
-            here = f"level {li} / slot {w}"
-            if not 0 <= dst < n_aug:
-                out.append(Finding(
-                    "race", ERROR, "TAB_DST_RANGE",
-                    f"{where}: {here} writes row {dst}, outside the "
-                    f"{n_aug}-row augmented image", where=here))
-                continue
-            bad_src = [int(r) for r in src_row if not 0 <= r < n_aug]
-            if bad_src:
-                out.append(Finding(
-                    "race", ERROR, "TAB_SRC_RANGE",
-                    f"{where}: {here} reads row(s) {bad_src}, outside "
-                    f"the {n_aug}-row augmented image", where=here))
-                continue
-            if dst in (ZERO_ROW, ONE_ROW):
-                out.append(Finding(
-                    "race", ERROR, "RACE_CONST_WRITE",
-                    f"{where}: {here} writes constant row {dst} — the "
-                    f"0/1 planes every padded vote depends on",
-                    where=here))
-            inert = _is_inert_slot(src_row, dst, inv)
-            if not inert and TRASH_ROW in src_row:
-                out.append(Finding(
-                    "race", ERROR, "RACE_TRASH_READ",
-                    f"{where}: {here} reads the trash row "
-                    f"({TRASH_ROW}) outside an inert slot — trash "
-                    f"holds garbage from prior levels", where=here))
-            if dst == TRASH_ROW:
-                continue  # trash collects every inert write; never raced
-            sig = (tuple(int(r) for r in src_row), inv)
-            if dst in writers and writers[dst] != sig:
-                out.append(Finding(
-                    "race", ERROR, "RACE_WAW_SLOTS",
-                    f"{where}: level {li} has two slots scattering "
-                    f"different votes into row {dst} — scatter order "
-                    f"within a level is unspecified", where=here))
-            writers[dst] = sig
     return out

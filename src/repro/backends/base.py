@@ -49,7 +49,7 @@ class DispatchScope:
     modelled energy accrued (CostModel-priced: per-dispatch launch
     energy + HBM traffic on accelerated backends, per-DRAM-command
     Fig. 5 energy on the device-model backend), both frozen when the
-    ``with`` block exits — so two workloads (bench rows, tests) each
+    ``with`` block exits — so two workloads (figure rows, tests) each
     read their own window of the monotonic counters instead of sharing
     one mutable total that leaks across resets.
     """
@@ -104,16 +104,6 @@ class Capabilities:
             kernel dispatch rather than a python loop — the property
             the sweep planner exploits to fuse a chunk of grid points
             into one launch.
-        megakernel: True when ``run_fused(mode="megakernel")`` executes
-            a whole Schedule in ONE kernel dispatch via lowered level
-            tables (:mod:`repro.compile.megakernel`).  Backends without
-            it still accept the mode and fall back to their exact
-            per-op/level path — mode is a request, this flag is the
-            contract.
-        vmem_budget_bytes: on-chip working-set budget the megakernel
-            VMEM planner (:func:`repro.compile.megakernel.plan_vmem`)
-            blocks the word axis against.  Irrelevant when
-            ``megakernel`` is False.
     """
 
     name: str
@@ -124,8 +114,6 @@ class Capabilities:
     max_majx: int
     n_act_levels: tuple[int, ...]
     native_batch: bool
-    megakernel: bool = False
-    vmem_budget_bytes: int = 8 * 2**20
 
 
 class Backend(abc.ABC):
@@ -137,7 +125,7 @@ class Backend(abc.ABC):
         self.ctx = ctx or ExecutionContext()
         #: Kernel launches issued so far (bulk-op or program execution).
         #: Only accelerated backends increment it; it is the structural
-        #: metric the fusion layer optimizes and repro.bench records.
+        #: count the fusion layer reduces (a count, not a device time).
         self.dispatch_count = 0
         #: Modelled energy (nJ) accrued so far, priced by
         #: :data:`repro.core.costmodel.COST`: the ``pallas`` backend
@@ -163,7 +151,7 @@ class Backend(abc.ABC):
         Yields a :class:`DispatchScope` whose ``.count`` is the
         launches issued — and ``.energy_nj`` the modelled energy accrued
         — inside the ``with`` block (frozen at exit).  Scopes nest and
-        sequence independently, so concurrent bench workloads and tests
+        sequence independently, so concurrent workloads and tests
         cannot leak counts into each other.
 
         >>> with backend.count_dispatches() as scope:
@@ -242,33 +230,20 @@ class Backend(abc.ABC):
         return state
 
     def run_fused(self, program: Program, state: jax.Array, *,
-                  sched=None, mode: str = "fused",
-                  lowering=None) -> jax.Array:
+                  sched=None) -> jax.Array:
         """Execute an addressed Program through the fusion scheduler.
 
         Semantically identical to :meth:`run` (verified adversarially in
-        tests/test_compile_differential.py and
-        tests/test_megakernel_differential.py).  The default falls back
+        tests/test_compile_differential.py).  The default falls back
         to per-op interpretation, so device-model and reference backends
         keep their exact command-level semantics; backends with native
         batch dispatch (``pallas``) override this with level-batched
         kernel launches (see :mod:`repro.compile.schedule`).
 
-        ``mode`` selects the execution strategy: ``"fused"`` (level
-        batching, the default) or ``"megakernel"`` (one dispatch for the
-        whole schedule, see :mod:`repro.compile.megakernel`).  Every
-        backend accepts every mode — backends whose
-        :meth:`capabilities` don't advertise ``megakernel`` satisfy the
-        request with their exact fallback, so callers can set a mode
-        unconditionally and compare backends apples-to-apples.
-
-        ``sched`` / ``lowering`` optionally supply prebuilt compile
-        artifacts (the session layer's content-hash cache skips
-        re-scheduling and re-lowering on repeated programs).  Backends
-        that interpret per-op ignore both.
+        ``sched`` optionally supplies a prebuilt schedule (the session
+        layer's content-hash cache skips re-scheduling on repeated
+        programs).  Backends that interpret per-op ignore it.
         """
-        if mode not in ("fused", "megakernel"):
-            raise ValueError(f"unknown run_fused mode {mode!r}")
         return self.run(program, state)
 
     def _exec_op(self, op, state: jax.Array) -> jax.Array:

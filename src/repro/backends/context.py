@@ -68,9 +68,6 @@ class ExecutionContext:
       certificate_for`); set False to opt out on hot paths that already
       certified their programs elsewhere,
     * ``block_r`` / ``block_c`` — VPU tile geometry for bulk kernels,
-    * ``vmem_budget_bytes`` — on-chip working-set ceiling the megakernel
-      executor's column planner blocks against
-      (:func:`repro.compile.megakernel.plan_vmem`),
     * ``subarray_cols`` — behavioural-sim row width (bits),
     * ``seed`` — stable-mask RNG seed: the chip / row-group identity;
       sweeps treat distinct seeds as distinct tested chips.
@@ -89,7 +86,6 @@ class ExecutionContext:
     certify: bool = True
     block_r: int = 8
     block_c: int = 512
-    vmem_budget_bytes: int = 8 * 2**20
     subarray_cols: int = 1024
     seed: int = 0
 

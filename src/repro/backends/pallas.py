@@ -22,22 +22,17 @@ op, so a program that runs once pays no XLA compile.  The second time,
 the walk is jitted with its indices baked in as constants, and from
 then on each call is one dispatch of that compiled program.  The
 backend keeps the walks of its last ``WALK_CACHE_SIZE`` schedules.
-``run_fused(mode="megakernel")`` goes further: the whole schedule
-lowers to static level tables (:mod:`repro.compile.megakernel`) that
-ONE ``pallas_call`` scans end-to-end, VMEM-resident, column-blocked
-against ``Capabilities.vmem_budget_bytes`` when the image is too wide.
 :meth:`run_fused` opens the host span ``pud/backend.run_fused``
-(:mod:`repro.obs`) around the level executor in both modes: the image
-upload and the level walk (fused), or the one megakernel launch with
-its padding and crop; inside it, ``pud/backend.levels_build`` wraps
-building and first running a jitted walk, and ``pud/backend.levels_jit``
-each later dispatch of one.
-``self.dispatch_count`` tracks real kernel launches, which is the
-structural metric ``benchmarks/bench.py`` and the CI perf gate assert
-on; each launch also accrues :data:`repro.core.costmodel.COST`-priced
-energy (launch round-trip at board power + HBM traffic) into
-``self.energy_nj_total``, so fusion's dispatch savings show up in
-joules too.
+(:mod:`repro.obs`) around the level executor: the image upload and the
+level walk; inside it, ``pud/backend.levels_build`` wraps building and
+first running a jitted walk, and ``pud/backend.levels_jit`` each later
+dispatch of one.
+``self.dispatch_count`` counts MAJX / Multi-RowCopy launches (one per
+schedule group, however the walk runs), a structural count and not a
+device time; each launch also accrues
+:data:`repro.core.costmodel.COST`-priced energy (launch round-trip at
+board power + HBM traffic) into ``self.energy_nj_total``, so fusion's
+dispatch savings show up in modelled joules too.
 """
 
 from __future__ import annotations
@@ -95,8 +90,6 @@ class PallasBackend(Backend):
             max_majx=1_000_000,
             n_act_levels=cal.N_ACT_LEVELS,
             native_batch=True,
-            megakernel=True,
-            vmem_budget_bytes=self.ctx.vmem_budget_bytes,
         )
 
     def _launch(self, n_bytes: float) -> None:
@@ -140,34 +133,25 @@ class PallasBackend(Backend):
 
     # ------------------------------------------------- fused program path
     def run_fused(self, program: Program, state: jax.Array, *,
-                  sched=None, mode: str = "fused",
-                  lowering=None) -> jax.Array:
+                  sched=None) -> jax.Array:
         """Level-batched program execution (see module docstring).
 
         Reads sample the level-entry state and writes commit at level
         exit, matching the hazard model the scheduler levels against;
         WAW leveling guarantees the per-level scatters hit disjoint
-        rows.  Prebuilt ``sched`` / ``lowering`` artifacts (the session
-        compile cache) skip the scheduling and lowering passes entirely.
+        rows.  A prebuilt ``sched`` (the session compile cache) skips
+        the scheduling pass entirely.
 
-        Fused mode runs the schedule's level walk (:meth:`_walk`): eager
-        the first time the backend sees the schedule, then as one jitted
+        Runs the schedule's level walk (:meth:`_walk`): eager the first
+        time the backend sees the schedule, then as one jitted
         function, built on the second sighting (span
         ``pud/backend.levels_build``) and dispatched from then on (span
         ``pud/backend.levels_jit``).  Either way ``dispatch_count`` and
         ``energy_nj_total`` advance by one launch per MAJ/MRC group.
-
-        ``mode="megakernel"`` routes to :meth:`run_megakernel` — the
-        whole schedule in one dispatch.
         """
         from repro.compile.schedule import build_schedule
 
         with obs.span("backend.run_fused"):
-            if mode == "megakernel":
-                return self.run_megakernel(program, state, sched=sched,
-                                           lowering=lowering)
-            if mode != "fused":
-                raise ValueError(f"unknown run_fused mode {mode!r}")
             if sched is None:
                 sched = build_schedule(program)
             state = jnp.asarray(state, jnp.uint32)
@@ -238,35 +222,6 @@ class PallasBackend(Backend):
                     vals = self._copy(entry[g.srcs])
                 image = image.at[g.dsts].set(vals)
         return image[:rows]
-
-    def run_megakernel(self, program: Program, state: jax.Array, *,
-                       sched=None, lowering=None) -> jax.Array:
-        """The whole schedule in ONE Pallas dispatch.
-
-        Lowers the program's Schedule to static level tables
-        (:mod:`repro.compile.megakernel`), plans VMEM column blocking
-        against ``ctx.vmem_budget_bytes``, and scans every level inside
-        a single ``pallas_call``.  Value-neutral programs (no write
-        slots) are the identity at zero dispatches — there is nothing
-        to launch, matching what the empty fused walk does.
-        """
-        from repro.compile.megakernel import lower_schedule, plan_vmem
-        from repro.compile.schedule import build_schedule
-        from repro.kernels.megakernel.ops import run_lowering
-
-        if lowering is None:
-            if sched is None:
-                sched = build_schedule(program)
-            lowering = lower_schedule(sched)
-        state = jnp.asarray(state, jnp.uint32)
-        if lowering.n_levels == 0 or lowering.w_max == 0:
-            return state
-        rows, words = state.shape
-        plan = plan_vmem(lowering, rows, words, self.ctx.vmem_budget_bytes,
-                         block_r=self.ctx.block_r)
-        self._launch(2 * rows * words * 4)  # image in + image out
-        return run_lowering(lowering, state, block_c=plan.block_c,
-                            interpret=self.interpret)
 
 
 #: Level walks a backend keeps (schedules seen, most recent last); the

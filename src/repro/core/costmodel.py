@@ -284,7 +284,7 @@ class CostModel:
 
     def dispatch_overhead(self, n_dispatches: int = 1) -> float:
         """Host-side launch overhead (ns) of ``n_dispatches`` kernels —
-        the structural cost fusion and the megakernel collapse."""
+        the structural cost level fusion amortizes."""
         return n_dispatches * self.kernel_launch_ns
 
     def dispatch_energy_nj(self, n_dispatches: int = 1) -> float:

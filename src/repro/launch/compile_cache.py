@@ -1,10 +1,9 @@
 """JAX's persistent compilation cache, placed from outside the program.
 
 The entry points that compile for the chip (``chip_smoke.py``,
-``benchmarks/bench.py``, ``benchmarks/serve_bench.py`` and
-``python -m repro.sweep.run``) call :func:`enable_compile_cache` before
-their first compile, so processes that run one after another share
-compiled kernels.
+``benchmarks/chip/run.py`` and ``python -m repro.sweep.run``) call
+:func:`enable_compile_cache` before their first compile, so processes
+that run one after another share compiled kernels.
 
 * Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
   this module sets no directory.

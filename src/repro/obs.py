@@ -37,23 +37,21 @@ benchmark (``benchmarks/chip/metrics``).
     read: a call that hits the cache opens neither span.
 ``pud/session.run_fused``
     Opens in ``DramSession.run_fused``: validation, ``program_key``, the
-    compile cache's schedule, lowering and certificate, then the
-    backend.  Read by ``session_ms.arith`` (its time outside the
-    backend's span).
+    compile cache's schedule and certificate, then the backend.  Read by
+    ``session_ms.arith`` (its time outside the backend's span).
 ``pud/backend.run_fused``
-    Opens in ``PallasBackend.run_fused``, fused mode and the megakernel
-    route alike: the level executor, the image upload and the level walk
-    (eager, op by op, or one dispatch of the jitted walk).  Read by
-    ``level_exec_ms.arith`` (its time).
+    Opens in ``PallasBackend.run_fused``: the level executor, the image
+    upload and the level walk (eager, op by op, or one dispatch of the
+    jitted walk).  Read by ``level_exec_ms.arith`` (its time).
 ``pud/backend.levels_build``
     Opens inside ``pud/backend.run_fused`` on the second sighting of a
-    schedule in fused mode: building its jitted level walk (trace and
-    XLA compile) and the first run of it.  Read by no metric: the
-    benchmark's cells pay it in set-up.
+    schedule: building its jitted level walk (trace and XLA compile) and
+    the first run of it.  Read by no metric: the benchmark's cells pay
+    it in set-up.
 ``pud/backend.levels_jit``
     Opens inside ``pud/backend.run_fused`` around each later dispatch of
     a jitted level walk.  Read by ``level_jit_per_call.arith`` (its
-    count).  A fused call that opens neither ran the walk eagerly: the
+    count).  A call that opens neither ran the walk eagerly: the
     schedule's first sighting.
 
 ``pud/service.scrub``

@@ -11,8 +11,7 @@ per-session :class:`~repro.ft.straggler.StragglerDetector` (one
 sessions exactly as the trainer flags slow SPMD workers.
 
 :meth:`SloMonitor.snapshot` freezes everything into a structured
-:class:`SloSnapshot` — the schema ``BENCH_serve.json`` embeds and
-``docs/SERVING.md`` documents.
+:class:`SloSnapshot` — the schema ``docs/SERVING.md`` documents.
 """
 
 from __future__ import annotations

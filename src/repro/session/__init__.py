@@ -6,7 +6,7 @@ bit-serial arithmetic — are programs over subarray rows, and (PULSAR
 programs.  :class:`DramSession` packages what every consumer needs for
 that: a resolved backend + :class:`~repro.backends.context.
 ExecutionContext`, typed :class:`Row`/:class:`PlaneGroup` allocation
-with build-time validation, automatic lowering through
+with build-time validation, automatic scheduling through
 :mod:`repro.compile`, and a content-hashed :class:`CompileCache` so a
 repeated program skips straight to fused execution.
 

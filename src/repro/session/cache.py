@@ -18,16 +18,8 @@ across its per-chunk sessions, and the serve layer's session pool
 shares one across concurrent request batches.  Lookups are serialized
 by a lock (build included), so N concurrent submissions of one program
 shape are exactly 1 miss + N-1 hits — never N racing builds.
-``stats`` records hits/misses; the bench harnesses report the hit rate
-in ``BENCH_fused.json`` / ``BENCH_serve.json``.
-
-Megakernel artifacts cache under the *same* content key: a
-:class:`~repro.compile.megakernel.MegaLowering` is a pure function of
-the schedule, which is a pure function of program content, so
-:meth:`CompileCache.lowering_for` keys its table store by
-``program_key`` too.  Lowerings keep separate ``lowering_stats`` —
-schedule hit/miss counts are load-bearing in the serve tests and must
-not move when a consumer opts into megakernel mode.
+``stats`` records hits/misses; the serve layer's SLO snapshots report
+the hit rate.
 """
 
 from __future__ import annotations
@@ -42,7 +34,6 @@ from repro.pud.isa import Program
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard for hints only
     from repro.analyze.cert import Certificate
-    from repro.compile.megakernel import MegaLowering
 
 
 def program_key(program: Program) -> str:
@@ -78,22 +69,17 @@ class CacheStats:
 class CompileCache:
     """LRU cache: ``program_key`` -> built :class:`Schedule`.
 
-    A second LRU store under the same keys holds megakernel
-    :class:`~repro.compile.megakernel.MegaLowering` tables
-    (:meth:`lowering_for`), with its own ``lowering_stats`` window; a
-    third holds analysis :class:`~repro.analyze.cert.Certificate`
-    records (:meth:`certificate_for`, ``certificate_stats``) so a
-    repeated program certifies once and is a pure lookup afterwards.
+    A second LRU store under the same keys holds analysis
+    :class:`~repro.analyze.cert.Certificate` records
+    (:meth:`certificate_for`, ``certificate_stats``) so a repeated
+    program certifies once and is a pure lookup afterwards.
     """
 
     def __init__(self, maxsize: int = 128):
         self.maxsize = maxsize
         self.stats = CacheStats()
-        self.lowering_stats = CacheStats()
         self.certificate_stats = CacheStats()
         self._entries: collections.OrderedDict[str, Schedule] = \
-            collections.OrderedDict()
-        self._lowerings: "collections.OrderedDict[str, MegaLowering]" = \
             collections.OrderedDict()
         self._certificates: "collections.OrderedDict[str, Certificate]" = \
             collections.OrderedDict()
@@ -125,66 +111,32 @@ class CompileCache:
                 self._entries.popitem(last=False)
             return sched
 
-    def lowering_for(self, program: Program, key: Optional[str] = None,
-                     sched: Optional[Schedule] = None) -> "MegaLowering":
-        """The program's megakernel level tables — cached by content.
-
-        Resolves the schedule through :meth:`schedule_for` first (the
-        lock is re-entrant, so this is one serialized pass) unless the
-        caller hands one in.  Hits/misses land on ``lowering_stats``,
-        never on ``stats`` — schedule-cache accounting is unchanged by
-        megakernel execution.
-        """
-        from repro.compile.megakernel import lower_schedule
-
-        key = key or program_key(program)
-        with self._lock:
-            low = self._lowerings.get(key)
-            if low is not None:
-                self._lowerings.move_to_end(key)
-                self.lowering_stats.hits += 1
-                return low
-            self.lowering_stats.misses += 1
-            if sched is None:
-                sched = self.schedule_for(program, key=key)
-            low = lower_schedule(sched)
-            self._lowerings[key] = low
-            while len(self._lowerings) > self.maxsize:
-                self._lowerings.popitem(last=False)
-            return low
-
     def certificate_for(self, program: Program, key: Optional[str] = None,
-                        sched: Optional[Schedule] = None,
-                        lowering: "Optional[MegaLowering]" = None
-                        ) -> "Certificate":
+                        sched: Optional[Schedule] = None) -> "Certificate":
         """The program's analysis :class:`~repro.analyze.cert.Certificate`.
 
-        Cached under the same content key as schedules, with a third
+        Cached under the same content key as schedules, with a second
         stats window (``certificate_stats``): a *hit* means the artifact
         was admitted analyzed and zero re-analysis happened — the
-        property the CI gate asserts.  A cached fused-only certificate
-        is *upgraded* (one extra miss) the first time the caller also
-        hands in a megakernel ``lowering``; a lowering-covering
-        certificate satisfies fused-only lookups.  Raises
-        :class:`~repro.analyze.cert.CertificationError` on any error
-        finding — a program that fails certification is never admitted.
+        property ``python -m repro.analyze --cache-check`` asserts.
+        Raises :class:`~repro.analyze.cert.CertificationError` on any
+        error finding — a program that fails certification is never
+        admitted.
         """
         from repro.analyze.cert import certify
 
         key = key or program_key(program)
         with self._lock:
             cert = self._certificates.get(key)
-            if cert is not None and (lowering is None
-                                     or cert.lowering_digest
-                                     == lowering.digest()):
+            if cert is not None:
                 self._certificates.move_to_end(key)
                 self.certificate_stats.hits += 1
                 return cert
             self.certificate_stats.misses += 1
             if sched is None:
                 sched = self.schedule_for(program, key=key)
-            cert = certify(program, sched=sched, lowering=lowering,
-                           key=key, where=f"program {key[:12]}")
+            cert = certify(program, sched=sched, key=key,
+                           where=f"program {key[:12]}")
             self._certificates[key] = cert
             while len(self._certificates) > self.maxsize:
                 self._certificates.popitem(last=False)

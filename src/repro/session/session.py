@@ -95,43 +95,30 @@ class DramSession:
         self._validate(program, state, program_key(program))
         return self.backend.run(program, state)
 
-    def run_fused(self, program: Program, state, *,
-                  mode: str = "fused") -> jax.Array:
+    def run_fused(self, program: Program, state) -> jax.Array:
         """Fused execution: validate, resolve the cached schedule, run.
 
         Bit-identical to :meth:`run` on every backend; batch-native
-        backends execute one kernel dispatch per schedule group — or,
-        with ``mode="megakernel"``, ONE dispatch for the whole program
-        (backends that don't advertise the capability fall back to
-        their exact path, see ``Backend.run_fused``).  A repeated
-        program is a cache hit — no re-scheduling; in megakernel mode
-        the lowered level tables cache under the same content key (with
-        their own ``cache.lowering_stats`` window, so schedule-cache
-        accounting is mode-independent).
+        backends execute one kernel dispatch per schedule group.  A
+        repeated program is a cache hit — no re-scheduling.
 
-        Unless ``ctx.certify`` is False, the resolved artifacts are
-        also statically certified (race / liveness / equivalence, see
+        Unless ``ctx.certify`` is False, the resolved schedule is also
+        statically certified (race / liveness / equivalence, see
         :mod:`repro.analyze`) through the cache's certificate store —
         one analysis per program content, raising
         :class:`~repro.analyze.cert.CertificationError` if the compiled
-        schedule or level tables ever diverge from program dataflow.
+        schedule ever diverges from program dataflow.
         """
         with obs.span("session.run_fused"):
             key = program_key(program)
             self._validate(program, state, key)
             sched = self.cache.schedule_for(program, key=key)
-            lowering = None
-            if mode == "megakernel" and self.capabilities().megakernel:
-                lowering = self.cache.lowering_for(program, key=key,
-                                                   sched=sched)
             if self.ctx.certify:
                 # Static race/liveness/equivalence certification of the
-                # exact artifacts about to execute; content-cached, so a
+                # exact schedule about to execute; content-cached, so a
                 # repeated program is a dictionary hit, not a re-analysis.
-                self.cache.certificate_for(program, key=key, sched=sched,
-                                           lowering=lowering)
-            return self.backend.run_fused(program, state, sched=sched,
-                                          mode=mode, lowering=lowering)
+                self.cache.certificate_for(program, key=key, sched=sched)
+            return self.backend.run_fused(program, state, sched=sched)
 
     # --------------------------------------------- §8.1 compiled arithmetic
     def elementwise(self, op: str, a, b, tier: Optional[int] = None,

@@ -7,19 +7,20 @@ execution::
 
 Each fixture freezes (a) a canonical serialized Program, (b) the seed of
 its random initial (rows, words) state, (c) the expected final state
-computed by the per-op oracle interpreter, (d) a ``megakernel``
-section pinning the lowered level-table structure (shapes, per-level
-slot counts, content digest) plus a digest of the expected final state,
-and (e) a ``certificate`` section freezing the static analyzer's
-verdict (:func:`repro.analyze.certify` digest + per-pass error/warning
-counts) — so an analyzer change that silently alters what is checked,
-or a compiler change that alters the artifacts, moves a pinned digest.
-tests/test_compile_golden.py replays every fixture through per-op,
-fused, and megakernel execution on all backends: a scheduler or
-lowering change that reorders ops but alters results — or silently
-repacks the tables — fails loudly against these bytes.  Review
-regenerated diffs op-by-op — a changed ``expected`` row means changed
-semantics, not formatting.
+computed by the per-op oracle interpreter, (d) a ``walk`` section
+pinning the level walk's structure (per level, each group's kind,
+padded arity or fan-out, op count and launch words) plus a digest of
+the expected final state, and (e) a ``certificate`` section freezing
+the static analyzer's verdict (:func:`repro.analyze.certify` digest +
+per-pass error/warning counts) — so an analyzer change that silently
+alters what is checked, or a compiler change that alters the
+schedule, moves a pinned digest.  tests/test_compile_golden.py replays
+every fixture through per-op and fused execution on all backends and
+through the pallas walk's eager and jitted runs: a scheduler change
+that reorders ops but alters results — or silently regroups the walk
+— fails loudly against these bytes.  Review regenerated diffs
+op-by-op — a changed ``expected`` row means changed semantics, not
+formatting.
 """
 
 from __future__ import annotations
@@ -89,38 +90,36 @@ FIXTURES = {
 }
 
 
-def _megakernel_section(prog, final: np.ndarray) -> dict:
-    """Freeze the lowered level-table structure + final-state digest."""
+def _walk_section(prog, final: np.ndarray) -> dict:
+    """Freeze the level walk's structure + the final-state digest."""
     import hashlib
 
-    from repro.compile import build_schedule, lower_schedule
+    from repro.backends.pallas import _LevelWalk
+    from repro.compile import build_schedule
 
-    low = lower_schedule(build_schedule(prog))
+    sched = build_schedule(prog)
+    walk = _LevelWalk(sched)
     return {
-        "n_levels": low.n_levels,
-        "w_max": low.w_max,
-        "x_max": low.x_max,
-        "level_meta": [list(c) for c in low.level_meta],
-        "table_digest": low.digest(),
+        "levels": [[[p.kind, p.param, len(g.ops), p.launch_words]
+                    for g, p in zip(lvl, plans)]
+                   for lvl, plans in zip(sched.levels, walk.levels)],
         "final_digest": hashlib.sha256(
             np.ascontiguousarray(final).tobytes()).hexdigest(),
     }
 
 
 def _certificate_section(prog) -> dict:
-    """Freeze the analyzer's certificate for schedule + lowering.
+    """Freeze the analyzer's certificate for the program's schedule.
 
-    Deterministic: the digest covers program content, both artifact
-    digests, the analyzer version, and the per-pass finding counts —
+    Deterministic: the digest covers program content, the schedule
+    digest, the analyzer version, and the per-pass finding counts —
     ``python -m repro.analyze --golden`` and
     ``tests/test_compile_golden.py`` both recompute and compare it.
     """
     from repro.analyze import certify
-    from repro.compile import build_schedule, lower_schedule
+    from repro.compile import build_schedule
 
-    sched = build_schedule(prog)
-    cert = certify(prog, sched=sched, lowering=lower_schedule(sched))
-    return cert.to_dict()
+    return certify(prog, sched=build_schedule(prog)).to_dict()
 
 
 def main() -> None:
@@ -142,7 +141,7 @@ def main() -> None:
             "words": WORDS,
             "ops": json.loads(prog.to_json()),
             "expected": ["".join(f"{w:08x}" for w in row) for row in final],
-            "megakernel": _megakernel_section(prog, final),
+            "walk": _walk_section(prog, final),
             "certificate": _certificate_section(prog),
         }
         path = os.path.join(out_dir, f"{name}.json")

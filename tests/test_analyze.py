@@ -1,12 +1,12 @@
 """Static analyzer tests: races, liveness, equivalence, certification.
 
 Positive direction: every golden fixture and every differential-suite
-random program must certify across fused AND megakernel lowerings —
-the analyzer may not reject artifacts the compiler legitimately emits
-(aliasing, dead stores, input replication, mixed arities, cost-only
-ops included).  Negative direction: every seeded table mutation
+random program must certify against its fused schedule — the analyzer
+may not reject artifacts the compiler legitimately emits (aliasing,
+dead stores, input replication, mixed arities, cost-only ops
+included).  Negative direction: every seeded schedule mutation
 (:mod:`repro.analyze.mutate`) and every hand-built hazard (dependent
-ops forced into one level, constant-row writes, use-after-free row
+ops forced into one level, malformed groups, use-after-free row
 references) must be caught with its stable finding code.
 """
 
@@ -17,17 +17,16 @@ import os
 import numpy as np
 import pytest
 
+from _proptest import sweep
 from repro.analyze import (Certificate, CertificationError, MUTATIONS,
                            allocator_findings, analyze, apply_mutation,
                            certify, check_ops, equivalence_findings,
-                           lifetimes, liveness_findings, lowering_findings,
-                           schedule_findings)
+                           lifetimes, liveness_findings, schedule_findings)
 from repro.analyze.cert import schedule_digest
 from repro.backends import ExecutionContext
-from repro.compile import build_schedule, lower_schedule
-from repro.compile.megakernel import ONE_ROW, TRASH_ROW, ZERO_ROW
+from repro.compile import build_schedule
 from repro.compile.schedule import FusedGroup, Schedule
-from repro.pud.isa import Program
+from repro.pud.isa import Program, PUDOp
 from repro.session import DramSession
 from repro.session.cache import CompileCache, program_key
 from repro.session.rows import RowAllocator
@@ -157,24 +156,31 @@ def test_schedule_findings_dropped_op():
     assert "SCHED_OP_SET" in _codes(schedule_findings(bad, prog))
 
 
-def test_lowering_findings_clean_on_compiler_output():
-    for path in GOLDEN_FILES:
-        _, prog = _load_golden(path)
-        low = lower_schedule(build_schedule(prog))
-        assert lowering_findings(low) == [], path
+def _group_malformations() -> dict[str, tuple[Program, Schedule]]:
+    """Programs beside a hand-built schedule whose one group the walk
+    could not run: a stale MAJ arity, a MAJ arity of the wrong parity,
+    an MRC fan-out too narrow, and an op in a group of another kind."""
+    maj5 = PUDOp("MAJ", x=5, n_act=8, srcs=(0, 1, 2, 3, 4), dsts=(5,))
+    mrc = PUDOp("MRC", n_act=4, srcs=(0,), dsts=(1, 2, 3))
+    not_ = PUDOp("NOT", srcs=(0,), dsts=(1,))
+    cases = {
+        "maj_stale": (maj5, FusedGroup("MAJ", 3, (maj5,))),
+        "maj_parity": (maj5, FusedGroup("MAJ", 6, (maj5,))),
+        "mrc_narrow": (mrc, FusedGroup("MRC", 2, (mrc,))),
+        "kind": (not_, FusedGroup("COPY", 0, (not_,))),
+    }
+    return {name: (Program([op]), Schedule(((g,),)))
+            for name, (op, g) in cases.items()}
 
 
-def test_lowering_findings_const_write_and_trash_read():
-    _, prog = _load_golden(GOLDEN_FILES[0])
-    low = lower_schedule(build_schedule(prog))
-    bad = apply_mutation(low, "const_write")
-    assert "RACE_CONST_WRITE" in _codes(lowering_findings(bad))
-    trash = low.src.copy()
-    # Point a live slot's first operand at the trash row.
-    trash[0, 0, 0] = TRASH_ROW
-    import dataclasses
-    bad2 = dataclasses.replace(low, src=trash)
-    assert "RACE_TRASH_READ" in _codes(lowering_findings(bad2))
+@pytest.mark.parametrize("case", sorted(_group_malformations()))
+def test_schedule_findings_malformed_group(case):
+    prog, bad = _group_malformations()[case]
+    assert "SCHED_GROUP" in _codes(schedule_findings(bad, prog))
+    with pytest.raises(CertificationError):
+        certify(prog, sched=bad)
+    # The compiler's own grouping of the same program is clean.
+    assert schedule_findings(build_schedule(prog), prog) == []
 
 
 # -------------------------------------------------------- liveness pass
@@ -237,9 +243,7 @@ def test_allocator_use_after_free_and_leak():
 def test_equivalence_clean_across_artifacts():
     for path in GOLDEN_FILES:
         _, prog = _load_golden(path)
-        sched = build_schedule(prog)
-        low = lower_schedule(sched)
-        assert equivalence_findings(prog, sched, low) == [], path
+        assert equivalence_findings(prog, build_schedule(prog)) == [], path
 
 
 def test_equivalence_catches_forced_same_level_dependency():
@@ -254,9 +258,9 @@ def test_equivalence_catches_forced_same_level_dependency():
 
 
 def test_equivalence_padding_and_expansion_identities():
-    # Mixed arities (forces constant padding), MRC expansion, NOT slots
-    # in one program: the lowering certifies only because the symbolic
-    # domain proves MAJ_k == MAJ_{k+2m}(.., 0*m, 1*m) and MAJ_1(v) == v.
+    # Mixed arities (forces constant padding), MRC fan-outs, NOT in one
+    # program: the schedule certifies only because the symbolic domain
+    # proves MAJ_k == MAJ_{k+2m}(.., 0*m, 1*m) for the padded MAJ3.
     prog = Program()
     prog.emit("MAJ", x=7, n_act=8,
               srcs=(0, 1, 2, 3, 4, 5, 6), dsts=(7,))
@@ -264,9 +268,8 @@ def test_equivalence_padding_and_expansion_identities():
     prog.emit("NOT", srcs=(7,), dsts=(9,))
     prog.emit("MRC", n_act=32, srcs=(8,), dsts=(10, 11, 12))
     sched = build_schedule(prog)
-    low = lower_schedule(sched)
-    assert low.x_max == 7  # the MAJ3 really is padded
-    assert equivalence_findings(prog, sched, low) == []
+    assert sched.histogram()[("MAJ", 7)] == 1  # the MAJ3 really is padded
+    assert equivalence_findings(prog, sched) == []
 
 
 # -------------------------------------------------- certification driver
@@ -276,14 +279,12 @@ def test_equivalence_padding_and_expansion_identities():
 def test_golden_certifies_and_matches_frozen_certificate(path):
     doc, prog = _load_golden(path)
     sched = build_schedule(prog)
-    low = lower_schedule(sched)
-    cert = certify(prog, sched=sched, lowering=low)
+    cert = certify(prog, sched=sched)
     frozen = doc["certificate"]
     assert cert.digest == frozen["digest"]
     assert cert.program_key == frozen["program_key"]
-    assert cert.lowering_digest == frozen["lowering_digest"] \
-        == low.digest()
-    assert cert.schedule_digest == schedule_digest(sched)
+    assert cert.schedule_digest == frozen["schedule_digest"] \
+        == schedule_digest(sched)
     assert {name: {"errors": e, "warnings": w}
             for name, e, w in cert.summary} == frozen["passes"]
 
@@ -291,11 +292,10 @@ def test_golden_certifies_and_matches_frozen_certificate(path):
 def test_certificate_deterministic():
     _, prog = _load_golden(GOLDEN_FILES[0])
     sched = build_schedule(prog)
-    low = lower_schedule(sched)
-    a = certify(prog, sched=sched, lowering=low)
-    b = certify(prog, sched=sched, lowering=low)
+    a = certify(prog, sched=sched)
+    b = certify(prog, sched=sched)
     assert a == b and a.digest == b.digest
-    assert isinstance(a, Certificate) and a.covers_lowering
+    assert isinstance(a, Certificate) and a.n_levels == sched.n_levels
 
 
 @pytest.mark.parametrize("mutation", sorted(MUTATIONS))
@@ -304,14 +304,38 @@ def test_seeded_mutations_rejected(mutation):
     for path in GOLDEN_FILES:
         _, prog = _load_golden(path)
         sched = build_schedule(prog)
-        bad = apply_mutation(lower_schedule(sched), mutation)
+        bad = apply_mutation(sched, mutation)
         if bad is None:
-            continue  # no site on this fixture (e.g. no NOT slots)
+            continue  # no site on this fixture (e.g. no MAJ group)
         applied += 1
+        assert bad != sched
+        assert apply_mutation(sched, mutation) == bad  # deterministic
         with pytest.raises(CertificationError) as err:
-            certify(prog, sched=sched, lowering=bad)
+            certify(prog, sched=bad)
         assert err.value.report.errors, (path, mutation)
+        assert certify(prog, sched=sched)  # the input was not modified
     assert applied >= 1, f"mutation {mutation} never applicable"
+
+
+@sweep(n_cases=20, seed=0x5C4E)
+def test_schedule_mutations_rejected_on_random_programs(rng):
+    """Beyond the fixtures: on random programs (aliasing, dead stores,
+    mixed arities), some mutation applies, and certify rejects every
+    one that does."""
+    from test_compile_differential import rand_program
+
+    prog = _dedup_dsts(rand_program(rng, n_ops=12))
+    sched = build_schedule(prog)
+    certify(prog, sched=sched)
+    applied = []
+    for name in MUTATIONS:
+        bad = apply_mutation(sched, name)
+        if bad is None:
+            continue
+        applied.append(name)
+        with pytest.raises(CertificationError):
+            certify(prog, sched=bad)
+    assert applied
 
 
 def test_analyze_report_never_raises():
@@ -335,9 +359,8 @@ def test_differential_programs_certify():
     for trial in range(25):
         prog = _dedup_dsts(rand_program(rng, n_ops=12))
         sched = build_schedule(prog)
-        low = lower_schedule(sched)
-        cert = certify(prog, sched=sched, lowering=low)
-        assert cert.covers_lowering, trial
+        cert = certify(prog, sched=sched)
+        assert cert.schedule_digest == schedule_digest(sched), trial
 
 
 def test_traced_adder_certifies_with_dead_gate():
@@ -350,7 +373,7 @@ def test_traced_adder_certifies_with_dead_gate():
 
     prog = trace_planes(f, 4, tier=5, n_act=32).program
     sched = build_schedule(prog)
-    cert = certify(prog, sched=sched, lowering=lower_schedule(sched))
+    cert = certify(prog, sched=sched)
     assert cert.summary[0] == ("race", 0, 0)
 
 
@@ -362,33 +385,28 @@ def test_certificate_cache_hit_and_upgrade():
     cache = CompileCache()
     sched = cache.schedule_for(prog)
 
-    fused_only = cache.certificate_for(prog, sched=sched)
-    assert fused_only.lowering_digest is None
+    first = cache.certificate_for(prog, sched=sched)
+    assert first.schedule_digest == schedule_digest(sched)
     assert (cache.certificate_stats.misses,
             cache.certificate_stats.hits) == (1, 0)
 
     again = cache.certificate_for(prog, sched=sched)
-    assert again is fused_only
+    assert again is first
     assert cache.certificate_stats.hits == 1  # zero re-analysis
 
-    low = cache.lowering_for(prog, sched=sched)
-    upgraded = cache.certificate_for(prog, sched=sched, lowering=low)
-    assert upgraded.covers_lowering          # one extra miss: upgrade
-    assert cache.certificate_stats.misses == 2
-
-    final = cache.certificate_for(prog, sched=sched, lowering=low)
-    assert final is upgraded
-    assert cache.certificate_stats.hits == 2
+    # Without a schedule in hand the lookup resolves the cached one.
+    assert cache.certificate_for(prog) is first
+    assert (cache.certificate_stats.misses, cache.certificate_stats.hits,
+            cache.stats.misses) == (1, 2, 1)
 
 
 def test_certificate_cache_rejects_uncertifiable():
     _, prog = _load_golden(GOLDEN_FILES[0])
     cache = CompileCache()
     sched = cache.schedule_for(prog)
-    bad = apply_mutation(cache.lowering_for(prog, sched=sched),
-                         "truncate_slot")
+    bad = apply_mutation(sched, "drop_op")
     with pytest.raises(CertificationError):
-        cache.certificate_for(prog, sched=sched, lowering=bad)
+        cache.certificate_for(prog, sched=bad)
     # Nothing admitted: a later good lookup is a fresh miss, not a hit.
     cache.certificate_for(prog, sched=sched)
     assert cache.certificate_stats.hits == 0

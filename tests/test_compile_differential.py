@@ -79,7 +79,14 @@ def _run_everywhere(prog: Program, state) -> dict[str, np.ndarray]:
 # ----------------------------------------------------- differential sweep
 
 
-@sweep(n_cases=8, seed=0x5EED)
+#: Seeds of the differential sweep: cases 0-7 from 0x5EED, 8-15 from
+#: 0x3E6A (the seeds of the former all-executors sweep).
+_FUSED_RNGS = ([np.random.default_rng((0x5EED, i)) for i in range(8)]
+               + [np.random.default_rng((0x3E6A, i)) for i in range(8)])
+
+
+@pytest.mark.parametrize("rng", _FUSED_RNGS,
+                         ids=[f"case{i}" for i in range(len(_FUSED_RNGS))])
 def test_random_programs_fused_equals_per_op_everywhere(rng):
     prog = rand_program(rng)
     state = jnp.asarray(rand_u32(rng, ROWS, WORDS))
@@ -176,6 +183,24 @@ def test_mixed_arity_level_is_one_dispatch():
     assert (got == want).all()
 
 
+@sweep(n_cases=4, seed=0xD1FF)
+def test_fused_launches_equal_schedule_groups(rng):
+    """One launch per MAJ / MRC group of the schedule, whichever way the
+    walk runs: eager, building the jitted walk, and jitted."""
+    prog = rand_program(rng, n_ops=14)
+    state = jnp.asarray(rand_u32(rng, ROWS, WORDS))
+    sched = build_schedule(prog)
+    groups = sum(1 for lvl in sched.levels for g in lvl
+                 if g.kind in ("MAJ", "MRC"))
+    pal = get_backend("pallas", IDEAL)
+    want = np.asarray(get_backend("oracle", IDEAL).run(prog, state))
+    for sighting in ("eager", "build", "jit"):
+        with pal.count_dispatches() as scope:
+            got = np.asarray(pal.run_fused(prog, state))
+        assert scope.count == groups, sighting
+        assert (got == want).all(), sighting
+
+
 # ------------------------------------------- the acceptance dispatch gate
 
 
@@ -268,13 +293,59 @@ def _adder_program() -> Program:
                                tier=5, n_act=32).program
 
 
+def _dead_ops_program() -> Program:
+    """Dead stores (results never read) still land in the image."""
+    prog = Program()
+    prog.emit("MAJ", x=3, n_act=4, srcs=(0, 1, 2), dsts=(5,))  # dead
+    prog.emit("COPY", srcs=(0,), dsts=(6,))                    # dead
+    prog.emit("MAJ", x=3, n_act=4, srcs=(1, 2, 3), dsts=(4,))
+    return prog
+
+
+def _maj3579_program() -> Program:
+    """MAJ3/5/7/9 in one level: all padded to MAJ9, one launch."""
+    prog = Program()
+    for i, x in enumerate((3, 5, 7, 9)):
+        prog.emit("MAJ", x=x, n_act=cal.min_activation_for(x),
+                  srcs=tuple(range(x)), dsts=(10 + i,))
+    return prog
+
+
+def _mrc_fanout31_program() -> Program:
+    prog = Program()
+    prog.emit("MRC", n_act=32, srcs=(0,), dsts=tuple(range(1, 32)))
+    return prog
+
+
+def _single_not_program() -> Program:
+    prog = Program()
+    prog.emit("NOT", srcs=(0,), dsts=(1,))
+    return prog
+
+
+def _cost_only_program() -> Program:
+    """Addressless ops and WR: no level, no launch, the identity."""
+    prog = Program()
+    for _ in range(5):
+        prog.emit("MAJ", x=5, n_act=8)
+        prog.emit("WR")
+    return prog
+
+
+#: Name -> (program, launches a call when the count is pinned).
 WALK_CORPUS = {
-    "aliasing": _aliasing_program,
-    "mixed_arity": _mixed_arity_program,
-    "mrc_prefix": _mrc_prefix_program,
-    "not_copy": _not_copy_program,
-    "random": lambda: rand_program(np.random.default_rng(9), n_ops=16),
-    "adder32": _adder_program,
+    "aliasing": (_aliasing_program, None),
+    "mixed_arity": (_mixed_arity_program, None),
+    "mrc_prefix": (_mrc_prefix_program, None),
+    "not_copy": (_not_copy_program, 0),
+    "random": (lambda: rand_program(np.random.default_rng(9), n_ops=16),
+               None),
+    "adder32": (_adder_program, None),
+    "dead_ops": (_dead_ops_program, None),
+    "maj3579": (_maj3579_program, 1),
+    "mrc_fanout31": (_mrc_fanout31_program, 1),
+    "single_not": (_single_not_program, 0),
+    "cost_only": (_cost_only_program, 0),
 }
 
 
@@ -285,8 +356,11 @@ def test_jitted_walk_matches_eager_and_per_op(name, monkeypatch):
     with the eager walk's launches and energy, at two image shapes."""
     from repro import obs
 
-    prog = WALK_CORPUS[name]()
+    build_prog, launches = WALK_CORPUS[name]
+    prog = build_prog()
     sched = build_schedule(prog)
+    if launches is not None:
+        assert sched.n_dispatches() == launches
     rng = np.random.default_rng(10)
     opened = []
     span = obs.span

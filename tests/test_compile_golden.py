@@ -5,10 +5,11 @@ adders, MAJ5/7/9 reduction trees, fan-out-31 Multi-RowCopy waves) with
 their expected output bitplanes under fixed seeds — regenerate with
 ``tests/golden/generate.py`` only on intentional semantic changes.  A
 scheduler change that reorders ops but alters results fails here loudly,
-on every backend and on all three execution paths (per-op, fused,
-megakernel); each fixture additionally pins the megakernel lowering's
-level-table structure and content digest, so a silent repacking of the
-tables fails even when the final state happens to agree.
+on every backend, per-op and fused, and in the pallas level walk's
+eager, building and jitted runs; each fixture additionally pins the
+walk's structure (per level, each group's kind, padded arity or
+fan-out, op count and launch words), so a silent regrouping fails even
+when the final state happens to agree.
 """
 
 import glob
@@ -21,7 +22,8 @@ import numpy as np
 import pytest
 
 from repro.backends import ExecutionContext, get_backend
-from repro.compile import build_schedule, lower_schedule
+from repro.backends.pallas import _LevelWalk
+from repro.compile import build_schedule
 from repro.pud.isa import Program
 
 IDEAL = ExecutionContext(ideal=True)
@@ -64,8 +66,6 @@ def test_golden_program_all_backends_both_paths(path):
         for mode, run in (("per_op", be.run), ("fused", be.run_fused)):
             got = np.asarray(run(prog, state))
             assert (got == expected).all(), (doc["name"], name, mode)
-        got = np.asarray(be.run_fused(prog, state, mode="megakernel"))
-        assert (got == expected).all(), (doc["name"], name, "megakernel")
 
 
 @pytest.mark.parametrize(
@@ -87,17 +87,18 @@ def test_golden_fused_dispatch_budget(path):
 @pytest.mark.parametrize(
     "path", GOLDEN_FILES, ids=[os.path.basename(p)[:-5]
                                for p in GOLDEN_FILES])
-def test_golden_megakernel_lowering_structure(path):
-    """The frozen level-table structure: shapes, per-level slot counts,
-    and the byte-level table digest must reproduce exactly."""
+def test_golden_level_walk_structure(path):
+    """The frozen walk structure: per level, each group's kind, padded
+    arity or fan-out, op count and launch words, plus the digest of the
+    expected final image, must reproduce exactly."""
     doc, prog, _, expected = _load(path)
-    frozen = doc["megakernel"]
-    low = lower_schedule(build_schedule(prog))
-    assert low.n_levels == frozen["n_levels"]
-    assert low.w_max == frozen["w_max"]
-    assert low.x_max == frozen["x_max"]
-    assert [list(c) for c in low.level_meta] == frozen["level_meta"]
-    assert low.digest() == frozen["table_digest"]
+    frozen = doc["walk"]
+    sched = build_schedule(prog)
+    walk = _LevelWalk(sched)
+    assert [[[p.kind, p.param, len(g.ops), p.launch_words]
+             for g, p in zip(lvl, plans)]
+            for lvl, plans in zip(sched.levels, walk.levels)] \
+        == frozen["levels"]
     assert hashlib.sha256(
         np.ascontiguousarray(expected).tobytes()).hexdigest() \
         == frozen["final_digest"]
@@ -106,14 +107,20 @@ def test_golden_megakernel_lowering_structure(path):
 @pytest.mark.parametrize(
     "path", GOLDEN_FILES, ids=[os.path.basename(p)[:-5]
                                for p in GOLDEN_FILES])
-def test_golden_megakernel_is_one_dispatch(path):
+def test_golden_jitted_walk(path):
+    """Three sightings of one schedule on one backend — eager, building
+    the jitted walk, dispatching it — each equal to ``expected`` and
+    each at the schedule's launch budget."""
     _, prog, state, expected = _load(path)
+    sched = build_schedule(prog)
     pal = get_backend("pallas", IDEAL)
-    with pal.count_dispatches() as scope:
-        got = np.asarray(pal.run_fused(prog, jnp.asarray(state),
-                                       mode="megakernel"))
-    assert scope.count == 1
-    assert (got == expected).all()
+    state = jnp.asarray(state)
+    for sighting in ("eager", "build", "jit"):
+        with pal.count_dispatches() as scope:
+            got = np.asarray(pal.run_fused(prog, state, sched=sched))
+        assert (got == expected).all(), sighting
+        assert scope.count == sched.n_dispatches(), sighting
+    assert pal._walks[sched].jitted is not None
 
 
 def test_serialization_roundtrip():
@@ -130,7 +137,7 @@ def test_golden_certificate_is_frozen_and_deterministic(path):
     """The frozen certificate section must reproduce bit-for-bit.
 
     Recomputes the full static analysis (races, liveness, symbolic
-    equivalence over schedule AND lowering) and compares against the
+    equivalence over the schedule) and compares against the
     fixture's pinned digest: an analyzer change that silently alters
     what is checked — or a compiler change that alters the artifacts —
     moves this digest and must go through fixture regeneration.
@@ -139,10 +146,8 @@ def test_golden_certificate_is_frozen_and_deterministic(path):
 
     doc, prog, _, _ = _load(path)
     sched = build_schedule(prog)
-    lowering = lower_schedule(sched)
-    cert = certify(prog, sched=sched, lowering=lowering)
+    cert = certify(prog, sched=sched)
     frozen = doc["certificate"]
     assert cert.to_dict() == frozen
     # Determinism: a second independent run lands on the same digest.
-    assert certify(prog, sched=sched, lowering=lowering).digest \
-        == frozen["digest"]
+    assert certify(prog, sched=sched).digest == frozen["digest"]

@@ -10,12 +10,11 @@ delegates to COST bit-identically, preserving the historical retry
 semantics (NOT/COPY energy prices one clean issue while its latency is
 retry-aware); (4) offload decisions carry an energy verdict next to
 the latency verdict; (5) backend dispatch scopes meter energy — zero
-on the oracle, positive on sim, and ordered megakernel <= fused <=
-per-op on pallas; (6) the serve layer threads energy into its SLO
+on the oracle, positive on sim, and ordered fused <= per-op on
+pallas; (6) the serve layer threads energy into its SLO
 snapshots and the sync ``serve()`` path honors ``tick_window_s``.
 """
 
-import os
 import time
 
 import numpy as np
@@ -32,7 +31,6 @@ from repro.serve import PudService, ServiceConfig
 from repro.session import DramSession
 from test_serve_service import heal_req
 
-REPO = os.path.join(os.path.dirname(__file__), "..")
 IDEAL = ExecutionContext(ideal=True)
 
 
@@ -212,7 +210,8 @@ def test_dispatch_scope_energy_oracle_zero_sim_positive():
 
 def test_pallas_energy_ordering_mega_fused_per_op():
     """Fusion's joule story mirrors its dispatch story: launch energy
-    amortizes, so megakernel <= fused <= per-op."""
+    amortizes, so fused <= per-op, on the eager walk and the jitted one
+    alike."""
     sess = DramSession("pallas")
     rng = np.random.default_rng(1)
     a = rng.integers(0, 2**32, 8, dtype=np.uint32)
@@ -223,14 +222,13 @@ def test_pallas_energy_ordering_mega_fused_per_op():
     for mode, run in (
             ("per_op", lambda: sess.run(prog, state)),
             ("fused", lambda: sess.run_fused(prog, state)),
-            ("megakernel", lambda: sess.run_fused(
-                prog, state, mode="megakernel"))):
+            ("jitted", lambda: sess.run_fused(prog, state))):
         with sess.count_dispatches() as scope:
             run()
         nj[mode] = scope.energy_nj
         assert scope.energy_nj > 0
-    assert nj["megakernel"] <= nj["fused"] <= nj["per_op"]
-    assert nj["megakernel"] < nj["per_op"]
+    assert nj["jitted"] == pytest.approx(nj["fused"], rel=1e-12)
+    assert nj["fused"] < nj["per_op"]
 
 
 # ------------------------------------------------------- serve-layer energy
@@ -256,21 +254,3 @@ def test_sync_serve_honors_tick_window():
     t0 = time.monotonic()
     svc.serve([heal_req(rng)])
     assert time.monotonic() - t0 >= window
-
-
-# -------------------------------------------------- bench schema contracts
-
-
-def test_bench_schemas_carry_energy_columns():
-    """Both bench writers are on the energy-carrying schema revisions
-    (the gates in scripts/ci.sh and scripts/check_docs.py assume so)."""
-    with open(os.path.join(REPO, "benchmarks", "bench.py")) as f:
-        fused_src = f.read()
-    with open(os.path.join(REPO, "benchmarks", "serve_bench.py")) as f:
-        serve_src = f.read()
-    assert 'SCHEMA = "repro-bench/fused-v4"' in fused_src
-    assert 'SCHEMA = "repro-bench/serve-v2"' in serve_src
-    assert '"energy_nj"' in fused_src
-    assert '"energy_nj"' in serve_src
-    assert '"energy_per_req_nj"' in serve_src
-    assert '"tick_window_s"' in serve_src

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.compile import compile_elementwise, trace
+from repro.compile import trace
 from repro.session import DramSession
 
 ONCE = ("elementwise", "compile", "session.run_fused", "backend.run_fused")
@@ -103,22 +103,6 @@ def test_every_traced_gate_reads_its_planes_back(traced_add):
     names = [e[0] for e in hit]
     assert "compile" in names
     assert "compile.trace" not in names and "compile.sync" not in names
-
-
-def test_the_megakernel_route_opens_the_backend_span(tmp_path):
-    rng = np.random.default_rng(1)
-    a, b = (rng.integers(0, 2**32, 64, dtype=np.uint32) for _ in range(2))
-    cp = compile_elementwise("add", a, b)
-    session = DramSession("pallas")
-    jax.profiler.start_trace(str(tmp_path))
-    try:
-        final = jax.block_until_ready(
-            session.run_fused(cp.program, cp.state, mode="megakernel"))
-    finally:
-        jax.profiler.stop_trace()
-    np.testing.assert_array_equal(np.asarray(cp.outputs(final)), a + b)
-    names = sorted(e[0] for e in _pud_events(str(tmp_path)))
-    assert names == ["backend.run_fused", "session.run_fused"]
 
 
 def test_a_span_without_a_profiler_keeps_nothing(tmp_path):

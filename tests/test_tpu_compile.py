@@ -1,10 +1,10 @@
 """Compile the main-path Pallas kernels for a described TPU v5e chip.
 
 Interpret-mode tests cannot see what the chip's compiler refuses (a
-vector load from SMEM, a scalar store to VMEM, a table that overflows
-SMEM).  These compile each kernel entry point with ``interpret=False``
-at one 65,536-bit DRAM row (2,048 ``uint32`` words) for a ``v5e:2x2``
-topology that is described, not attached.  Nothing runs.
+vector load from SMEM, a scalar store to VMEM).  These compile each
+kernel entry point with ``interpret=False`` at one 65,536-bit DRAM row
+(2,048 ``uint32`` words) for a ``v5e:2x2`` topology that is described,
+not attached.  Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
@@ -16,16 +16,12 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.backends import ExecutionContext
-from repro.compile import (build_schedule, compile_elementwise,
-                           lower_schedule, plan_vmem)
+from repro.compile import build_schedule
 from repro.kernels.bitserial.ops import bitserial_add
 from repro.kernels.majx.ops import majx
-from repro.kernels.megakernel.ops import run_lowering
 from repro.kernels.mismatch.ops import mismatch_count
 from repro.kernels.rowcopy.ops import fanout
 
@@ -57,22 +53,6 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _add32_case():
-    """The §8.1 add32 program over one row of lanes, lowered to the
-    megakernel's level tables, and its column plan."""
-    ctx = ExecutionContext(ideal=True)
-    rng = np.random.default_rng(0)
-    a, b = (rng.integers(0, 2**32, WORDS * 32, dtype=np.uint32)
-            for _ in range(2))
-    cp = compile_elementwise("add", a, b, tier=ctx.tier, n_act=ctx.n_act)
-    low = lower_schedule(build_schedule(cp.program))
-    rows, words = cp.state.shape
-    plan = plan_vmem(low, rows, words, ctx.vmem_budget_bytes,
-                     block_r=ctx.block_r)
-    return (functools.partial(run_lowering, low, block_c=plan.block_c,
-                              interpret=False), [(rows, words)])
-
-
 CASES = {
     "majx9": lambda: (functools.partial(majx, interpret=False),
                       [(9, 8, WORDS)]),
@@ -86,7 +66,6 @@ CASES = {
                               [(32, 8, WORDS)] * 2),
     "mismatch": lambda: (functools.partial(mismatch_count, interpret=False),
                          [(8, WORDS)] * 2),
-    "megakernel_add32": _add32_case,
 }
 
 
