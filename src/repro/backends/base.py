@@ -32,7 +32,7 @@ from __future__ import annotations
 import abc
 import contextlib
 import dataclasses
-from typing import Optional, Sequence
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -91,7 +91,10 @@ class Capabilities:
             otherwise.  ``ExecutionContext(ideal=True)`` forces False.
         device_model: True when ops execute through the behavioural
             ``Subarray``/``PUDDevice`` APA/PRE/ACT command model rather
-            than closed-form boolean semantics.
+            than closed-form boolean semantics.  Such ops keep host
+            state per command, so they never run under ``jax.jit``: a
+            caller that builds a backend's program into a jitted one of
+            its own (:meth:`Backend.walk_fn`) runs it eagerly there.
         accelerated: True when bulk ops dispatch Pallas TPU kernels
             (compiled on a TPU, the Pallas interpreter elsewhere).
         max_majx: widest MAJ arity this backend can execute.  For the
@@ -246,6 +249,28 @@ class Backend(abc.ABC):
         """
         return self.run(program, state)
 
+    def walk_fn(self, program: Program, sched, rows: int):
+        """``program`` as a function of a ``(rows, words)`` image, for a
+        caller to build into a program of its own, and ``replay(width)``,
+        which advances this backend's counters as one call of it on a
+        ``width``-word image does.
+
+        The per-op default is :meth:`run` of the program, plain jnp, so
+        it traces under ``jax.jit`` unless ``capabilities().device_model``
+        (then it runs eagerly and counts its own commands); it counts
+        nothing to replay.  ``sched`` and ``rows`` are the session's
+        schedule and the image's row count, which backends with a fused
+        path (``pallas``) build their walk from and check against.
+        """
+        return _PerOpRun(self, program.content_key(), program), _no_replay
+
+    def tracer(self) -> tuple["Backend", Callable[[], None]]:
+        """A twin of this backend for ``jax.jit`` to trace bulk ops on,
+        and ``replay``, which advances this backend's counters as one
+        call of the traced program advanced the twin's.  The default
+        twin is the backend itself: its traced ops count nothing."""
+        return self, lambda: None
+
     def _exec_op(self, op, state: jax.Array) -> jax.Array:
         if not op.dsts:
             return state  # cost-only op: nothing addressable to do
@@ -301,3 +326,21 @@ class Backend(abc.ABC):
 
     def gate_not(self, p: jax.Array) -> jax.Array:
         return self._not(p)
+
+
+@dataclasses.dataclass(frozen=True)
+class _PerOpRun:
+    """:meth:`Backend.run` of one program, as a function of the image;
+    equal for one backend and equal program content, so a caller may key
+    a cache on it."""
+
+    backend: Backend
+    key: str
+    program: Program = dataclasses.field(compare=False)
+
+    def __call__(self, state: jax.Array) -> jax.Array:
+        return self.backend.run(self.program, state)
+
+
+def _no_replay(width: int) -> None:
+    """A per-op run counts its own commands: nothing to replay."""

@@ -26,7 +26,9 @@ backend keeps the walks of its last ``WALK_CACHE_SIZE`` schedules.
 (:mod:`repro.obs`) around the level executor: the image upload and the
 level walk; inside it, ``pud/backend.levels_build`` wraps building and
 first running a jitted walk, and ``pud/backend.levels_jit`` each later
-dispatch of one.
+dispatch of one.  :meth:`walk_fn` hands a caller the same walk, as a
+traceable function, to build into a jitted program of its own (the
+scrub's tile step); the caller replays its launches.
 ``self.dispatch_count`` counts MAJX / Multi-RowCopy launches (one per
 schedule group, however the walk runs), a structural count and not a
 device time; each launch also accrues
@@ -42,7 +44,7 @@ import copy
 import dataclasses
 import functools
 import threading
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +80,9 @@ class PallasBackend(Backend):
         #: Schedule -> :class:`_LevelWalk`, least recently used first.
         self._walks: collections.OrderedDict = collections.OrderedDict()
         self._walks_lock = threading.Lock()
+        #: Set on a :meth:`tracer` twin: the bytes of each launch it
+        #: traces, for the replay.
+        self._log: Optional[list] = None
 
     def capabilities(self) -> Capabilities:
         return Capabilities(
@@ -96,7 +101,11 @@ class PallasBackend(Backend):
         """Account one kernel launch: bump the dispatch counter and
         accrue its CostModel energy — the launch round-trip at board
         power plus the HBM access energy of the kernel's ``n_bytes`` of
-        operand + result traffic."""
+        operand + result traffic.  A :meth:`tracer` twin logs the launch
+        instead."""
+        if self._log is not None:
+            self._log.append(n_bytes)
+            return
         self.dispatch_count += 1
         self.energy_nj_total += (COST.dispatch_energy_nj(1)
                                  + COST.hbm_energy_nj(n_bytes))
@@ -164,19 +173,42 @@ class PallasBackend(Backend):
                 return self._walk(walk, state)
             if walk.jitted is None:
                 with obs.span("backend.levels_build"):
-                    # Traced on a copy of the backend: the launches it
-                    # counts while tracing are dropped, and each call's
-                    # are replayed on this one below.
-                    walk.jitted = jax.jit(
-                        functools.partial(copy.copy(self)._walk, walk))
+                    walk.jitted = jax.jit(walk.fn)
                     out = walk.jitted(state)
             else:
                 with obs.span("backend.levels_jit"):
                     out = walk.jitted(state)
-            width = state.shape[1]
-            for words in walk.launch_words:
-                self._launch(words * width * 4)
+            self._replay(walk, state.shape[1])
             return out
+
+    def walk_fn(self, program: Program, sched, rows: int):
+        """The schedule's level walk as a traceable function of a
+        ``(rows, words)`` image, and ``replay(width)``: the walk
+        :meth:`run_fused` jits, from the same LRU entry, whose launches
+        the caller replays after each call (see
+        :meth:`Backend.walk_fn`)."""
+        walk, _ = self._level_walk(sched)
+        if walk.max_row >= rows:
+            raise ValueError(f"program addresses row {walk.max_row} of a "
+                             f"{rows}-row image")
+        return walk.fn, functools.partial(self._replay, walk)
+
+    def tracer(self) -> tuple["PallasBackend", Callable[[], None]]:
+        """A twin that logs the launches traced on it, and the replay of
+        that log on this backend (see :meth:`Backend.tracer`)."""
+        twin = copy.copy(self)
+        twin._log = log = []
+
+        def replay() -> None:
+            for n_bytes in log:
+                self._launch(n_bytes)
+
+        return twin, replay
+
+    def _replay(self, walk: "_LevelWalk", width: int) -> None:
+        """One call's launches of ``walk`` on a ``width``-word image."""
+        for words in walk.launch_words:
+            self._launch(words * width * 4)
 
     def _level_walk(self, sched) -> tuple["_LevelWalk", bool]:
         """The schedule's :class:`_LevelWalk` from the LRU, built and
@@ -187,6 +219,9 @@ class PallasBackend(Backend):
                 self._walks.move_to_end(sched)
                 return walk, False
             walk = self._walks[sched] = _LevelWalk(sched)
+            # Traced on a copy of the backend: the launches it counts
+            # while tracing are dropped, and each call's are replayed.
+            walk.fn = functools.partial(copy.copy(self)._walk, walk)
             while len(self._walks) > WALK_CACHE_SIZE:
                 self._walks.popitem(last=False)
             return walk, True
@@ -289,8 +324,9 @@ def _plan_group(group) -> _GroupPlan:
 
 
 class _LevelWalk:
-    """A schedule's group plans, built once, and its jitted walk, built
-    on the schedule's second sighting."""
+    """A schedule's group plans, built once, its walk as a traceable
+    function (``fn``), and its jitted walk, built on the schedule's
+    second sighting."""
 
     def __init__(self, sched):
         self.levels = tuple(tuple(_plan_group(g) for g in level)
@@ -301,4 +337,5 @@ class _LevelWalk:
                                   if g.launch_words)
         self.max_row = max((int(a.max()) for g in groups
                             for a in (g.srcs, g.dsts)), default=-1)
+        self.fn = None
         self.jitted = None

@@ -56,17 +56,21 @@ benchmark (``benchmarks/chip/metrics``).
 
 ``pud/service.scrub``
     Opens in ``PudService.scrub``: one scrub of a resident replica set,
-    the queue, admission and batcher included.  Read by
-    ``scrub_host_ms.scrub`` (its time less the two below), and every
-    ``*.scrub`` reader reads nothing from a trace without it.
+    the queue, admission and batcher included, and the final read of
+    the counts.  Read by ``scrub_host_ms.scrub`` (its time less that of
+    ``pud/backend.run_fused`` and ``pud/scrub.verify``, which no longer
+    open inside it, so ``level_exec_ms.scrub`` and ``verify_ms.scrub``
+    read 0), and every ``*.scrub`` reader reads nothing from a trace
+    without it.
 ``pud/scrub.tile``
-    Opens in ``serve.scrub.scrub`` around each tile: its image, the
-    fused run (``pud/session.run_fused`` and ``pud/backend.run_fused``
-    open inside, read by ``level_exec_ms.scrub``), the mismatch passes
-    and the write-back.  Read by ``tiles_per_call.scrub`` (its count).
-``pud/scrub.verify``
-    Opens inside ``pud/scrub.tile`` around the tile's mismatch passes,
-    one per replica.  Read by ``verify_ms.scrub`` (its time).
+    Opens in ``serve.scrub.scrub`` around each tile: its one step and
+    the replay of the step's launches on the backend's counters.  Read
+    by ``tiles_per_call.scrub`` (its count).
+``pud/scrub.step``
+    Opens inside ``pud/scrub.tile`` around the tile's one dispatch: the
+    jitted step that builds the tile's image, runs the walk, writes the
+    vote back and counts each replica's wrong bits.  Read by
+    ``steps_per_tile.scrub`` (its count over that of ``pud/scrub.tile``).
 
 The kernels' device time is read from the name each ``pallas_call``
 gives its device op (``KERNEL_NAME`` in each kernel module of

@@ -15,20 +15,35 @@ number of tiles.
 A scrub votes the replicas tile by tile.  Every tile has one shape, so
 one frozen :class:`~repro.pud.isa.Program` of ``tile_rows`` MAJ ops
 (:func:`tile_program`) serves every tile of every scrub: built, hashed,
-scheduled and certified once, it runs through
-:meth:`~repro.session.DramSession.run_fused` with each tile's image,
-the ``x`` replica tiles stacked above a zeroed output group.  The bits
-each replica had wrong come from ``mismatch`` of its tile against the
-voted one, and the voted words are written back into every replica in
-place (donated buffers).  Working memory is a few tile images; the
-counts reach the host once per scrub.
+scheduled and certified once, and resolved once per scrub by
+:meth:`~repro.session.DramSession.walk_fn`, which hands back the
+backend's walk of it as a function of the tile's image.  Each tile is
+then one dispatch of one jitted step (:func:`_step`), which takes the
+replicas, a ``(tiles, x)`` ``uint32`` count buffer and the tile's index,
+donates the first two, and inside one program:
+
+* builds the tile's image, the ``x`` replica tiles stacked above a
+  zeroed output group (:func:`_tile_image`);
+* runs the walk (the ``majx_csa`` kernel on ``pallas``);
+* writes the voted rows back into every replica, in place
+  (:func:`_commit`);
+* counts each replica's wrong bits against the vote with the backend's
+  ``mismatch`` (``mismatch_popcount`` on ``pallas``) into the buffer's
+  row for the tile.
+
+The host replays each tile's kernel launches on the backend's counters
+(one per schedule group of the walk, one per replica's mismatch), and
+reads the count buffer once per scrub, summing it in ``int64``: a tile's
+count fits ``uint32``, a whole scrub's need not.  Working memory beyond
+the replicas is a few tile images.  A backend whose ops cannot be traced
+(``Capabilities.device_model``) runs the same step eagerly.
 
 ``TILE_ROWS`` is a constant, not a knob: 1,024 rows of 4,096 words
 (16 MiB a replica tile).  A replica set smaller than a tile gets one
 tile of its own size, rounded up to whole sublanes.
 
-Spans (:mod:`repro.obs`): ``pud/scrub.tile`` around each tile and
-``pud/scrub.verify`` around each tile's mismatch passes.
+Spans (:mod:`repro.obs`): ``pud/scrub.tile`` around each tile, and
+inside it ``pud/scrub.step`` around the tile's one dispatch.
 """
 
 from __future__ import annotations
@@ -226,14 +241,14 @@ def tile_program(x: int, tile_rows: int) -> FrozenProgram:
     return prog.freeze()
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
 def _tile_image(replicas, start, tile_rows: int):
+    """The ``x`` replica tiles from row ``start``, stacked above a zeroed
+    output group."""
     tiles = [jax.lax.dynamic_slice_in_dim(r, start, tile_rows)
              for r in replicas]
     return jnp.concatenate(tiles + [jnp.zeros_like(tiles[0])])
 
 
-@functools.partial(jax.jit, donate_argnums=0)
 def _commit(replicas, image, start):
     """The voted rows of a tile's image written into every replica;
     returns the replicas, each replica's tile as it was, and the vote."""
@@ -244,23 +259,63 @@ def _commit(replicas, image, start):
                   for r in replicas), before, voted)
 
 
+def _step(walk, mismatch, tile_rows: int, replicas, counts, t):
+    """Tile ``t`` voted by ``walk`` and written back into every replica;
+    row ``t`` of ``counts`` set to each replica's wrong bits."""
+    start = t * tile_rows
+    image = walk(_tile_image(replicas, start, tile_rows))
+    replicas, before, voted = _commit(replicas, image, start)
+    row = jnp.stack([mismatch(b, voted) for b in before]).astype(jnp.uint32)
+    return replicas, jax.lax.dynamic_update_slice(counts, row[None], (t, 0))
+
+
+@functools.lru_cache(maxsize=16)
+def _compiled_step(walk, backend, shape: tuple, x: int, tile_rows: int,
+                   tiles: int):
+    """:func:`_step` compiled for ``x`` replicas of ``shape``, with the
+    replicas and the count buffer donated, and the replay of one call's
+    mismatch launches.  Traced on the backend's twin, so the launches
+    the trace makes are replayed, not counted."""
+    twin, replay = backend.tracer()
+    step = jax.jit(functools.partial(_step, walk, twin.mismatch, tile_rows),
+                   donate_argnums=(0, 1))
+    compiled = step.lower(
+        (jax.ShapeDtypeStruct(shape, jnp.uint32),) * x,
+        jax.ShapeDtypeStruct((tiles, x), jnp.uint32),
+        jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    return compiled, replay
+
+
+def _sum_counts(counts) -> tuple[int, ...]:
+    """Each replica's total over a ``(tiles, x)`` count buffer, summed in
+    ``int64`` (the total can pass ``2**32``)."""
+    return tuple(int(c) for c in np.asarray(counts, np.int64).sum(0))
+
+
+def _counted_eagerly() -> None:
+    """An eager step's ops count themselves: nothing to replay."""
+
+
 def scrub(session, rs: ReplicaSet) -> tuple[int, ...]:
-    """Vote every tile of ``rs`` through ``session.run_fused`` and write
-    the votes back into every replica; returns the bits each replica
-    had wrong."""
-    x, tile = rs.x, rs.layout.tile_rows
-    prog = tile_program(x, tile)
-    counts = []
-    for t in range(rs.layout.tiles):
+    """Vote every tile of ``rs``, one step a tile (see the module
+    docstring), and write the votes back into every replica; returns the
+    bits each replica had wrong."""
+    x, tile, tiles = rs.x, rs.layout.tile_rows, rs.layout.tiles
+    walk, replay_walk = session.walk_fn(tile_program(x, tile), (x + 1) * tile)
+    if session.capabilities().device_model:
+        step = functools.partial(_step, walk, session.mismatch, tile)
+        replay_mismatch = _counted_eagerly
+    else:
+        step, replay_mismatch = _compiled_step(
+            walk, session.backend, rs.replicas[0].shape, x, tile, tiles)
+    counts = jnp.zeros((tiles, x), jnp.uint32)
+    for t in range(tiles):
         with obs.span("scrub.tile"):
-            start = t * tile
-            image = session.run_fused(
-                prog, _tile_image(rs.replicas, start, tile))
-            rs.replicas, before, voted = _commit(rs.replicas, image, start)
-            with obs.span("scrub.verify"):
-                counts += [session.mismatch(b, voted) for b in before]
-    total = np.asarray(jnp.stack(counts), np.int64).reshape(-1, x).sum(0)
-    return tuple(int(c) for c in total)
+            with obs.span("scrub.step"):
+                rs.replicas, counts = step(rs.replicas, counts, np.int32(t))
+            replay_walk(ROW_WORDS)
+            replay_mismatch()
+    return _sum_counts(counts)
 
 
 def plan(session, rs: ReplicaSet):

@@ -15,7 +15,9 @@ things every consumer was hand-assembling on top of the registry:
   schedule through a content-hashed :class:`~repro.session.cache.
   CompileCache`, so repeated programs (serve votes, sweep chunks, §8.1
   executors) skip re-scheduling and go straight to the backend's
-  ``run_fused``.
+  ``run_fused``; :meth:`walk_fn` resolves it the same way and hands
+  back the backend's walk, for a caller to build into its own jitted
+  program (the scrub's tile step).
 
 A session also satisfies the full backend surface by delegation (bulk
 ops, ``capabilities``, the ``GateExecutor`` protocol, dispatch
@@ -77,8 +79,7 @@ class DramSession:
                               name=name or f"{self.name}/program")
 
     # ------------------------------------------------- program execution
-    def _validate(self, program: Program, state, key: str) -> None:
-        n_rows = int(np.shape(state)[0])
+    def _validate(self, program: Program, n_rows: int, key: str) -> None:
         if (key, n_rows) in self._validated:
             return
         check_program(program, n_rows, where=self.name)
@@ -92,7 +93,8 @@ class DramSession:
 
     def run(self, program: Program, state) -> jax.Array:
         """Per-op interpretation, validated up front."""
-        self._validate(program, state, program_key(program))
+        self._validate(program, int(np.shape(state)[0]),
+                       program_key(program))
         return self.backend.run(program, state)
 
     def run_fused(self, program: Program, state) -> jax.Array:
@@ -110,15 +112,37 @@ class DramSession:
         schedule ever diverges from program dataflow.
         """
         with obs.span("session.run_fused"):
-            key = program_key(program)
-            self._validate(program, state, key)
-            sched = self.cache.schedule_for(program, key=key)
-            if self.ctx.certify:
-                # Static race/liveness/equivalence certification of the
-                # exact schedule about to execute; content-cached, so a
-                # repeated program is a dictionary hit, not a re-analysis.
-                self.cache.certificate_for(program, key=key, sched=sched)
+            sched = self._schedule(program, int(np.shape(state)[0]))
             return self.backend.run_fused(program, state, sched=sched)
+
+    def walk_fn(self, program: Program, rows: int):
+        """The program as a function of a ``(rows, words)`` image, for a
+        caller to build into a jitted program of its own, and
+        ``replay(width)``, which advances the backend's counters as one
+        call of it on a ``width``-word image does.
+
+        Validated against ``rows``, scheduled and certified through the
+        compile cache as :meth:`run_fused` does (one analysis per program
+        content); returns the backend's :meth:`~repro.backends.base.
+        Backend.walk_fn` of that schedule.  Where
+        ``capabilities().device_model``, the function runs eagerly and
+        counts its own commands.
+        """
+        return self.backend.walk_fn(program, self._schedule(program, rows),
+                                    rows)
+
+    def _schedule(self, program: Program, n_rows: int) -> Schedule:
+        """Validate ``program`` against ``n_rows`` and resolve its
+        schedule, certified unless ``ctx.certify`` is False."""
+        key = program_key(program)
+        self._validate(program, n_rows, key)
+        sched = self.cache.schedule_for(program, key=key)
+        if self.ctx.certify:
+            # Static race/liveness/equivalence certification of the
+            # exact schedule about to execute; content-cached, so a
+            # repeated program is a dictionary hit, not a re-analysis.
+            self.cache.certificate_for(program, key=key, sched=sched)
+        return sched
 
     # --------------------------------------------- §8.1 compiled arithmetic
     def elementwise(self, op: str, a, b, tier: Optional[int] = None,

@@ -74,8 +74,10 @@ def test_the_layout_matches_the_reference(small_tiles):
     assert not packed[used:].any()
 
 
-@pytest.mark.parametrize("backend", ["oracle", "pallas"])
+@pytest.mark.parametrize("backend", ["oracle", "pallas", "sim"])
 def test_scrub_equals_the_reference_majority(backend, small_tiles):
+    """Every replica differs from the vote, in both tiles; ``sim``
+    (a device model) runs the tile step eagerly, the others jitted."""
     svc = PudService(ServiceConfig(backend=backend, pool_size=1))
     trees = [random_leaves(s) for s in (1, 2, 3)]
     rs = svc.install_replica_trees(trees)
@@ -141,15 +143,16 @@ def test_live_round_trips_every_dtype():
 
 
 def test_one_program_per_tile_shape_through_the_service(small_tiles):
-    """Every tile of every scrub runs one frozen Program: one schedule
-    miss, one certificate, then hits; each scrub is a request."""
+    """Every tile of every scrub runs one frozen Program, resolved once
+    a scrub: one schedule miss and one certificate, then a hit per
+    scrub; each scrub is a request."""
     svc = PudService(ServiceConfig(backend="pallas", pool_size=1))
     rs = svc.install_replicas(random_leaves(7), tenant="t")
     for _ in range(2):
         svc.scrub(rs)
-    tiles = rs.layout.tiles
+    assert rs.layout.tiles == 2
     assert svc.cache.stats.misses == 1
-    assert svc.cache.stats.hits == 2 * tiles - 1
+    assert svc.cache.stats.hits == 1
     assert svc.cache.certificate_stats.misses == 1
     assert svc.snapshot().tenants["t"]["completed"] == 2
     prog = scrub_mod.tile_program(3, 8)
@@ -214,8 +217,68 @@ def test_scrub_spans_nest_inside_the_service_call(tmp_path, small_tiles):
     names = [e[0] for e in events]
     tiles = rs.layout.tiles
     assert names.count("service.scrub") == 1
-    for name in ("scrub.tile", "scrub.verify", "session.run_fused",
-                 "backend.run_fused"):
+    for name in ("scrub.tile", "scrub.step"):
         assert names.count(name) == tiles, name
+    # The walk and the mismatch passes run inside the step's program.
+    for name in ("scrub.verify", "session.run_fused", "backend.run_fused"):
+        assert names.count(name) == 0, name
     (call,) = [e for e in events if e[0] == "service.scrub"]
     assert all(call[1] <= s and e <= call[2] for _, s, e in events)
+    spans = {name: [(s, e) for n, s, e in events if n == name]
+             for name in ("scrub.tile", "scrub.step")}
+    for s, e in spans["scrub.step"]:
+        assert sum(ts <= s and e <= te for ts, te in spans["scrub.tile"]) \
+            == 1
+
+
+def parent_scrub(session, rs):
+    """The tile loop before the one-step tile, the reference for the
+    counters: the walk through ``run_fused``, the write-back, and one
+    ``mismatch`` call per replica, each its own dispatch."""
+    x, tile = rs.x, rs.layout.tile_rows
+    prog = scrub_mod.tile_program(x, tile)
+    counts = []
+    for t in range(rs.layout.tiles):
+        start = t * tile
+        image = session.run_fused(
+            prog, scrub_mod._tile_image(rs.replicas, start, tile))
+        rs.replicas, before, voted = scrub_mod._commit(
+            tuple(map(jnp.copy, rs.replicas)), image, start)
+        counts.append([int(session.mismatch(b, voted)) for b in before])
+    return tuple(int(c) for c in np.sum(counts, 0))
+
+
+@pytest.mark.parametrize("backend", ["pallas", "sim"])
+def test_counters_advance_as_the_tile_loop_did(backend, small_tiles):
+    """``dispatch_count`` and ``energy_nj_total`` advance per scrub
+    exactly as the loop of separate dispatches did: a launch per group
+    of the walk and one per replica's mismatch, in that order, a tile
+    (``pallas``: the walk's eager first sighting and jitted later ones
+    count alike); and the votes and counts agree."""
+    trees = [random_leaves(s) for s in (11, 12, 13)]
+    new, old = (PudService(ServiceConfig(backend=backend, pool_size=1))
+                for _ in range(2))
+    rs_new, rs_old = (svc.install_replica_trees(trees) for svc in (new, old))
+    for _ in range(2):
+        got = scrub_mod.scrub(new.sessions[0], rs_new)
+        want = parent_scrub(old.sessions[0], rs_old)
+        assert got == want and any(want)
+        np.testing.assert_array_equal(host(rs_new), host(rs_old))
+        a, b = new.sessions[0].backend, old.sessions[0].backend
+        assert a.energy_nj_total > 0
+        assert (a.dispatch_count, a.energy_nj_total) == \
+            (b.dispatch_count, b.energy_nj_total)
+        trees = [random_leaves(s) for s in (14, 15, 16)]
+        rs_new.replicas = tuple(jnp.asarray(r) for r in host(
+            new.install_replica_trees(trees)))
+        rs_old.replicas = tuple(map(jnp.copy, rs_new.replicas))
+
+
+def test_the_count_sum_is_exact_past_2_to_32():
+    """Per-tile counts near ``2**32`` sum exactly: the buffer is summed
+    in ``int64`` on the host, not in ``uint32``."""
+    counts = jnp.asarray([[2**32 - 1, 2**32 - 2, 2**31],
+                          [2**32 - 1, 1, 2**31],
+                          [2**32 - 5, 2**32 - 1, 2**31 + 3]], jnp.uint32)
+    assert scrub_mod._sum_counts(counts) == (
+        3 * 2**32 - 7, 2**33 - 2, 3 * 2**31 + 3)

@@ -79,10 +79,12 @@ def test_kernel_compiles_for_v5e(name, one_chip):
 
 
 def test_scrub_tile_compiles_for_v5e(one_chip):
-    """The scrub's tile vote at its real tile (1,024 rows of 4,096
-    words): the jitted level walk of the tile program holds a kernel and
-    keeps its working set well under 1 GB, and the write-back updates
-    the replicas in place."""
+    """The scrub's tile at its real size (1,024 rows of 4,096 words):
+    the jitted level walk of the tile program holds a kernel and keeps
+    its working set well under 1 GB; the tile step (image, walk,
+    write-back and mismatch counts in one program) over three replicas
+    of four tiles holds both kernels, updates the replicas in place and
+    keeps its temporaries under 256 MiB."""
     import copy
 
     from repro.backends.pallas import PallasBackend, _LevelWalk
@@ -94,8 +96,8 @@ def test_scrub_tile_compiles_for_v5e(one_chip):
     walk = _LevelWalk(build_schedule(scrub.tile_program(3, tile)))
     image = jax.ShapeDtypeStruct((4 * tile, scrub.ROW_WORDS), jnp.uint32,
                                  sharding=one_chip)
-    compiled = jax.jit(functools.partial(copy.copy(backend)._walk, walk)
-                       ).lower(image).compile()
+    fn = functools.partial(copy.copy(backend)._walk, walk)
+    compiled = jax.jit(fn).lower(image).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes + \
@@ -103,9 +105,16 @@ def test_scrub_tile_compiles_for_v5e(one_chip):
     replicas = tuple(jax.ShapeDtypeStruct((4 * tile, scrub.ROW_WORDS),
                                           jnp.uint32, sharding=one_chip)
                      for _ in range(3))
-    commit = scrub._commit.lower(replicas, image, 0).compile()
-    assert commit.memory_analysis().alias_size_in_bytes == \
-        3 * 4 * tile * scrub.ROW_WORDS * 4
+    counts = jax.ShapeDtypeStruct((4, 3), jnp.uint32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    twin, _ = backend.tracer()
+    step = jax.jit(functools.partial(scrub._step, fn, twin.mismatch, tile),
+                   donate_argnums=(0, 1)).lower(replicas, counts, t).compile()
+    text = step.as_text()
+    assert "majx_csa" in text and "mismatch_popcount" in text
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= 3 * 4 * tile * scrub.ROW_WORDS * 4
+    assert mem.temp_size_in_bytes < 2**28
 
 
 def test_elementwise_image_and_unpack_compile_for_v5e(one_chip):
