@@ -249,8 +249,8 @@ class PallasBackend(Backend):
                     batch = jnp.swapaxes(entry[g.srcs], 0, 1)
                     vals = self.majx(batch)[g.sel]
                 elif g.kind == "MRC":
-                    copies = self.rowcopy(entry[g.srcs], g.param)
-                    vals = copies[g.sel_copy, g.sel]
+                    # (P, B, W) copies onto the (P, B) grid of dsts.
+                    vals = self.rowcopy(entry[g.srcs], g.param)
                 elif g.kind == "NOT":
                     vals = self._not(entry[g.srcs])
                 else:
@@ -276,10 +276,12 @@ class _GroupPlan:
     pair adding one to the popcount and one to the majority threshold,
     so ``MAJ_k(x..) == MAJ_X(x.., 0*m, 1*m)`` exactly; ``sel`` is the op
     each of ``dsts`` takes.  MRC: ``srcs`` is the (B,) source rows, one
-    fan-out to the widest destination count serves them all, and each
-    op scatters the prefix of copies its own ``dsts`` ask for (copies
-    are identical, so a prefix is exact): ``sel_copy`` / ``sel`` pick
-    (copy, op) per dst.  NOT / COPY: ``srcs`` is one source row per dst.
+    fan-out to the widest destination count P serves them all, and
+    ``dsts`` is the (P, B) grid of the row copy ``j`` of op ``i`` lands
+    on, so the kernel's copies scatter straight into the image; an op
+    with fewer destinations repeats its last one (copies are identical,
+    so the repeat writes the same value).  NOT / COPY: ``srcs`` is one
+    source row per dst.
     ``launch_words`` is the kernel's operand + result words per image
     word (0 without a kernel).
     """
@@ -289,7 +291,6 @@ class _GroupPlan:
     srcs: np.ndarray
     dsts: np.ndarray
     sel: Optional[np.ndarray] = None
-    sel_copy: Optional[np.ndarray] = None
     launch_words: int = 0
 
 
@@ -312,10 +313,10 @@ def _plan_group(group) -> _GroupPlan:
         return _GroupPlan("MAJ", x_max, srcs, dsts, sel=sel,
                           launch_words=len(ops) * (x_max + 1))
     if group.kind == "MRC":
-        sel_copy = np.array([j for op in ops for j in range(len(op.dsts))])
+        grid = np.array([[op.dsts[min(j, len(op.dsts) - 1)] for op in ops]
+                         for j in range(group.param)])
         return _GroupPlan("MRC", group.param,
-                          np.array([op.srcs[0] for op in ops]), dsts,
-                          sel=sel, sel_copy=sel_copy,
+                          np.array([op.srcs[0] for op in ops]), grid,
                           launch_words=len(ops) * (1 + group.param))
     # NOT / COPY: one gather (+ complement) + scatter, no kernel.
     return _GroupPlan(group.kind, group.param,

@@ -14,7 +14,7 @@ The spans, with their names stable.  Each opens once per call at its
 site, except ``compile.trace`` and ``compile.sync``, which open once per
 miss of the compile cache, the
 two ``backend.levels_*`` spans, of which a fused call opens at most one,
-and the ``scrub.*`` spans, which open once per tile;
+and the ``scrub.*`` and ``erase.*`` spans, which open once per tile;
 they nest on the calling thread.  The metric that reads each is a reader of the
 benchmark (``benchmarks/chip/metrics``).
 
@@ -71,6 +71,19 @@ benchmark (``benchmarks/chip/metrics``).
     jitted step that builds the tile's image, runs the walk, writes the
     vote back and counts each replica's wrong bits.  Read by
     ``steps_per_tile.scrub`` (its count over that of ``pud/scrub.tile``).
+``pud/service.erase``
+    Opens in ``PudService.erase``: one wipe of a resident region, the
+    queue, admission and batcher included, until the device has
+    finished it.  Read by ``erase_host_ms.erase`` (its time), and every
+    ``*.erase`` reader reads nothing from a trace without it.
+``pud/erase.tile``
+    Opens in ``serve.region.erase`` around each bank: its one step and
+    the replay of the step's launches on the backend's counters.
+``pud/erase.step``
+    Opens inside ``pud/erase.tile`` around the bank's one dispatch: the
+    jitted step that builds the bank's image, runs the walk of the
+    bank's erase program and writes the wiped rows back.  Read by
+    ``steps_per_call.erase`` (its count).
 
 The kernels' device time is read from the name each ``pallas_call``
 gives its device op (``KERNEL_NAME`` in each kernel module of

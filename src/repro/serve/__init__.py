@@ -13,6 +13,8 @@ substrate efficiently.  This package is that service subsystem:
   coalesce into ONE fused Program per tick;
 * :mod:`repro.serve.scrub` — replica sets resident on the device and
   their tile-by-tile MAJ scrub;
+* :mod:`repro.serve.region` — a region of rows resident on the device
+  and its bank-by-bank Multi-RowCopy wipe in place;
 * :mod:`repro.serve.slo` — request traces + rolling p50/p99/throughput/
   occupancy/cache-hit SLO snapshots;
 * :mod:`repro.serve.service` — :class:`PudService`, the engine tying
@@ -31,8 +33,10 @@ from repro.serve.batcher import Batcher, BatchOutcome, BatchPlan
 from repro.serve.queue import (EraseRequest, EraseResult, HealRequest,
                                HealResult, IntegrityRequest,
                                IntegrityResult, Priority, PudRequest,
+                               RegionEraseRequest, RegionEraseResult,
                                RequestQueue, ScrubRequest, ScrubResult,
                                ServeError)
+from repro.serve.region import Region
 from repro.serve.scrub import ReplicaSet
 from repro.serve.service import PudService, ServiceConfig
 from repro.serve.slo import RequestTrace, SloMonitor, SloSnapshot, Span
@@ -42,7 +46,8 @@ __all__ = [
     "BatchOutcome", "BatchPlan", "Batcher", "DeadlineExceededError",
     "EraseRequest", "EraseResult", "HealRequest", "HealResult",
     "IntegrityRequest", "IntegrityResult", "Priority", "PudRequest",
-    "PudService", "QueueFullError", "ReplicaSet", "RequestQueue",
+    "PudService", "QueueFullError", "Region", "RegionEraseRequest",
+    "RegionEraseResult", "ReplicaSet", "RequestQueue",
     "RequestTrace", "ScrubRequest", "ScrubResult", "ServeError",
     "ServiceConfig", "SloMonitor", "SloSnapshot", "Span", "TenantArena",
 ]

@@ -18,6 +18,9 @@ coalesce_key` merge into ONE addressed Program built through the typed
 * **scrub** — a resident replica set votes tile by tile, one frozen
   tile Program for every tile (:mod:`repro.serve.scrub`); scrubs never
   coalesce.
+* **region erase** — a resident region is wiped in place bank by bank,
+  one frozen bank Program along the row decoder's groups
+  (:mod:`repro.serve.region`); region erases never coalesce.
 * **verify** — ``mismatch`` is a scalar reduction per request (no
   per-request split of a fused result), so integrity checks share the
   tick and session but execute one bulk op each.
@@ -38,7 +41,7 @@ import numpy as np
 from repro.core import calibration as cal
 from repro.serve.queue import (EraseRequest, EraseResult, HealRequest,
                                HealResult, IntegrityRequest, IntegrityResult,
-                               PudRequest, ScrubResult)
+                               PudRequest, RegionEraseResult, ScrubResult)
 
 
 @dataclasses.dataclass
@@ -102,6 +105,8 @@ class Batcher:
             return self._execute_verify(plan, session)
         if plan.kind == "scrub":
             return self._execute_scrub(plan, session)
+        if plan.kind == "region_erase":
+            return self._execute_region_erase(plan, session)
         raise ValueError(f"unknown batch kind {plan.kind!r}")
 
     def _execute_heal(self, plan: BatchPlan, session) -> BatchOutcome:
@@ -171,6 +176,19 @@ class Batcher:
                              words=rs.layout.words)
         return BatchOutcome(plan, [result], n_ops=rs.layout.tile_rows,
                             n_levels=1)
+
+    def _execute_region_erase(self, plan: BatchPlan,
+                              session) -> BatchOutcome:
+        from repro.pud.secure_erase import erase_program
+        from repro.serve import region
+
+        [req] = plan.requests
+        r = req.region
+        region.erase(session, r, req.pattern)
+        result = RegionEraseResult(banks=r.banks, words=r.size)
+        prog = erase_program(r.bank_rows, r.subarray_rows)
+        return BatchOutcome(plan, [result], n_ops=len(prog.ops),
+                            n_levels=session.schedule_for(prog).n_levels)
 
     def _execute_verify(self, plan: BatchPlan, session) -> BatchOutcome:
         results = []

@@ -4,7 +4,8 @@ The serve layer's unit of work is a *request*: a tenant asking for one
 of the paper's production capabilities — an integrity check (bit-level
 mismatch of a live tile vs a reference), a MAJX heal (majority vote
 across replica tiles, §5), a scrub of resident replicas (the same vote,
-tile by tile, written back), or a Multi-RowCopy bulk erase (§8.2).
+tile by tile, written back), or a Multi-RowCopy bulk erase (§8.2) of
+rows handed in or of a resident region.
 Requests are plain dataclasses over packed uint32 bit-plane
 tiles (the layout of :mod:`repro.core.bitplanes`), carry priority /
 deadline / tenant metadata, and expose the two properties the service
@@ -212,6 +213,36 @@ class ScrubRequest(PudRequest):
         return (self.replicas.x + 1) * self.replicas.layout.tile_rows
 
 
+@dataclasses.dataclass
+class RegionEraseRequest(PudRequest):
+    """§8.2 wipe of a resident region in place, bank by bank, along the
+    row decoder's groups.
+
+    ``region`` is a :class:`~repro.serve.region.Region` installed by
+    :meth:`~repro.serve.service.PudService.install_region`; when the
+    request completes every word of it holds ``pattern`` (a ``uint32``).
+    Region erases never coalesce: each is its own plan.  Admission
+    charges one row, the pattern's: the region's rows are resident and
+    wiped in place.  Result: :class:`RegionEraseResult`.
+    """
+
+    region: object = None                      # required; validated below
+    pattern: int = 0
+
+    def __post_init__(self):
+        if self.region is None:
+            raise ServeError("RegionEraseRequest.region is required")
+        if not 0 <= int(self.pattern) < 2**32:
+            raise ServeError(f"RegionEraseRequest pattern must be a uint32, "
+                             f"got {self.pattern}")
+
+    def coalesce_key(self) -> tuple:
+        return ("region_erase", id(self))
+
+    def rows_needed(self) -> int:
+        return 1
+
+
 # ---------------------------------------------------------------- results
 
 
@@ -242,6 +273,12 @@ class ScrubResult:
 @dataclasses.dataclass(frozen=True)
 class EraseResult:
     wiped: np.ndarray           # (rows, words), pattern everywhere
+
+
+@dataclasses.dataclass(frozen=True)
+class RegionEraseResult:
+    banks: int                  # banks wiped, one step each
+    words: int                  # words of the region, each now the pattern
 
 
 # ------------------------------------------------------------------ queue

@@ -31,8 +31,10 @@ Two client styles share one engine:
 
 Replicas of a params pytree can live on the device
 (:meth:`PudService.install_replicas`) and be scrubbed there tile by tile
-(:meth:`PudService.scrub`, :mod:`repro.serve.scrub`); the scrub is a
-request like any other.
+(:meth:`PudService.scrub`, :mod:`repro.serve.scrub`); so can a region
+of rows (:meth:`PudService.install_region`), wiped in place by
+Multi-RowCopy (:meth:`PudService.erase`, :mod:`repro.serve.region`).
+Both are requests like any other.
 
 The serve engine's ``heal_params`` / ``verify_params``
 (:mod:`repro.serve.engine`) are thin sync clients of this service, so
@@ -54,9 +56,12 @@ from repro.backends import Backend, ExecutionContext
 from repro.serve.admission import (AdmissionController, AdmissionError,
                                    DeadlineExceededError)
 from repro.serve.batcher import Batcher
+from repro.serve import region as region_mod
 from repro.serve import scrub as scrub_mod
-from repro.serve.queue import (PudRequest, RequestQueue, ScrubRequest,
+from repro.serve.queue import (PudRequest, RegionEraseRequest,
+                               RegionEraseResult, RequestQueue, ScrubRequest,
                                ScrubResult, ServeError)
+from repro.serve.region import Region
 from repro.serve.scrub import ReplicaSet
 from repro.serve.slo import RequestTrace, SloMonitor, SloSnapshot
 from repro.session import CompileCache, DramSession
@@ -272,6 +277,25 @@ class PudService:
         """The params pytree replica 0 holds (after a scrub, every
         replica holds it), unpacked on the device."""
         return scrub_mod.unpack(replicas.replicas[0], replicas.layout)
+
+    # ----------------------------------------------------- resident region
+    def install_region(self, rows, *,
+                       subarray_rows: int = region_mod.SUBARRAY_ROWS,
+                       tenant: str = "default") -> Region:
+        """Hold ``rows``, ``(banks, bank_rows, words)`` ``uint32``, as a
+        resident region of ``subarray_rows``-row subarrays (a device
+        array is held, not copied); the handle is what :meth:`erase`
+        takes."""
+        return region_mod.install(rows, subarray_rows, tenant)
+
+    def erase(self, region: Region, pattern: int) -> RegionEraseResult:
+        """Wipe ``region`` in place with ``pattern`` through the queue,
+        admission and batcher; on return every word of it holds
+        ``pattern``."""
+        with obs.span("service.erase"):
+            [result] = self.serve([RegionEraseRequest(
+                region=region, pattern=pattern, tenant=region.tenant)])
+        return result
 
     # ------------------------------------------------------------ async API
     async def start(self) -> None:

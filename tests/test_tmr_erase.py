@@ -11,8 +11,11 @@ from repro.core.power import STANDARD_POWER_W, power_table, simra_power_w
 from repro.core.subarray import Subarray
 from repro.pud import tmr
 from repro.pud.offload import plan_broadcast, plan_vote
-from repro.pud.secure_erase import (destruction_time_ns, erase_subarray,
-                                    speedup_over_rowclone)
+from repro.pud.latency import LAT
+from repro.pud.secure_erase import (BANK_ROWS, SUBARRAY_ROWS,
+                                    destruction_time_ns, erase_program,
+                                    erase_subarray, plan_time_ns,
+                                    speedup_over_rowclone, subarray_plan)
 
 
 @sweep(6)
@@ -76,7 +79,28 @@ def test_functional_erase():
     sa.fill("0xAA")
     t = erase_subarray(sa, 0)
     assert (np.asarray(sa.planes) == 0).all()
-    assert t > 0
+    assert t == plan_time_ns(sa.rows) > 0
+
+
+def test_the_plan_against_the_fig17_model():
+    """A bank by the plan and by the Fig. 17 model.  Both wipe every row
+    with 31 rows an MRC.  The model writes one row a bank and counts
+    ``ceil((rows - 1) / 31)`` MRCs, as if every MRC's source already held
+    the pattern; the plan's groups are disjoint, so it writes each
+    group's source (one WR a group) and issues one MRC a group.  The
+    seeding is the whole difference: 2,047 more WRs, 67 fewer MRCs."""
+    subarrays = BANK_ROWS // SUBARRAY_ROWS
+    groups = subarrays * len(subarray_plan())
+    model_mrcs = -(-(BANK_ROWS - 1) // 31)
+    assert (groups, model_mrcs) == (2048, 2115)
+    assert groups * 32 == BANK_ROWS <= model_mrcs * 31 + 1
+    assert destruction_time_ns("mrc") == pytest.approx(
+        LAT.wr_row + model_mrcs * LAT.mrc)
+    assert subarrays * plan_time_ns() == pytest.approx(
+        destruction_time_ns("mrc") + (groups - 1) * LAT.wr_row
+        - (model_mrcs - groups) * LAT.mrc)
+    hist = erase_program(BANK_ROWS).histogram()
+    assert hist == {("MRC", 0, 32): groups, ("COPY", 0, 0): groups}
 
 
 # -------------------------------------------------------------- offload

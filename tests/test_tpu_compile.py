@@ -117,6 +117,34 @@ def test_scrub_tile_compiles_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < 2**28
 
 
+def test_region_erase_step_compiles_for_v5e(one_chip):
+    """The rank wipe's step at its real size: a bank of 65,536 rows of
+    2,048 words, in a region of 16 banks.  It holds ``rowcopy_fanout``,
+    aliases the whole region (wiped in place, 8.59 GB) and keeps its
+    temporaries (the bank's image, its copy with the pattern row, the
+    kernel's 31 copies) under 1.5 GiB: 1.07 GB when written."""
+    import copy
+
+    from repro.backends.pallas import PallasBackend, _LevelWalk
+    from repro.pud.secure_erase import BANK_ROWS, erase_program
+    from repro.serve import region
+
+    backend = PallasBackend()
+    backend.interpret = False
+    walk = _LevelWalk(build_schedule(erase_program(BANK_ROWS)))
+    fn = functools.partial(copy.copy(backend)._walk, walk)
+    rows = jax.ShapeDtypeStruct((16, BANK_ROWS, WORDS), jnp.uint32,
+                                sharding=one_chip)
+    pattern = jax.ShapeDtypeStruct((), jnp.uint32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    step = jax.jit(functools.partial(region._step, fn),
+                   donate_argnums=0).lower(rows, pattern, t).compile()
+    assert "rowcopy_fanout" in step.as_text()
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes == 16 * BANK_ROWS * WORDS * 4
+    assert mem.temp_size_in_bytes < 3 * 2**29
+
+
 def test_elementwise_image_and_unpack_compile_for_v5e(one_chip):
     """The add32 arith cell's per-call device work around the walk, at
     its 2^20 lanes: packing both operands into the image, and unpacking
